@@ -52,7 +52,7 @@ def _run_engine(cfg, *, slots: int, sparsity: float, requests: int,
     trace = poisson_trace(requests, rate=rate, seed=seed,
                           vocab_size=cfg.vocab_size,
                           prompt_len=(1, 4), max_new=(max(1, hi // 2), hi))
-    with eng.mesh:
+    with jax.set_mesh(eng.mesh):
         for spec in trace:
             eng.submit(**spec)
         return eng.run()

@@ -27,6 +27,7 @@ try:                                    # script: benchmarks/ on sys.path
     from _bench_io import bench_timer, merge_section
 except ImportError:                     # package: imported from repo root
     from benchmarks._bench_io import bench_timer, merge_section
+import jax
 
 from repro.configs import get_config, get_smoke_config
 from repro.serve import RequestState, ServeEngine, poisson_trace
@@ -42,7 +43,7 @@ def _cell(cfg, *, slots: int, requests: int, rate: float, max_len: int,
     trace = poisson_trace(requests, rate=rate, seed=seed,
                           vocab_size=cfg.vocab_size, prompt_len=(1, 4),
                           max_new=(max(1, hi // 2), hi))
-    with eng.mesh:
+    with jax.set_mesh(eng.mesh):
         for spec in trace:
             eng.submit(**spec)   # future arrivals: due-time shedding
         rep = eng.run()

@@ -26,6 +26,7 @@ try:                                    # script: benchmarks/ on sys.path
     from _bench_io import bench_timer, merge_section
 except ImportError:                     # package: imported from repo root
     from benchmarks._bench_io import bench_timer, merge_section
+import jax
 
 from repro.configs import get_config, get_smoke_config
 from repro.serve import ServeEngine, poisson_trace
@@ -48,7 +49,7 @@ def _run(cfg, trace, *, slots, max_len, sparsity, seed, paged,
                       sparsity=sparsity, seed=seed, paged=paged,
                       page_len=page_len, page_pool_tokens=pool_tokens,
                       head_sparsity=0.0)
-    with eng.mesh:
+    with jax.set_mesh(eng.mesh):
         for spec in trace:
             eng.submit(**spec)
         return eng.run()

@@ -27,6 +27,7 @@ try:                                    # script: benchmarks/ on sys.path
     from _bench_io import bench_timer, merge_section
 except ImportError:                     # package: imported from repo root
     from benchmarks._bench_io import bench_timer, merge_section
+import jax
 
 from repro.configs import get_config, get_smoke_config
 from repro.serve import ServeEngine, poisson_trace
@@ -49,7 +50,7 @@ def _run(cfg, trace, *, slots, max_len, sparsity, seed, prefill_chunk,
                       sparsity=sparsity, seed=seed, head_sparsity=0.0,
                       prefill_chunk=prefill_chunk, paged=paged,
                       page_len=page_len)
-    with eng.mesh:
+    with jax.set_mesh(eng.mesh):
         for spec in trace:
             eng.submit(**spec)
         return eng.run()

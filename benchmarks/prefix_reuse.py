@@ -30,6 +30,7 @@ except ImportError:                     # package: imported from repo root
     from benchmarks._bench_io import bench_timer, merge_section
 
 import numpy as np
+import jax
 
 from repro.configs import get_config, get_smoke_config
 from repro.serve import ServeEngine
@@ -68,7 +69,7 @@ def _run(cfg, trace, *, slots, max_len, sparsity, seed, page_len,
                       prefill_chunk=prefill_chunk,
                       prefix_reuse=prefix_reuse, preempt=preempt)
     reqs = []
-    with eng.mesh:
+    with jax.set_mesh(eng.mesh):
         for spec in trace:
             reqs.append(eng.submit(**spec))
         rep = eng.run()

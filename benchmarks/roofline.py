@@ -23,7 +23,7 @@ import os
 from typing import Dict, List
 
 from repro.configs import SHAPES
-from repro.launch.hlo_analysis import roofline
+from repro.launch.hlo_analysis import V5E, peaks, roofline
 
 try:
     from _bench_io import bench_timer, merge_section
@@ -73,9 +73,10 @@ def load_records(dryrun_dir: str = "results/dryrun") -> List[Dict]:
 def build_rows(recs: List[Dict]) -> List[Dict]:
     rows = []
     for rec in recs:
+        # dry-run records are compiled for the 16x16 v5e production mesh
         terms = roofline(rec["flops_per_device"],
                          rec["hbm_bytes_per_device"],
-                         rec["collectives"].get("wire_bytes", 0.0))
+                         rec["collectives"].get("wire_bytes", 0.0), V5E)
         mf = model_flops_per_device(rec)
         rows.append({
             "arch": rec["arch"], "shape": rec["shape"], "mesh": rec["mesh"],
@@ -87,7 +88,7 @@ def build_rows(recs: List[Dict]) -> List[Dict]:
             "useful_ratio": mf / max(rec["flops_per_device"], 1.0),
             "step_s": terms["step_time_overlapped_s"],
             # usable fraction of peak compute in the overlapped-ideal step
-            "mfu_bound": (mf / 197e12) / max(
+            "mfu_bound": (mf / peaks(V5E)["flops"]) / max(
                 terms["step_time_overlapped_s"], 1e-30),
             "advice": advice(rec, terms),
         })

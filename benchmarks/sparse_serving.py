@@ -20,7 +20,7 @@ import os
 import numpy as np
 
 from repro.configs import SHAPES, get_config
-from repro.launch.hlo_analysis import HBM_BW
+from repro.launch.hlo_analysis import V5E, peaks
 from repro.sparse.format import pack_bitmap
 from repro.sparse.pruning import per_tensor_prune
 
@@ -76,9 +76,10 @@ def run(cells=(("gemma3-12b", "decode_2k_b8"),
         rec = json.load(open(path))
         total = rec["hbm_bytes_per_device"]
         wbytes = matmul_weight_bytes_per_device(arch)
-        dense_t = total / HBM_BW
+        hbm_bw = peaks(V5E)["hbm_bw"]     # the dry-run's 16x16 v5e mesh
+        dense_t = total / hbm_bw
         sparse_total = total - wbytes + wbytes / comp
-        sparse_t = sparse_total / HBM_BW
+        sparse_t = sparse_total / hbm_bw
         rows.append({
             "arch": arch, "shape": shape,
             "total_bytes": total, "weight_bytes": wbytes,

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import argparse
 import warnings
+import jax
 
 from repro.configs import get_smoke_config
 from repro.serve import FaultPlan, RequestState, ServeEngine, poisson_trace
@@ -33,7 +34,7 @@ def _run(cfg, seed: int, faults=None, audit: bool = False):
     trace = poisson_trace(8, rate=0.5, seed=seed,
                           vocab_size=eng.cfg.vocab_size,
                           prompt_len=(1, 6), max_new=(4, 10))
-    with eng.mesh:
+    with jax.set_mesh(eng.mesh):
         reqs = [eng.submit(**spec) for spec in trace]
         rep = eng.run()
     return eng, rep, {r.rid: list(r.tokens) for r in reqs}

@@ -22,6 +22,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import jax
 
 from repro.serve import (ServeEngine, poisson_trace, validate_events,
                          validate_trace)
@@ -42,7 +43,7 @@ def run_once(arch: str, out_dir: str | None, *, requests: int,
     trace = poisson_trace(requests, rate=0.5, seed=seed,
                           vocab_size=eng.cfg.vocab_size,
                           prompt_len=(1, 6), max_new=(2, 6))
-    with eng.mesh:
+    with jax.set_mesh(eng.mesh):
         for spec in trace:
             eng.submit(**spec)
         eng.run()

@@ -2,57 +2,106 @@
 
 TPU adaptation of the paper's EIM + SIDR (DESIGN.md §2):
 
-* weights travel HBM→VMEM in the paper's bitmap format (packed bits + packed
-  non-zero values + per-row start offsets) — HBM traffic falls by ≈ the
-  density ratio, the analogue of the 86 % SRAM-access cut;
-* inside VMEM each tile is decompressed with the EIM re-sort
-  (``row_start[i] + rank_within_row`` = IMId/masked-bitmap logic of §II-C)
-  and fed dense to the MXU;
-* the activation tile is fetched once per (i, k) and *reused across the
-  whole output-column grid dimension* (its BlockSpec index map ignores j) —
-  the SIDR row-broadcast; the compressed weight tile is likewise reused
-  across the output-row dimension — the SIDR column-broadcast;
+* weights travel HBM→VMEM in the paper's bitmap format (packed bits +
+  packed non-zero values, ``sparse.format.BitmapWeight``) — HBM traffic
+  falls by ≈ the density ratio, the analogue of the 86 % SRAM-access cut;
+* inside VMEM each tile is decompressed with the EIM re-sort (a value's
+  slot is the number of set bits before it — the IMId/masked-bitmap logic
+  of §II-C) and fed dense to the MXU.  Every step of the re-sort is an
+  operation the TPU compiler lowers: the prefix counts are matmuls
+  against triangular ones tiles, a tile row's (at most BN) values are
+  fetched from the two stored value rows they can span by one-hot
+  matmuls, and the expansion into bitmap positions is a 2-D lane gather;
+* the compressed weight tile is reused across the output-row grid
+  dimension (its index map ignores i) — the SIDR column-broadcast;
 * output-stationary f32 accumulator in VMEM across the K grid axis.
 
-Grid: (M/BM, N/BN, K/BK), K innermost (sequential accumulation).
+Grid: (G, M/BM, N/BN, K/BK), K innermost (sequential accumulation); G is
+the group axis of a group-stacked weight (1 for a plain matrix).
 """
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat
+from repro.sparse.format import _TILE_ND, WORD_ROWS, BitmapWeight
 
-from repro.sparse.format import _TILE_ND, BitmapWeight
-
-
-def _decompress_tile(bits_packed, values, row_start, bk: int, bn: int,
-                     budget: int, dtype):
-    """EIM re-sort inside VMEM: packed tile -> dense (BK, BN)."""
-    shifts = jax.lax.broadcasted_iota(jnp.uint8, (1, 1, 8), 2)
-    bits = (bits_packed[:, :, None] >> shifts) & jnp.uint8(1)
-    bits = bits.reshape(bk, bn).astype(jnp.int32)
-    rank = jnp.cumsum(bits, axis=1) - 1              # rank within tile row
-    slot = jnp.clip(row_start[:, None] + rank, 0, budget - 1)
-    vals = jnp.take(values, slot.reshape(-1), axis=0).reshape(bk, bn)
-    return jnp.where(bits != 0, vals, jnp.zeros((), dtype)).astype(dtype)
+#: lane width of a TPU vector register: the tile's column width on chip
+LANES = 128
 
 
-def _kernel(x_ref, bits_ref, vals_ref, rows_ref, o_ref, acc_ref, *,
-            bk: int, bn: int, budget: int, n_k: int):
-    k = pl.program_id(2)
+def tile_fits_chip(k: int, n: int, block: Tuple[int, int]) -> Optional[str]:
+    """Why the compiled kernel cannot take a (K, N) weight tiled
+    ``block``, or None when it can.  A block's last two dims must be
+    divisible by (8, 128) or span the whole array; the kernel's lane
+    gather wants exactly one vreg of lanes per tile row, and its bitmap
+    words cover 32 rows each."""
+    bk, bn = block
+    if bn != LANES:
+        return f"BN={bn} is not the {LANES}-lane width the TPU kernel needs"
+    if bk % WORD_ROWS or (bk % LANES and bk != k):
+        return (f"BK={bk} is neither a multiple of {LANES} nor the whole "
+                f"K={k} in {WORD_ROWS}-row bitmap words")
+    return None
+
+
+def _decompress_tile(words, vals, bk: int, dtype):
+    """EIM re-sort inside VMEM: packed tile -> dense (BK, BN).
+
+    words: (ceil(BK/32), BN) int32 bitmap words; vals: (Q, BN) value rows.
+    """
+    nw, bn = words.shape
+    f32, b16 = jnp.float32, jnp.bfloat16
+    shifts = jax.lax.broadcasted_iota(jnp.int32, (WORD_ROWS, bn), 0)
+    bits = jnp.concatenate([(words[i:i + 1] >> shifts) & 1
+                            for i in range(nw)], axis=0)[:bk]
+    # prefix counts as matmuls on 0/1 tiles: exact (f32 accumulation of
+    # integers <= BK*BN), and the MXU takes them where cumsum has no
+    # TPU lowering
+    row = jax.lax.broadcasted_iota(jnp.int32, (bn, bn), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (bn, bn), 1)
+    upper = (row <= col).astype(b16)
+    incl = jnp.dot(bits.astype(b16), upper, preferred_element_type=f32)
+    row = jax.lax.broadcasted_iota(jnp.int32, (bk, bk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (bk, bk), 1)
+    lower = (col < row).astype(b16)
+    row_nnz = jnp.broadcast_to(incl[:, bn - 1:], (bk, bn)).astype(b16)
+    start = jnp.dot(lower, row_nnz, preferred_element_type=f32)
+    slot = (start + incl).astype(jnp.int32) - 1       # tile slot per element
+    # a row's slots lie in stored rows h0 and h0 + 1: fetch both by
+    # one-hot matmuls (exact: one non-zero term per output)
+    h0 = start[:, :1].astype(jnp.int32) // bn                   # (BK, 1)
+    q = vals.shape[0]
+    qs = jax.lax.broadcasted_iota(jnp.int32, (bk, q), 1)
+    prec = (jax.lax.Precision.HIGHEST if vals.dtype == f32
+            else jax.lax.Precision.DEFAULT)
+
+    def fetch(h):
+        return jnp.dot((qs == h).astype(vals.dtype), vals, precision=prec,
+                       preferred_element_type=f32)
+
+    off = slot - h0 * bn                              # in [0, 2*BN) on bits
+    lane = off % bn
+    first = jnp.take_along_axis(fetch(h0), lane, axis=1)
+    second = jnp.take_along_axis(fetch(h0 + 1), lane, axis=1)
+    w = jnp.where(off < bn, first, second)
+    return jnp.where(bits != 0, w, 0.0).astype(dtype)
+
+
+def _kernel(x_ref, bits_ref, vals_ref, o_ref, acc_ref, *, bk: int,
+            n_k: int):
+    k = pl.program_id(3)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w_tile = _decompress_tile(bits_ref[0, 0], vals_ref[0, 0], rows_ref[0, 0],
-                              bk, bn, budget, x_ref.dtype)
+    w_tile = _decompress_tile(bits_ref[...], vals_ref[...], bk, x_ref.dtype)
     acc_ref[...] += jnp.dot(x_ref[...], w_tile,
                             preferred_element_type=jnp.float32)
 
@@ -71,70 +120,74 @@ def _round_up(x: int, mult: int) -> int:
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "interpret", "out_dtype"))
-def bitmap_spmm(x: jax.Array, w: BitmapWeight, *, bm: int = 128,
-                interpret: bool = True, out_dtype=None) -> jax.Array:
-    """Compute ``x @ W`` with W stored bitmap-compressed.
+def bitmap_spmm_grouped(x: jax.Array, w: BitmapWeight, *, bm: int = 128,
+                        interpret: bool = False,
+                        out_dtype=None) -> jax.Array:
+    """Per-group ``x[g] @ W_g`` over a group-stacked ``BitmapWeight``.
 
-    x: (M, K); W logical shape (K, N).  Returns (M, N).
+    x: (G, M, K); W logical shape (K, N) per group, array leaves leading
+    with G.  Returns (G, M, N).  One kernel launch covers every group
+    (the group is the outermost grid axis), each group streaming only its
+    own compressed tiles.  Note the capacity-dispatch MoE caller runs this
+    over *all* stored experts; the manifest's per-activated-expert HBM
+    accounting models a gather dispatch that skips unselected groups
+    (DESIGN_PACKED.md §6, modeled vs executed).
 
-    Decode-shaped small-M path: any M in 1..bm (and any M not a multiple
-    of ``bm``) is accepted — the row tile shrinks to M rounded up to the
-    dtype's sublane multiple, the handful of zero pad rows accumulate
-    zeros and their stores are sliced away, instead of the old behaviour
-    of requiring the caller to pad a 4-row decode batch 32× up to 128.
+    Decode-shaped small-M path: any M is accepted — the row tile shrinks
+    to M rounded up to the dtype's sublane multiple, and the handful of
+    zero pad rows accumulate zeros and are sliced away.
     """
-    m, k = x.shape
+    g, m, k = x.shape
     kk, n = w.shape
     assert k == kk, (x.shape, w.shape)
+    assert g == w.values.shape[0], (x.shape, w.values.shape)
     bk, bn = w.block
     kt, nt = k // bk, n // bn
+    nw, q = w.packed_bits.shape[-2], w.values.shape[-2]
     out_dtype = out_dtype or x.dtype
-    budget = w.budget
 
     if m % bm != 0:
         bm = min(bm, _round_up(m, _sublane(x.dtype)))
         m_pad = _round_up(m, bm)
         if m_pad != m:
-            xp = jnp.pad(x, ((0, m_pad - m), (0, 0)))
-            return bitmap_spmm(xp, w, bm=bm, interpret=interpret,
-                               out_dtype=out_dtype)[:m]
+            xp = jnp.pad(x, ((0, 0), (0, m_pad - m), (0, 0)))
+            return bitmap_spmm_grouped(xp, w, bm=bm, interpret=interpret,
+                                       out_dtype=out_dtype)[:, :m]
 
-    grid = (m // bm, nt, kt)
-    kernel = functools.partial(_kernel, bk=bk, bn=bn, budget=budget, n_k=kt)
+    sq = pl.Squeezed()
     return pl.pallas_call(
-        kernel,
-        grid=grid,
+        functools.partial(_kernel, bk=bk, n_k=kt),
+        grid=(g, m // bm, nt, kt),
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, kq: (i, kq)),
-            pl.BlockSpec((1, 1, bk, bn // 8), lambda i, j, kq: (kq, j, 0, 0)),
-            pl.BlockSpec((1, 1, budget), lambda i, j, kq: (kq, j, 0)),
-            pl.BlockSpec((1, 1, bk), lambda i, j, kq: (kq, j, 0)),
+            pl.BlockSpec((sq, bm, bk), lambda gi, i, j, kq: (gi, i, kq)),
+            pl.BlockSpec((sq, sq, sq, nw, bn),
+                         lambda gi, i, j, kq: (gi, kq, j, 0, 0)),
+            pl.BlockSpec((sq, sq, sq, q, bn),
+                         lambda gi, i, j, kq: (gi, kq, j, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kq: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
+        out_specs=pl.BlockSpec((sq, bm, bn),
+                               lambda gi, i, j, kq: (gi, i, j)),
+        out_shape=jax.ShapeDtypeStruct((g, m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=pallas_compat.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret,
         name="bitmap_spmm",
-    )(x, w.packed_bits, w.values, w.row_start)
+    )(x, w.packed_bits, w.values)
 
 
-def group_slice(w: BitmapWeight, g: int) -> BitmapWeight:
-    """The g-th (K, N) member of a group-stacked ``BitmapWeight``.
+def bitmap_spmm(x: jax.Array, w: BitmapWeight, *, bm: int = 128,
+                interpret: bool = False, out_dtype=None) -> jax.Array:
+    """Compute ``x @ W`` with W stored bitmap-compressed.
 
-    Group-stacked weights (MoE expert stacks, RWKV lerp stacks — see
-    ``sparse.format.pack_bitmap_experts``) carry one leading G axis per
-    array leaf at dispatch time (the period axis has already been
-    scanned off); ``shape``/``block``/``budget`` are shared, so a slice
-    is a plain per-matrix ``BitmapWeight`` the kernel accepts as-is.
-    The ``dense_cache`` is deliberately not sliced through: the Pallas
-    path never reads it (it exists only for the xla oracle dispatch,
-    which consumes the stacked cache whole).
+    x: (M, K); W logical shape (K, N).  Returns (M, N).  The one-group
+    case of ``bitmap_spmm_grouped``.
     """
-    return BitmapWeight(packed_bits=w.packed_bits[g], values=w.values[g],
-                        row_start=w.row_start[g], shape=w.shape,
-                        block=w.block, shard=w.shard)
+    w1 = BitmapWeight(packed_bits=w.packed_bits[None],
+                      values=w.values[None], shape=w.shape, block=w.block)
+    return bitmap_spmm_grouped(x[None], w1, bm=bm, interpret=interpret,
+                               out_dtype=out_dtype)[0]
 
 
 def shard_slice(w: BitmapWeight, s: int) -> BitmapWeight:
@@ -159,29 +212,8 @@ def shard_slice(w: BitmapWeight, s: int) -> BitmapWeight:
     return BitmapWeight(
         packed_bits=take(w.packed_bits, "packed_bits"),
         values=take(w.values, "values"),
-        row_start=take(w.row_start, "row_start"),
         shape=shape, block=w.block,
         dense_cache=take(w.dense_cache, "dense_cache"))
-
-
-def bitmap_spmm_grouped(x: jax.Array, w: BitmapWeight, *, bm: int = 128,
-                        interpret: bool = True, out_dtype=None) -> jax.Array:
-    """Per-group ``x[g] @ W_g`` over a group-stacked ``BitmapWeight``.
-
-    x: (G, M, K); W logical shape (K, N) per group, array leaves leading
-    with G.  Returns (G, M, N).  The group count is static (it is a
-    weight-layout property), so the dispatch is an unrolled loop of G
-    small-M ``bitmap_spmm`` calls, each streaming only its own group's
-    compressed tiles.  Note the capacity-dispatch MoE caller runs this
-    over *all* stored experts; the manifest's per-activated-expert HBM
-    accounting models a gather dispatch that skips unselected groups
-    (DESIGN_PACKED.md §6, modeled vs executed).
-    """
-    g = x.shape[0]
-    assert g == w.values.shape[0], (x.shape, w.values.shape)
-    return jnp.stack([
-        bitmap_spmm(x[i], group_slice(w, i), bm=bm, interpret=interpret,
-                    out_dtype=out_dtype) for i in range(g)])
 
 
 def hbm_traffic_model(x_shape: Tuple[int, int], w: BitmapWeight,

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat
-
 from repro.sparse.format import BlockSparseWeight
 
 
@@ -47,7 +45,7 @@ def _kernel(kidx_ref, nnzb_ref, x_ref, w_ref, o_ref, acc_ref, *, smax: int):
 
 @functools.partial(jax.jit, static_argnames=("bm", "interpret", "out_dtype"))
 def block_sparse_matmul(x: jax.Array, w: BlockSparseWeight, *, bm: int = 128,
-                        interpret: bool = True, out_dtype=None) -> jax.Array:
+                        interpret: bool = False, out_dtype=None) -> jax.Array:
     """Compute ``x @ W`` with W block-sparse.  x: (M, K) -> (M, N)."""
     m, k = x.shape
     kk, n = w.shape
@@ -76,7 +74,7 @@ def block_sparse_matmul(x: jax.Array, w: BlockSparseWeight, *, bm: int = 128,
         functools.partial(_kernel, smax=smax),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        compiler_params=pallas_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="block_sparse_matmul",

@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat
-
 from repro.sparse.nm import NmWeight
 
 
@@ -50,7 +48,7 @@ def _kernel(x_ref, v_ref, i_ref, o_ref, acc_ref, *, n, m, bk, bn, n_k):
 
 @functools.partial(jax.jit, static_argnames=("bm", "interpret", "out_dtype"))
 def nm_spmm(x: jax.Array, w: NmWeight, *, bm: int = 128,
-            interpret: bool = True, out_dtype=None) -> jax.Array:
+            interpret: bool = False, out_dtype=None) -> jax.Array:
     """Compute ``x @ W`` with W N:M-compressed. x: (M, K) -> (M, N)."""
     mm, k = x.shape
     kk, n_cols = w.shape
@@ -73,7 +71,7 @@ def nm_spmm(x: jax.Array, w: NmWeight, *, bm: int = 128,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kq: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mm, n_cols), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=pallas_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="nm_spmm",

@@ -77,8 +77,8 @@ def bitmap_spmm_grouped(x: jax.Array, w: BitmapWeight,
     """Per-group ``x[g] @ W_g`` over a group-stacked ``BitmapWeight``
     (MoE expert stacks, RWKV lerp stacks — layout in
     ``sparse.format.pack_bitmap_experts``).  x: (G, M, K) -> (G, M, N);
-    the Pallas path unrolls G small-M kernel calls so each group streams
-    only its own compressed tiles."""
+    the Pallas path is one kernel launch with the group as its outermost
+    grid axis, each group streaming only its own compressed tiles."""
     impl = impl or default_impl()
     if impl == "xla":
         return _ref.bitmap_spmm_grouped_ref(x, unshard_bitmap(w))

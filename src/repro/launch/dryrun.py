@@ -58,7 +58,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *,
         bspec_fn = shd.batch_specs(cfg, mesh, shape.global_batch)
         bsh = {k: NamedSharding(mesh, bspec_fn(k)) for k in batch}
         osh = shd.named(mesh, shd.opt_specs(cfg, mesh))
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 step,
                 in_shardings=(psh, osh, bsh),
@@ -71,7 +71,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *,
         batch.pop("targets")
         bspec_fn = shd.batch_specs(cfg, mesh, shape.global_batch)
         bsh = {k: NamedSharding(mesh, bspec_fn(k)) for k in batch}
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 step, in_shardings=(psh, bsh), out_shardings=None,
             ).lower(params, batch)
@@ -92,7 +92,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *,
         tok_sh = NamedSharding(mesh, bspec_fn("tokens"))
         emb_sh = NamedSharding(mesh, bspec_fn("embeds"))
         pos_sh = NamedSharding(mesh, P())
-        with mesh:
+        with jax.set_mesh(mesh):
             if "embeds" in specs:
                 lowered = jax.jit(
                     lambda p, c, e, pos: step(p, c, None, pos, embeds=e),

@@ -4,19 +4,43 @@
 result-shape bytes of every collective op (per-device view).  Wire-traffic
 factors (ring algorithms, large-group limit): all-reduce counts 2×, the
 rest 1×.  ``roofline`` turns (flops, hbm bytes, collective bytes) into the
-three per-device time terms for TPU v5e-class hardware.
+three per-device time terms for a chip named by its ``device_kind``.
 """
 from __future__ import annotations
 
 import re
 from typing import Dict
 
+import jax
+
 from repro.launch.hlo_shapes import shape_bytes
 
-# hardware constants (per chip) — TPU v5e class, from the assignment
-PEAK_FLOPS = 197e12          # bf16 FLOP/s
-HBM_BW = 819e9               # bytes/s
-ICI_BW = 50e9                # bytes/s per link (~ per-direction usable)
+#: ``device_kind`` JAX reports for a TPU v5e chip
+V5E = "TPU v5 lite"
+
+#: Published per-chip peaks keyed by ``jax.Device.device_kind``.
+#: v5e: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, HBM at
+#: 819 GB/s, 1,600 Gbit/s chip-to-chip interconnect.
+PEAKS: Dict[str, Dict[str, float]] = {
+    V5E: {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 1600e9 / 8},
+}
+
+
+def projection_target() -> str:
+    """The ``device_kind`` a roofline projection describes: the chip this
+    process runs on, or — on a CPU run, which has no roofline of its
+    own — the v5e, named explicitly."""
+    dev = jax.devices()[0]
+    return V5E if dev.platform == "cpu" else dev.device_kind
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """The peak table entry for ``device_kind``; a chip the table does
+    not hold is an error, never a default."""
+    if device_kind not in PEAKS:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r} (known: {sorted(PEAKS)})")
+    return PEAKS[device_kind]
 
 _COLL_RE = re.compile(
     r"=\s*(\([^=]*?\)|[a-z0-9]+\[[0-9,]*\][^ ]*)\s+"
@@ -48,11 +72,13 @@ def collective_bytes(hlo_text: str) -> Dict[str, float]:
 
 
 def roofline(flops: float, hbm_bytes: float, wire_bytes: float,
-             num_links: int = 4) -> Dict[str, float]:
-    """Three per-device roofline time terms (seconds) + the bottleneck."""
-    t_compute = flops / PEAK_FLOPS
-    t_memory = hbm_bytes / HBM_BW
-    t_collective = wire_bytes / (ICI_BW * num_links)
+             device_kind: str) -> Dict[str, float]:
+    """Three per-device roofline time terms (seconds) + the bottleneck,
+    against the peaks of ``device_kind``."""
+    pk = peaks(device_kind)
+    t_compute = flops / pk["flops"]
+    t_memory = hbm_bytes / pk["hbm_bw"]
+    t_collective = wire_bytes / pk["ici_bw"]
     terms = {"compute_s": t_compute, "memory_s": t_memory,
              "collective_s": t_collective}
     bound = max(terms, key=terms.get)
@@ -60,6 +86,7 @@ def roofline(flops: float, hbm_bytes: float, wire_bytes: float,
     t_sum = t_compute + t_memory + t_collective
     return {
         **terms,
+        "device_kind": device_kind,
         "bottleneck": bound.replace("_s", ""),
         # fraction of the ideal overlapped step this term would allow
         "roofline_fraction_overlap": t_max / t_sum if t_sum else 0.0,

@@ -13,9 +13,12 @@ Run (CPU example, staggered Poisson arrivals):
 from __future__ import annotations
 
 import argparse
+from typing import List, Sequence, Tuple
 
+import jax
 import numpy as np
 
+from repro.launch import compile_cache
 from repro.serve import FaultPlan, ServeEngine, ServeOverloaded, \
     poisson_trace
 
@@ -44,7 +47,7 @@ def serve(arch: str, smoke: bool = True, batch: int = 4, steps: int = 32,
               f"(head compression {eng.head_compression:.2f}x)")
     rng = np.random.default_rng(seed)
     first = rng.integers(0, eng.cfg.vocab_size, (batch, 1))
-    with eng.mesh:
+    with jax.set_mesh(eng.mesh):
         reqs = [eng.submit([int(first[b, 0])], max_new_tokens=steps)
                 for b in range(batch)]
         rep = eng.run()
@@ -55,11 +58,42 @@ def serve(arch: str, smoke: bool = True, batch: int = 4, steps: int = 32,
             "report": rep}
 
 
+def make_trace(eng: ServeEngine, requests: int, rate: float, seed: int,
+               prompt_len: Tuple[int, int] = (1, 4),
+               max_new: Tuple[int, int] = (8, 24)) -> List[dict]:
+    """The seeded Poisson trace ``serve_trace`` drives, clipped so every
+    request fits the engine's ``max_len``."""
+    plen = (min(prompt_len[0], eng.max_len), min(prompt_len[1], eng.max_len))
+    hi = max(1, min(max_new[1], eng.max_len - plen[1] + 1))
+    lo = max(1, min(max_new[0], hi))
+    return poisson_trace(requests, rate=rate, seed=seed,
+                         vocab_size=eng.cfg.vocab_size, prompt_len=plen,
+                         max_new=(lo, hi))
+
+
+def run_trace(eng: ServeEngine, trace: Sequence[dict],
+              temperature: float = 0.0) -> Tuple[dict, int]:
+    """Submit ``trace`` under the engine's mesh and drain it; returns the
+    engine report and the count of arrivals shed at submit."""
+    shed_at_submit = 0
+    with jax.set_mesh(eng.mesh):
+        for spec in trace:
+            try:
+                eng.submit(**spec, temperature=temperature)
+            except ServeOverloaded:
+                # admission control said no — the typed rejection is the
+                # feature, not a failure; count it and keep the trace going
+                shed_at_submit += 1
+        rep = eng.run()
+    return rep, shed_at_submit
+
+
 def serve_trace(arch: str, smoke: bool = True, slots: int = 4,
                 requests: int = 8, rate: float = 0.5, max_len: int = 128,
                 max_new: tuple = (8, 24), sparsity: float = 0.0,
                 head_sparsity: float | None = None, seed: int = 0,
-                model_parallel: int = 1, stream_weights: bool = True,
+                model_parallel: int = 1, devices=None,
+                stream_weights: bool = True,
                 temperature: float = 0.0, top_k: int = 0,
                 paged: bool = False, page_len: int = 16,
                 page_pool_tokens: int | None = None,
@@ -77,6 +111,7 @@ def serve_trace(arch: str, smoke: bool = True, slots: int = 4,
                 verbose: bool = True) -> dict:
     """Continuous-batching mode: seeded Poisson arrivals into the engine.
 
+    ``devices`` (default: all) are the devices the engine's mesh spans.
     ``head_sparsity`` defaults to ``sparsity`` (the serving regime: the
     LM head is per-tensor pruned before bitmap packing); pass 0.0 to
     stream the exact dense head.  ``stream_weights=False`` serves a
@@ -115,6 +150,7 @@ def serve_trace(arch: str, smoke: bool = True, slots: int = 4,
                                 max_len=max_len, sparsity=sparsity,
                                 head_sparsity=head_sparsity,
                                 seed=seed, model_parallel=model_parallel,
+                                devices=devices,
                                 stream_weights=stream_weights,
                                 bitmap_head=stream_weights, top_k=top_k,
                                 paged=paged, page_len=page_len,
@@ -131,22 +167,8 @@ def serve_trace(arch: str, smoke: bool = True, slots: int = 4,
                                 events_out=events_out,
                                 metrics_out=metrics_out,
                                 traffic_out=traffic_out)
-    prompt_len = (1, min(4, max_len))
-    hi = max(1, min(max_new[1], max_len - prompt_len[1] + 1))
-    lo = max(1, min(max_new[0], hi))
-    trace = poisson_trace(requests, rate=rate, seed=seed,
-                          vocab_size=eng.cfg.vocab_size,
-                          prompt_len=prompt_len, max_new=(lo, hi))
-    shed_at_submit = 0
-    with eng.mesh:
-        for spec in trace:
-            try:
-                eng.submit(**spec, temperature=temperature)
-            except ServeOverloaded:
-                # admission control said no — the typed rejection is the
-                # feature, not a failure; count it and keep the trace going
-                shed_at_submit += 1
-        rep = eng.run()
+    trace = make_trace(eng, requests, rate, seed, max_new=max_new)
+    rep, shed_at_submit = run_trace(eng, trace, temperature)
     for path in eng.close():
         if verbose:
             print(f"telemetry written: {path}")
@@ -339,6 +361,7 @@ def main():
     ap.add_argument("--model-parallel", type=int, default=1)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    compile_cache.enable()
     faults = (FaultPlan.chaos(seed=args.chaos_seed)
               if args.chaos_seed is not None else None)
     serve_trace(args.arch, smoke=args.smoke, slots=args.slots,

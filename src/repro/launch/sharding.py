@@ -268,7 +268,6 @@ def bitmap_specs(bw: Optional[BitmapWeight], mesh) -> Any:
         bw,
         packed_bits=spec(bw.packed_bits, _TILE_ND["packed_bits"]),
         values=spec(bw.values, _TILE_ND["values"]),
-        row_start=spec(bw.row_start, _TILE_ND["row_start"]),
         dense_cache=spec(bw.dense_cache, _TILE_ND["dense_cache"]))
 
 

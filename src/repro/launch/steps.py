@@ -11,7 +11,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map_nocheck
 from repro.launch.sharding import bitmap_sharded, bitmap_specs, packed_specs
 from repro.models.config import ModelConfig
 from repro.models.model import (decode_step, forward, lm_head_weight,
@@ -330,8 +329,9 @@ def build_serve_step_spmd(cfg: ModelConfig, mesh,
                 top_ks=top_ks, page_tables=page_tables)
             return nxt, logits, _slice_cache(new_cache, pools, ndata)
 
-        return shard_map_nocheck(body, mesh, in_specs,
-                                 (P(), P(), cspecs))(*args)
+        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                             out_specs=(P(), P(), cspecs),
+                             check_vma=False)(*args)
 
     return serve_step
 
@@ -364,7 +364,8 @@ def build_prefill_step_spmd(cfg: ModelConfig, mesh,
                 page_tables=page_tables)
             return hidden, _slice_cache(new_cache, pools, ndata)
 
-        return shard_map_nocheck(body, mesh, in_specs,
-                                 (P(), cspecs))(*args)
+        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                             out_specs=(P(), cspecs),
+                             check_vma=False)(*args)
 
     return prefill_step

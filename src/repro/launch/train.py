@@ -70,7 +70,7 @@ def train(arch: str, smoke: bool = True, steps: int = 50, batch: int = 8,
     opt_state = jax.device_put(opt_state, ospecs)
 
     step_fn = build_train_step(cfg, opt_cfg, prune_masks=masks)
-    with mesh:
+    with jax.set_mesh(mesh):
         jit_step = jax.jit(step_fn, donate_argnums=(0, 1))
 
         data_cfg = DataConfig(global_batch=batch, seq_len=seq, seed=seed)

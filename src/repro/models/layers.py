@@ -425,9 +425,12 @@ def matmul_or_bitmap(h: jax.Array, w: jax.Array, bw, impl) -> jax.Array:
     """One projection: dense ``h @ w`` unless a packed ``BitmapWeight`` is
     provided, in which case the matmul streams the compressed form through
     ``kernels/ops.bitmap_spmm`` (xla ref on CPU, Pallas on TPU) — packing
-    is lossless, so the two paths are numerically identical."""
+    is lossless, and the dense branch spells the matmul exactly as the
+    kernel's reference does (f32 accumulation, one rounding to the
+    activation dtype), so the two paths are numerically identical."""
     if bw is None:
-        return h @ w.astype(h.dtype)
+        return jnp.dot(h, w.astype(h.dtype),
+                       preferred_element_type=jnp.float32).astype(h.dtype)
     from repro.kernels import ops  # lazy: layers must not import kernels
     return ops.bitmap_spmm(h, bw, impl=impl)
 

@@ -715,15 +715,8 @@ def head_logits(params: Dict, cfg: ModelConfig, hidden: jax.Array,
     serving engine packs the head once and streams it compressed, so the
     dominant decode weight-traffic term runs the paper's format end-to-end.
     """
-    if lm_weight is None:
-        w = lm_head_weight(params, cfg).astype(hidden.dtype)
-        logits = (hidden @ w).astype(jnp.float32)
-    else:
-        from repro.kernels import ops
-        # the kernel's small-M path handles decode batches below the
-        # 128-row tile (rows round up to the sublane multiple, not 128)
-        logits = ops.bitmap_spmm(hidden, lm_weight,
-                                 impl=lm_impl).astype(jnp.float32)
+    logits = L.matmul_or_bitmap(hidden, lm_head_weight(params, cfg),
+                                lm_weight, lm_impl).astype(jnp.float32)
     from repro.models.perf_flags import baseline_mode
     if not baseline_mode():
         # §Perf: keep decode logits vocab-sharded — otherwise GSPMD

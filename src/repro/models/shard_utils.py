@@ -1,9 +1,11 @@
 """Activation-sharding hints that degrade to no-ops off-mesh.
 
-``hint(x, *axes)`` applies ``with_sharding_constraint`` when tracing inside
-a mesh context, silently dropping axes the mesh doesn't have or that don't
-divide the dim — so model code can carry production sharding annotations
-while remaining runnable on a single CPU device (smoke tests, examples).
+``hint(x, *axes)`` applies ``with_sharding_constraint`` when tracing under
+a mesh set with ``jax.set_mesh``, dropping axes the mesh doesn't have as
+``Auto`` axes (inside ``shard_map`` they are ``Manual``: the per-device
+layout is already explicit) or that don't divide the dim — so model code
+can carry production sharding annotations while remaining runnable on a
+single CPU device (smoke tests, examples).
 
 Axis conventions (launch/sharding.py): "batch" expands to ("pod","data");
 "model" is tensor parallel; None replicates.
@@ -16,45 +18,23 @@ import jax
 from jax.sharding import PartitionSpec as P
 
 
-def _current_mesh():
-    try:
-        env = jax.interpreters.pxla.thread_resources.env
-        mesh = env.physical_mesh
-        if mesh.empty:
-            return None
-        return mesh
-    except Exception:
-        return None
-
-
-def _manual_axes(mesh) -> bool:
-    """True when tracing inside ``shard_map`` over this mesh — its axes
-    are *manual* there, so a with_sharding_constraint naming them is
-    both an error and pointless (the per-device layout is explicit)."""
-    try:
-        from jax._src import core
-        bound = core.get_axis_env().axis_sizes
-        return any(a in bound for a in mesh.axis_names)
-    except Exception:
-        return False
-
-
 def hint(x: jax.Array, *axes: Optional[str]) -> jax.Array:
     """Constrain dim i of x to axis names axes[i] ("batch"/"model"/None)."""
-    mesh = _current_mesh()
-    if mesh is None or _manual_axes(mesh):
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = set(mesh.auto_axes) if not mesh.empty else set()
+    if not auto:
         return x
     spec = []
     for dim, ax in zip(x.shape, axes):
         if ax == "batch":
             names: Tuple[str, ...] = tuple(
-                a for a in ("pod", "data") if a in mesh.axis_names)
+                a for a in ("pod", "data") if a in auto)
             size = 1
             for a in names:
                 size *= mesh.shape[a]
             spec.append(names if names and dim % size == 0 and dim > 1
                         else None)
-        elif ax is not None and ax in mesh.axis_names:
+        elif ax is not None and ax in auto:
             spec.append(ax if dim % mesh.shape[ax] == 0 else None)
         else:
             spec.append(None)

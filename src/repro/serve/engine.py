@@ -77,7 +77,8 @@ from repro.serve.cache import SlotKVCache
 from repro.serve.errors import (DeadlineExceeded, RequestRejected,
                                 ServeOverloaded)
 from repro.serve.faults import FaultPlan, InvariantAuditor
-from repro.serve.packed import PackedModel, choose_block, pack_model
+from repro.serve.packed import (PackedModel, block_reason, choose_block,
+                                pack_model)
 from repro.serve.paging import OutOfPages, PagedKVCache
 from repro.serve.prefill import PrefillPlanner
 from repro.serve.request import Request, RequestState
@@ -89,15 +90,17 @@ from repro.sparse.pruning import global_l1_prune, per_tensor_prune, \
     sparsity_of
 
 
-def _head_block(d_model: int, vocab: int,
+def _head_block(d_model: int, vocab: int, chip: bool = False,
                 cap: int = 128) -> Optional[Tuple[int, int]]:
-    """Largest (BK, BN) bitmap tile that divides the head; BN % 8 == 0."""
-    return choose_block(d_model, vocab, cap)
+    """Largest (BK, BN) bitmap tile that divides the head (BN % 8 == 0)
+    and, for the compiled TPU kernel (``chip``), that the chip takes."""
+    block = choose_block(d_model, vocab, cap)
+    return None if block_reason(d_model, vocab, block, chip) else block
 
 
 def pack_lm_head(params, cfg: ModelConfig, sparsity: float = 0.0,
-                 cache_dense: bool = False,
-                 shards: int = 1) -> Optional[BitmapWeight]:
+                 cache_dense: bool = False, shards: int = 1,
+                 chip: bool = False) -> Optional[BitmapWeight]:
     """Prune (per-tensor) + pack the (D, V) LM head once for serving.
 
     ``shards > 1`` asks for the vocab-split (column-parallel) sharded
@@ -106,7 +109,7 @@ def pack_lm_head(params, cfg: ModelConfig, sparsity: float = 0.0,
     device stores 1/S of the packed head.  Falls back to the replicated
     pack (``shard=None`` — the caller records the typed reason) when the
     vocab doesn't divide or no per-shard tile fits."""
-    block = _head_block(cfg.d_model, cfg.vocab_size)
+    block = _head_block(cfg.d_model, cfg.vocab_size, chip)
     if block is None:
         return None
     w = lm_head_weight(params, cfg)
@@ -114,7 +117,7 @@ def pack_lm_head(params, cfg: ModelConfig, sparsity: float = 0.0,
         w = per_tensor_prune(w, sparsity)
     wf = np.asarray(w.astype(jnp.float32))
     if shards > 1 and cfg.vocab_size % shards == 0:
-        sblock = _head_block(cfg.d_model, cfg.vocab_size // shards)
+        sblock = _head_block(cfg.d_model, cfg.vocab_size // shards, chip)
         if sblock is not None:
             bw = pack_bitmap(wf, block=sblock, cache_dense=cache_dense)
             return shard_bitmap(bw, shards, "col")
@@ -127,6 +130,7 @@ class ServeEngine:
     def __init__(self, cfg: ModelConfig, *, num_slots: int = 4,
                  max_len: int = 128, sparsity: float = 0.0, seed: int = 0,
                  model_parallel: int = 1, impl: Optional[str] = None,
+                 devices: Optional[Sequence] = None,
                  bitmap_head: bool = True,
                  head_sparsity: Optional[float] = None,
                  stream_weights: bool = True, top_k: int = 0,
@@ -144,7 +148,13 @@ class ServeEngine:
                  events_out: Optional[str] = None,
                  metrics_out: Optional[str] = None,
                  traffic_out: Optional[str] = None):
-        """``head_sparsity``: ``global_l1_prune`` deliberately keeps
+        """``devices``: the devices the engine's (data, model) mesh spans
+        (default: every visible device — more than ``model_parallel``
+        of them adds a data axis and routes the steps through
+        ``shard_map``); pass ``jax.devices()[:1]`` to hold a one-chip
+        engine to one chip on a multi-chip host.
+
+        ``head_sparsity``: ``global_l1_prune`` deliberately keeps
         (tied) embeddings dense, so the LM head is additionally pruned
         per-tensor to this level before packing — that is what gives the
         bitmap head its compression at serve time.  Defaults to
@@ -274,7 +284,7 @@ class ServeEngine:
         self.num_slots = num_slots
         self.max_len = max_len
         self.sparsity = sparsity
-        self.mesh = make_elastic_mesh(model_parallel)
+        self.mesh = make_elastic_mesh(model_parallel, devices)
         # fallback bookkeeping: every recorded reason lands in
         # ``self.fallbacks`` (mirrored by report()["fallbacks"]) and is
         # warned at most once per (key, reason) per engine instance
@@ -291,9 +301,13 @@ class ServeEngine:
         # pack once, cache on the engine: every decode step streams the
         # stack + head through the bitmap-compressed kernels/ops path.
         # On the xla (non-TPU) dispatch the pack also renders the dense
-        # oracle view, so serving pays no per-step software decompression.
+        # oracle view, so serving pays no per-step software decompression;
+        # on the compiled TPU kernel a tile the chip refuses serves dense
+        # with a typed reason.
         from repro.kernels.ops import default_impl
-        cache_dense = (impl or default_impl()) == "xla"
+        impl = impl or default_impl()
+        cache_dense = impl == "xla"
+        chip = impl == "pallas"
         self.stream_fallback: Optional[str] = None
         mp_actual = int(self.mesh.shape.get("model", 1))
         self.model_parallel = mp_actual
@@ -309,7 +323,7 @@ class ServeEngine:
             self.fallbacks["stream"] = self.stream_fallback
         self.packed: Optional[PackedModel] = (
             pack_model(self.params, cache_dense=cache_dense,
-                       shards=(mp_actual if self._spmd else 1))
+                       shards=(mp_actual if self._spmd else 1), chip=chip)
             if stream_weights else None)
         if self._spmd and self.packed is not None:
             # place each sharded leaf's shard axis on its own model-axis
@@ -327,7 +341,7 @@ class ServeEngine:
             self.lm_weight = pack_lm_head(
                 self.params, cfg, self.head_sparsity,
                 cache_dense=cache_dense,
-                shards=(mp_actual if self._spmd else 1))
+                shards=(mp_actual if self._spmd else 1), chip=chip)
             if (self._spmd and mp_actual > 1
                     and self.lm_weight is not None
                     and self.lm_weight.shard is None):
@@ -345,10 +359,12 @@ class ServeEngine:
                     shd.named(self.mesh,
                               shd.bitmap_specs(self.lm_weight, self.mesh)))
             if self.lm_weight is None:
+                why = block_reason(
+                    cfg.d_model, cfg.vocab_size,
+                    choose_block(cfg.d_model, cfg.vocab_size), chip)
                 self.head_fallback = (
-                    f"no (BK, BN) tile divides (d_model={cfg.d_model}, "
-                    f"vocab={cfg.vocab_size}) with BN % 8 == 0; "
-                    f"head served dense")
+                    f"LM head (d_model={cfg.d_model}, "
+                    f"vocab={cfg.vocab_size}): {why}; head served dense")
                 self._warn_fallback(
                     "head", self.head_fallback,
                     f"bitmap LM head fell back to dense: "
@@ -1127,6 +1143,20 @@ class ServeEngine:
                 # before anything reads it
                 self._pos[slot] = self.planner.next_pos(int(slot))
         self._c_prefill_steps.inc()
+
+    def probe_logits(self, tokens: Sequence[int]) -> np.ndarray:
+        """(num_slots, V) logits of one decode step feeding ``tokens``
+        (one per slot) at position 0, through the engine's own jitted
+        step — packed or dense as configured.  A correctness probe: like
+        ``warmup``'s throwaway steps, it writes only position 0 of idle
+        slots, which admission zeroes again."""
+        tok = jnp.asarray(np.asarray(tokens, np.int32).reshape(-1, 1))
+        if tok.shape[0] != self.num_slots:
+            raise ValueError(f"probe_logits takes one token per slot "
+                             f"({self.num_slots}), got {tok.shape[0]}")
+        _, logits, self.kv.cache = self._decode(
+            tok, jnp.zeros(self.num_slots, jnp.int32))
+        return np.asarray(logits)
 
     def warmup(self) -> None:
         """Compile the decode step + slot reset before the latency clock

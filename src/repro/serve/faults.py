@@ -256,10 +256,10 @@ class FaultPlan:
 
 
 def _checksum(bw) -> int:
-    """CRC32 over a BitmapWeight's canonical arrays (bits + values +
-    row starts); ``dense_cache`` is a derived rendering and excluded."""
+    """CRC32 over a BitmapWeight's canonical arrays (bits + values);
+    ``dense_cache`` is a derived rendering and excluded."""
     crc = 0
-    for arr in (bw.packed_bits, bw.values, bw.row_start):
+    for arr in (bw.packed_bits, bw.values):
         crc = zlib.crc32(np.asarray(arr).tobytes(), crc)
     return crc
 

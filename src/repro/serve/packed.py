@@ -106,6 +106,21 @@ def choose_block(k: int, n: int, cap: int = 128
     return bk, bn
 
 
+def block_reason(k: int, n: int, block: Optional[Tuple[int, int]],
+                 chip: bool) -> str:
+    """Why a (k, n) operand tiled ``block`` cannot stream packed ("" when
+    it can): no tile at all, or — for the compiled TPU kernel (``chip``)
+    — a tile the chip's tiling rules refuse."""
+    if block is None:
+        return f"no (BK, BN) tile divides ({k}, {n}) with BN % 8"
+    if chip:
+        from repro.kernels.bitmap_spmm import tile_fits_chip
+        why = tile_fits_chip(k, n, block)
+        if why:
+            return f"tile {block} of ({k}, {n}) does not fit the TPU: {why}"
+    return ""
+
+
 @dataclasses.dataclass
 class PackEntry:
     """Manifest row: one tensor's pack decision + modeled per-step bytes.
@@ -280,8 +295,17 @@ def _shard_block(comp: str, name: str, k: int, n: int, cap: int,
     return block, (mode, shards), ""
 
 
+def _local_dims(k: int, n: int, shard: Optional[Tuple[str, int]]
+                ) -> Tuple[int, int]:
+    """The (K, N) extent one shard's kernel call sees."""
+    if shard is None:
+        return k, n
+    mode, s = shard
+    return (k, n // s) if mode == "col" else (k // s, n)
+
+
 def _pack_leaf(path: str, comp: str, name: str, w, cap: int,
-               cache_dense: bool, shards: int = 1
+               cache_dense: bool, shards: int = 1, chip: bool = False
                ) -> Tuple[PackEntry, Optional[BitmapWeight]]:
     arr = np.asarray(w)
     dense_bytes = arr.size * arr.dtype.itemsize
@@ -305,9 +329,9 @@ def _pack_leaf(path: str, comp: str, name: str, w, cap: int,
         _, g, k, n = arr.shape
         block, shard, shard_reason = _shard_block(comp, name, k, n, cap,
                                                   shards)
-        if block is None:
-            return fallback(
-                f"no (BK, BN) tile divides ({k}, {n}) with BN % 8")
+        reason = block_reason(*_local_dims(k, n, shard), block, chip)
+        if reason:
+            return fallback(reason)
         bw = pack_bitmap_experts(arr, block=block, cache_dense=cache_dense)
         if shard is not None:
             bw = shard_bitmap(bw, shard[1], shard[0])
@@ -325,8 +349,9 @@ def _pack_leaf(path: str, comp: str, name: str, w, cap: int,
         return fallback(f"not a 2-D projection (ndim={arr.ndim - 1})")
     _, k, n = arr.shape
     block, shard, shard_reason = _shard_block(comp, name, k, n, cap, shards)
-    if block is None:
-        return fallback(f"no (BK, BN) tile divides ({k}, {n}) with BN % 8")
+    reason = block_reason(*_local_dims(k, n, shard), block, chip)
+    if reason:
+        return fallback(reason)
     bw = pack_bitmap_stacked(arr, block=block, cache_dense=cache_dense)
     if shard is not None:
         bw = shard_bitmap(bw, shard[1], shard[0])
@@ -339,7 +364,8 @@ def _pack_leaf(path: str, comp: str, name: str, w, cap: int,
 
 
 def pack_model(params: Dict, cap: int = 128,
-               cache_dense: bool = False, shards: int = 1) -> PackedModel:
+               cache_dense: bool = False, shards: int = 1,
+               chip: bool = False) -> PackedModel:
     """Pack every dispatchable serve-time GEMM operand of ``params``.
 
     Packing is lossless (per-tensor budget = max tile non-zero count), so
@@ -351,6 +377,10 @@ def pack_model(params: Dict, cap: int = 128,
     the xla oracle dispatch (decompression is a pack-time cost off-TPU;
     it never counts toward the modeled HBM bytes) — the engine enables
     it when the resolved kernel impl is "xla".
+
+    ``chip`` (the engine sets it when the compiled TPU kernel serves)
+    sends every tensor whose tile the chip's tiling rules refuse
+    (``kernels.bitmap_spmm.tile_fits_chip``) to a typed dense fallback.
 
     ``shards > 1`` packs every TP-ruled tensor (``launch.sharding``'s
     PACKED_COL/PACKED_ROW) with an explicit shard axis so each
@@ -367,7 +397,7 @@ def pack_model(params: Dict, cap: int = 128,
             for name, w in tensors.items():
                 path = f"blocks/{bname}/{comp}/{name}"
                 entry, bw = _pack_leaf(path, comp, name, w, cap, cache_dense,
-                                       shards)
+                                       shards, chip)
                 manifest.append(entry)
                 packed_c[name] = bw
             packed_b[comp] = packed_c

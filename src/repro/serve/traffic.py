@@ -55,7 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.energy import NUM_MACS, energy_dataflow, tops_per_watt
-from repro.launch.hlo_analysis import roofline
+from repro.launch.hlo_analysis import projection_target, roofline
 from repro.launch.hlo_counters import analyze as hlo_analyze
 from repro.models.model import attn_capacity
 from repro.serve.packed import (ROUTED_EXPERT, activated_scale,
@@ -307,10 +307,11 @@ class TrafficLedger:
         }
 
     def _roofline(self) -> Dict[str, Dict]:
-        """Place each phase on the v5e roofline: measured per-step bytes
-        (modeled per-step stream before any step has run) against the
-        phase's useful FLOPs."""
+        """Place each phase on the roofline of ``projection_target()``:
+        measured per-step bytes (modeled per-step stream before any step
+        has run) against the phase's useful FLOPs."""
         eng = self.eng
+        chip = projection_target()
         sparse, dense, stack_sparse = self._totals()
         roles = self.per_role()
         macs_tok = dense / np.dtype(np.float32).itemsize
@@ -321,7 +322,8 @@ class TrafficLedger:
             b = sum(self._phase_bytes("decode").values()) / dec
         else:
             b = float(sparse + eng.num_slots * self._line_total)
-        out["decode"] = roofline(2.0 * macs_tok * eng.num_slots, b, 0.0)
+        out["decode"] = roofline(2.0 * macs_tok * eng.num_slots, b, 0.0,
+                                 chip)
         pre = eng._c_prefill_steps.value
         if pre > 0:
             pb = sum(self._phase_bytes("prefill").values()) / pre
@@ -330,7 +332,7 @@ class TrafficLedger:
                 / (self._line_total * pre) if self._line_total else
                 float(eng.prefill_chunk * eng.num_slots))
             pf = 2.0 * (stack_dense / 4.0) * tok_per_call
-            out["prefill"] = roofline(pf, pb, 0.0)
+            out["prefill"] = roofline(pf, pb, 0.0, chip)
         return out
 
     # -------------------------------------------------------- crosscheck ----
@@ -447,7 +449,8 @@ class TrafficLedger:
         for phase in phases:
             lo, hi = bands[phase]
             compiled = self._lowered(phase).compile()
-            counted = hlo_analyze(compiled.as_text())
+            text = compiled.as_text()
+            counted = hlo_analyze(text)
             modeled = self.modeled_executed(phase)
             ratio = (counted["bytes"] / modeled["total_bytes"]
                      if modeled["total_bytes"] else float("nan"))
@@ -458,6 +461,9 @@ class TrafficLedger:
                 "ratio": float(ratio),
                 "tolerance": [float(lo), float(hi)],
                 "within_band": bool(lo <= ratio <= hi),
+                # compiled Pallas kernel launches in the step program
+                "tpu_custom_calls": text.count(
+                    'custom_call_target="tpu_custom_call"'),
             }
         self._crosscheck = out
         return out

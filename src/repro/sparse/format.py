@@ -3,10 +3,11 @@
 Two storage formats, both jax pytrees so they flow through jit/pjit:
 
 * ``BitmapWeight`` — the paper's bitmap format at VMEM-tile granularity:
-  per (BK×BN) tile a packed bitmap (1 bit/element), packed non-zero values
-  (row-major, padded to a per-tile budget) and per-row start offsets
-  (the host-side half of EIM: the ``row_start + rank`` decompression the
-  kernel performs is exactly the IMId/masked-bitmap re-sort of §II-C).
+  per (BK×BN) tile a bitmap (1 bit/element, packed 32 rows to an int32
+  word) and the tile's non-zero values, row-major, in BN-wide rows.  The
+  EIM re-sort (§II-C: a value's slot is the number of set bits before it)
+  runs inside the kernel as prefix-count matmuls on the bitmap, so no
+  per-row offsets are stored.
   HBM bytes ≈ density·data + 1/8·bitmap ⇒ ~3.2× traffic cut at 75 % sparsity.
 
 * ``BlockSparseWeight`` — coarse-grain: all-zero (BK×BN) blocks are dropped
@@ -28,6 +29,11 @@ import jax.numpy as jnp
 import numpy as np
 
 
+#: bitmap rows packed into one int32 word (bit ``r % 32`` of word
+#: ``r // 32`` in each column)
+WORD_ROWS = 32
+
+
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class BitmapWeight:
@@ -43,9 +49,8 @@ class BitmapWeight:
     describes the compressed stream the Pallas kernel actually fetches.
     """
 
-    packed_bits: jax.Array   # (KT, NT, BK, BN // 8) uint8
-    values: jax.Array        # (KT, NT, budget) dtype, row-major packed
-    row_start: jax.Array     # (KT, NT, BK) int32 — first value slot per row
+    packed_bits: jax.Array   # (KT, NT, ceil(BK/32), BN) int32 bit words
+    values: jax.Array        # (KT, NT, Q, BN) dtype, row-major packed
     shape: Tuple[int, int] = dataclasses.field(metadata=dict(static=True))
     block: Tuple[int, int] = dataclasses.field(metadata=dict(static=True))
     dense_cache: jax.Array | None = None    # (K, N) oracle-path rendering
@@ -59,21 +64,22 @@ class BitmapWeight:
 
     @property
     def budget(self) -> int:
-        return self.values.shape[-1]
+        """Value slots per tile (the max tile non-zero count, rounded up
+        to whole BN-wide rows: a tile row's at most BN values then span
+        at most two stored rows, which is what the kernel gathers from)."""
+        return self.values.shape[-2] * self.values.shape[-1]
 
     @property
     def hbm_bytes(self) -> int:
         return (self.packed_bits.size * self.packed_bits.dtype.itemsize
-                + self.values.size * self.values.dtype.itemsize
-                + self.row_start.size * self.row_start.dtype.itemsize)
+                + self.values.size * self.values.dtype.itemsize)
 
     @property
     def dense_bytes(self) -> int:
         # stacked weights (pack_bitmap_stacked: leading P axis;
         # pack_bitmap_experts: leading (P, E) axes) carry extra leading
         # dims on the arrays while `shape` stays per-matrix — count them
-        stacks = math.prod(self.values.shape[:-3]) if self.values.ndim > 3 \
-            else 1
+        stacks = math.prod(self.values.shape[:-4])
         if self.shard is not None:
             # the explicit shard axis inflates the leading dims but the
             # S shards together hold exactly one logical matrix
@@ -84,6 +90,18 @@ class BitmapWeight:
     @property
     def compression(self) -> float:
         return self.dense_bytes / self.hbm_bytes
+
+
+def _pack_words(bits: np.ndarray) -> np.ndarray:
+    """(..., BK, BN) bool -> (..., ceil(BK/32), BN) int32 bit words."""
+    bk = bits.shape[-2]
+    nw = -(-bk // WORD_ROWS)
+    pad = [(0, 0)] * (bits.ndim - 2) + [(0, nw * WORD_ROWS - bk), (0, 0)]
+    b = np.pad(bits, pad).reshape(bits.shape[:-2] + (nw, WORD_ROWS,
+                                                     bits.shape[-1]))
+    shifts = np.arange(WORD_ROWS, dtype=np.uint32)[:, None]
+    words = (b.astype(np.uint32) << shifts).sum(axis=-2, dtype=np.uint32)
+    return words.view(np.int32)
 
 
 def pack_bitmap(w, block: Tuple[int, int] = (128, 128),
@@ -97,7 +115,8 @@ def pack_bitmap(w, block: Tuple[int, int] = (128, 128),
     in DESIGN.md.  Default budget = measured max tile density.  An explicit
     ``budget`` (≥ the max tile non-zero count — packing is then lossless)
     lets callers share one value-slot budget across several packs, e.g. the
-    period-stacked pack below.
+    period-stacked pack below.  The stored slot count rounds the budget up
+    to whole BN-wide rows.
     """
     w = np.asarray(w)
     k, n = w.shape
@@ -126,42 +145,39 @@ def pack_bitmap(w, block: Tuple[int, int] = (128, 128),
             keep &= flat > 0
             tiles = tiles * keep.reshape(tiles.shape)
             bits = tiles != 0
-    budget = max(budget, 1)
+            per_tile = bits.reshape(kt, nt, -1).sum(-1)
+    q = max(1, -(-budget // bn))
 
-    flat_bits = bits.reshape(kt, nt, bk, bn)
-    row_nnz = flat_bits.sum(-1)
-    row_start = np.zeros((kt, nt, bk), np.int32)
-    row_start[:, :, 1:] = np.cumsum(row_nnz, -1)[:, :, :-1]
+    # a tile's non-zeros in row-major order fill its slots 0, 1, ...: the
+    # slot of element (r, c) is the count of set bits before it in the tile
+    nz = tiles[bits]                           # every tile's, tile-major
+    counts = per_tile.reshape(-1)
+    starts = np.cumsum(counts) - counts
+    tile_of = np.repeat(np.arange(kt * nt), counts)
+    slot = np.arange(nz.size) - np.repeat(starts, counts)
+    values = np.zeros((kt * nt, q * bn), w.dtype)
+    values[tile_of, slot] = nz
 
-    ranks = np.cumsum(flat_bits, -1) - 1
-    slot = row_start[..., None] + ranks
-    values = np.zeros((kt, nt, budget), w.dtype)
-    i0, i1, i2, i3 = np.nonzero(flat_bits)
-    values[i0, i1, slot[i0, i1, i2, i3]] = tiles[i0, i1, i2, i3]
-
-    packed = np.packbits(flat_bits, axis=-1, bitorder="little")
     dense = (jnp.asarray(tiles.transpose(0, 2, 1, 3).reshape(k, n))
              if cache_dense else None)
     return BitmapWeight(
-        packed_bits=jnp.asarray(packed),
-        values=jnp.asarray(values),
-        row_start=jnp.asarray(row_start),
+        packed_bits=jnp.asarray(_pack_words(bits)),
+        values=jnp.asarray(values.reshape(kt, nt, q, bn)),
         shape=(k, n), block=(bk, bn), dense_cache=dense)
 
 
 def unpack_bitmap(bw: BitmapWeight) -> jax.Array:
-    """Pure-jnp decompression oracle (mirrors the in-kernel EIM re-sort)."""
-    kt, nt, bk, bnb = bw.packed_bits.shape
-    bn = bnb * 8
-    shifts = jnp.arange(8, dtype=jnp.uint8)
-    bits = (bw.packed_bits[..., None] >> shifts) & 1        # (KT,NT,BK,BN/8,8)
-    bits = bits.reshape(kt, nt, bk, bn).astype(jnp.int32)
-    rank = jnp.cumsum(bits, -1) - 1
-    idx = jnp.clip(bw.row_start[..., None] + rank, 0, bw.budget - 1)
-    vals = jnp.take_along_axis(
-        bw.values[:, :, None, :], idx.reshape(kt, nt, bk * bn)[:, :, None, :],
-        axis=-1).reshape(kt, nt, bk, bn)
-    dense_tiles = jnp.where(bits != 0, vals, 0)
+    """Pure-jnp decompression oracle (the EIM re-sort the kernel runs:
+    a value's slot is the count of set bits before it in its tile)."""
+    kt, nt, nw, bn = bw.packed_bits.shape
+    bk = bw.block[0]
+    shifts = jnp.arange(WORD_ROWS, dtype=jnp.int32)[:, None]
+    bits = (bw.packed_bits[:, :, :, None, :] >> shifts) & 1  # (..,NW,32,BN)
+    bits = bits.reshape(kt, nt, nw * WORD_ROWS, bn)[:, :, :bk]
+    flat = bits.reshape(kt, nt, bk * bn)
+    slot = jnp.clip(jnp.cumsum(flat, -1) - 1, 0, bw.budget - 1)
+    vals = jnp.take_along_axis(bw.values.reshape(kt, nt, -1), slot, axis=-1)
+    dense_tiles = jnp.where(flat != 0, vals, 0).reshape(kt, nt, bk, bn)
     return dense_tiles.transpose(0, 2, 1, 3).reshape(bw.shape)
 
 
@@ -190,7 +206,6 @@ def pack_bitmap_stacked(w, block: Tuple[int, int],
     return BitmapWeight(
         packed_bits=jnp.stack([q.packed_bits for q in per]),
         values=jnp.stack([q.values for q in per]),
-        row_start=jnp.stack([q.row_start for q in per]),
         shape=(k, n), block=block,
         dense_cache=(jnp.stack([q.dense_cache for q in per])
                      if cache_dense else None))
@@ -200,12 +215,12 @@ def unpack_bitmap_stacked(bw: BitmapWeight) -> jax.Array:
     """Dense oracle for a stacked ``BitmapWeight``: recurses over every
     leading stack axis (one for period-stacked tensors, two for the
     (P, E) expert layout), returning ``(*stack_axes, K, N)``."""
-    if bw.values.ndim == 3:
+    if bw.values.ndim == 4:
         return unpack_bitmap(bw)
     return jnp.stack([
         unpack_bitmap_stacked(BitmapWeight(
             packed_bits=bw.packed_bits[i], values=bw.values[i],
-            row_start=bw.row_start[i], shape=bw.shape, block=bw.block))
+            shape=bw.shape, block=bw.block))
         for i in range(bw.packed_bits.shape[0])])
 
 
@@ -235,7 +250,6 @@ def pack_bitmap_experts(w, block: Tuple[int, int],
         packed_bits=flat.packed_bits.reshape(
             (p, e) + flat.packed_bits.shape[1:]),
         values=flat.values.reshape((p, e) + flat.values.shape[1:]),
-        row_start=flat.row_start.reshape((p, e) + flat.row_start.shape[1:]),
         shape=(k, n), block=block,
         dense_cache=(flat.dense_cache.reshape(p, e, k, n)
                      if cache_dense else None))
@@ -251,12 +265,12 @@ def unpack_bitmap_experts(bw: BitmapWeight) -> jax.Array:
 # (column-parallel) or K (row-parallel) tile axis is split into S
 # contiguous shard ranges and re-exposed as an explicit shard axis placed
 # immediately before each leaf's tile dims, so a single PartitionSpec axis
-# ('model' at that position) makes every shard's bitmap+values+row_start
+# ('model' at that position) makes every shard's bitmap+values
 # device-local.  ``shape``/``block`` keep the full logical geometry.
 
 #: trailing per-tile dims of each array leaf (the shard axis sits just
 #: before these; leading stack axes — period P, expert E — come first)
-_TILE_ND = {"packed_bits": 4, "values": 3, "row_start": 3, "dense_cache": 2}
+_TILE_ND = {"packed_bits": 4, "values": 4, "dense_cache": 2}
 
 #: offset from ndim of the tile axis being split: col splits the NT axis
 #: (second tile dim — or N itself for dense_cache), row splits KT/K
@@ -306,8 +320,7 @@ def shard_bitmap(bw: BitmapWeight, shards: int, mode: str) -> BitmapWeight:
     return dataclasses.replace(
         bw,
         packed_bits=_split_leaf(bw.packed_bits, 4, mode, shards),
-        values=_split_leaf(bw.values, 3, mode, shards),
-        row_start=_split_leaf(bw.row_start, 3, mode, shards),
+        values=_split_leaf(bw.values, 4, mode, shards),
         dense_cache=_split_leaf(bw.dense_cache, 2, mode, shards),
         shard=(mode, shards))
 
@@ -320,8 +333,7 @@ def unshard_bitmap(bw: BitmapWeight) -> BitmapWeight:
     return dataclasses.replace(
         bw,
         packed_bits=_merge_leaf(bw.packed_bits, 4, mode),
-        values=_merge_leaf(bw.values, 3, mode),
-        row_start=_merge_leaf(bw.row_start, 3, mode),
+        values=_merge_leaf(bw.values, 4, mode),
         dense_cache=_merge_leaf(bw.dense_cache, 2, mode),
         shard=None)
 
@@ -346,8 +358,7 @@ def gather_bitmap(bw: BitmapWeight, axis_name: str) -> BitmapWeight:
     return dataclasses.replace(
         bw,
         packed_bits=g(bw.packed_bits, 4),
-        values=g(bw.values, 3),
-        row_start=g(bw.row_start, 3),
+        values=g(bw.values, 4),
         dense_cache=g(bw.dense_cache, 2),
         shard=None)
 

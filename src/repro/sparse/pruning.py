@@ -6,7 +6,8 @@ per-tensor variant and sparsity accounting helpers.
 """
 from __future__ import annotations
 
-from typing import Any, Callable
+import functools
+from typing import Any, Callable, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +23,63 @@ def _is_prunable(path: tuple, leaf: jax.Array,
     return "embed" not in name  # embeddings stay dense by default
 
 
+@jax.jit
+def _kth_magnitude(leaves: Tuple[jax.Array, ...], k: jax.Array) -> jax.Array:
+    """The k-th smallest (0-based) float32 |w| over every leaf.
+
+    Bisection on the float32 bit pattern (ordered like the value for
+    non-negative floats), counting ``<=`` per leaf: 31 reads of the
+    leaves and no concatenated copy or sort, so a model whose prunable
+    weights fill most of the device memory still fits.
+    """
+    def count_le(p):
+        return sum(jnp.sum(jax.lax.bitcast_convert_type(
+            jnp.abs(l).astype(jnp.float32), jnp.int32) <= p,
+            dtype=jnp.uint32) for l in leaves)
+
+    def step(_, lohi):
+        lo, hi = lohi
+        mid = lo + (hi - lo) // 2
+        ok = count_le(mid) > k
+        return jnp.where(ok, lo, mid + 1), jnp.where(ok, mid, hi)
+
+    lo, _ = jax.lax.fori_loop(0, 31, step,
+                              (jnp.int32(0), jnp.int32(0x7F800000)))
+    return jax.lax.bitcast_convert_type(lo, jnp.float32)
+
+
+def _position(q, n: int):
+    """``jnp.quantile``'s (linear) sample position: the two ranks and
+    their weights, in its float32 arithmetic."""
+    pos = q * (jnp.float32(n) - 1)
+    low, high = jnp.floor(pos), jnp.ceil(pos)
+    high_w = pos - low
+    return (jnp.clip(low, 0, n - 1), jnp.clip(high, 0, n - 1),
+            1 - high_w, high_w)
+
+
+@functools.partial(jax.jit, static_argnames="n")
+def _interpolate(lo_v, hi_v, q, n: int):
+    _, _, low_w, high_w = _position(q, n)
+    return lo_v * low_w + hi_v * high_w
+
+
+def global_threshold(leaves: Sequence[jax.Array],
+                     sparsity: float) -> jax.Array:
+    """``jnp.quantile`` (linear method) of every leaf's |w| taken
+    together, without concatenating them: the two order statistics it
+    interpolates come from ``_kth_magnitude``, and the interpolation is
+    one jitted expression like ``jnp.quantile``'s own, so the threshold
+    is the same float32 number."""
+    n = sum(l.size for l in leaves)
+    q = jnp.float32(sparsity)
+    low, high, _, _ = _position(q, n)
+    leaves = tuple(leaves)
+    lo_v = _kth_magnitude(leaves, jnp.uint32(int(low)))
+    hi_v = _kth_magnitude(leaves, jnp.uint32(int(high)))
+    return _interpolate(lo_v, hi_v, q, n)
+
+
 def global_l1_prune(params: Any, sparsity: float,
                     predicate: Callable | None = None) -> Any:
     """Zero the globally-smallest |w| fraction across all prunable leaves."""
@@ -31,8 +89,7 @@ def global_l1_prune(params: Any, sparsity: float,
     prunable = [(p, l) for p, l in leaves if _is_prunable(p, l, predicate)]
     if not prunable:
         return params
-    mags = jnp.concatenate([jnp.abs(l).reshape(-1) for _, l in prunable])
-    thresh = jnp.quantile(mags.astype(jnp.float32), sparsity)
+    thresh = global_threshold([l for _, l in prunable], sparsity)
 
     flat_paths = {jax.tree_util.keystr(p) for p, _ in prunable}
 

@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map_nocheck
-
 
 def quantize_int8(g: jax.Array) -> Tuple[jax.Array, jax.Array]:
     scale = jnp.max(jnp.abs(g)) / 127.0 + 1e-12
@@ -76,10 +74,11 @@ def compressed_psum_grads(grad_fn: Callable, mesh, axis: str = "data"
         return grads, jax.tree.map(lambda r: r[None], resid)
 
     def wrapped(params, batch, err):
-        return shard_map_nocheck(
+        return jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(), P(axis), P(axis)),
             out_specs=(P(), P(axis)),
+            check_vma=False,
         )(params, batch, err)
 
     return wrapped

@@ -87,6 +87,7 @@ _SPMD_SCRIPT = textwrap.dedent("""
     import numpy as np, jax, jax.numpy as jnp, json
     from repro.configs import get_smoke_config
     from repro.launch import sharding as shd
+    from repro.launch.mesh import make_mesh
     from repro.launch.steps import build_train_step
     from repro.models import init_params
     from repro.train import optimizer as opt_lib
@@ -105,11 +106,11 @@ _SPMD_SCRIPT = textwrap.dedent("""
     p1, o1, m1 = jax.jit(step)(params, opt_state, batch)
 
     # 2x4 (data, model) mesh
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     psh = shd.named(mesh, shd.param_specs(cfg, mesh))
     osh = shd.named(mesh, shd.opt_specs(cfg, mesh))
     bspec = shd.batch_specs(cfg, mesh, 8)
-    with mesh:
+    with jax.set_mesh(mesh):
         pd = jax.device_put(params, psh)
         od = jax.device_put(opt_state, osh)
         bd = {k: jax.device_put(v, jax.NamedSharding(mesh, bspec(k)))
@@ -162,9 +163,8 @@ def test_hlo_counter_collectives():
         mesh = jax.make_mesh((8,), ("x",))
         def f(a):
             return jax.lax.psum(a, "x")
-        from repro.compat import shard_map
-        sf = shard_map(f, mesh=mesh, in_specs=P("x", None),
-                       out_specs=P(None))
+        sf = jax.shard_map(f, mesh=mesh, in_specs=P("x", None),
+                           out_specs=P(None))
         c = jax.jit(sf).lower(
             jax.ShapeDtypeStruct((8, 128), jnp.float32)).compile()
         r = analyze(c.as_text())

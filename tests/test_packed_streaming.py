@@ -112,7 +112,6 @@ def test_expert_pack_roundtrip_properties():
     from hypothesis import given, settings
     from hypothesis import strategies as st
     from repro.kernels import ops
-    from repro.kernels.bitmap_spmm import group_slice
     from repro.sparse.format import (BitmapWeight, pack_bitmap_experts,
                                      unpack_bitmap_experts)
 
@@ -129,18 +128,17 @@ def test_expert_pack_roundtrip_properties():
         np.testing.assert_array_equal(np.asarray(unpack_bitmap_experts(bw)),
                                       w)
         tile_nnz = (w != 0).reshape(p * e, -1).sum(-1)
-        assert bw.budget == max(1, int(tile_nnz.max()))
+        # the budget is the max tile non-zero count in whole BN-wide rows
+        assert bw.budget == -(-max(1, int(tile_nnz.max())) // n) * n
         # grouped dispatch == per-expert dense matmul (one period slice)
         per = BitmapWeight(packed_bits=bw.packed_bits[0],
-                           values=bw.values[0], row_start=bw.row_start[0],
+                           values=bw.values[0],
                            shape=bw.shape, block=bw.block)
         x = r.standard_normal((e, 3, k)).astype(np.float32)
         want = np.einsum("gmk,gkn->gmn", x, w[0])
         got = ops.bitmap_spmm_grouped(x, per, impl="xla")
         np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5,
                                    atol=2e-5)
-        assert np.asarray(group_slice(per, e - 1).values).shape == \
-            bw.values.shape[2:]
 
     check()
 
@@ -150,7 +148,7 @@ def test_expert_pack_roundtrip_properties():
     w *= r.random((3, 64, 32)) >= 0.7
     bw = pack_bitmap_experts(w[None], block=(64, 32))
     per = BitmapWeight(packed_bits=bw.packed_bits[0], values=bw.values[0],
-                       row_start=bw.row_start[0], shape=bw.shape,
+                       shape=bw.shape,
                        block=bw.block)
     x = r.standard_normal((3, 4, 64)).astype(np.float32)
     got = ops.bitmap_spmm_grouped(x, per, impl="pallas_interpret")
@@ -361,3 +359,27 @@ def test_sampling_reproducible_and_greedy_unchanged():
 
     _, s_notrunc = run(top_k=0)
     assert len(s_notrunc) == 6            # top_k=0 path also samples
+
+
+@pytest.mark.parametrize("d_model,d_ff,packs", [(64, 96, False),
+                                                (128, 256, True)],
+                         ids=["sub-lane", "lane-aligned"])
+def test_chip_pack_falls_back_on_tiles_the_tpu_refuses(d_model, d_ff,
+                                                       packs):
+    """Packed for the compiled TPU kernel, a tensor whose tile breaks the
+    chip's tiling rules serves dense with a typed manifest reason, and a
+    lane-aligned one packs; the CPU pack of the same model packs both."""
+    import jax
+    from repro.models.model import init_params
+    cfg = ModelConfig(name="lanes", d_model=d_model, num_layers=2,
+                      num_heads=2, num_kv_heads=2, d_ff=d_ff,
+                      vocab_size=256, pattern=(BlockCfg(mixer="attn"),),
+                      max_seq_len=32)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    gemms = lambda pm: {e.path: e for e in pm.manifest
+                        if "not a GEMM operand" not in e.reason}
+    chip = gemms(pack_model(params, chip=True))
+    assert chip and all(e.packed == packs for e in chip.values())
+    for e in chip.values():
+        assert packs or "does not fit the TPU" in e.reason, e.reason
+    assert all(e.packed for e in gemms(pack_model(params)).values())

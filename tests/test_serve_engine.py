@@ -145,3 +145,38 @@ def test_bitmap_head_is_packed_and_engaged(engine):
     engine.submit([2], max_new_tokens=2)
     engine.run()
     assert engine.kv.resets >= 1
+
+
+# ------------------------------------------------------- serving CLI -------
+
+_CLI_WORKER = """
+import json, os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+import jax
+from repro.launch.serve import serve_trace
+rep = serve_trace("olmo-1b", smoke=True, requests=3, sparsity=0.5,
+                  model_parallel=%MP%, devices=jax.devices()[:%NDEV%],
+                  verbose=False)
+print(json.dumps({"requests": rep["requests"],
+                  "tokens": rep["generated_tokens"]}))
+"""
+
+
+@pytest.mark.parametrize("mp,ndev", [(1, 1), (1, 2), (2, 2)],
+                         ids=["mp1-1dev", "mp1-2dev", "mp2-2dev"])
+def test_serve_trace_runs_under_the_engine_mesh(mp, ndev):
+    """The serving CLI's ``serve_trace`` enters the engine's mesh and
+    serves to completion on fake CPU devices, one-device and sharded —
+    the path that once raised a ShardingTypeError on every run."""
+    import json
+    import os
+    import subprocess
+    import sys
+    script = _CLI_WORKER.replace("%MP%", str(mp)).replace("%NDEV%",
+                                                         str(ndev))
+    out = subprocess.run([sys.executable, "-c", script], capture_output=True,
+                         text=True, timeout=600,
+                         env={**os.environ, "PYTHONPATH": "src"})
+    assert out.returncode == 0, out.stderr[-2000:]
+    res = json.loads(out.stdout.strip().splitlines()[-1])
+    assert res["requests"] == 3 and res["tokens"] > 0, res

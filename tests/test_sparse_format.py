@@ -56,3 +56,19 @@ def test_per_tensor_prune_exact():
     frac = float((pruned == 0).mean())
     assert abs(frac - 0.75) < 0.01
     assert sparsity_of({"w": pruned}) == frac
+
+
+@pytest.mark.parametrize("sparsity", [0.3333, 0.5, 0.75, 0.999])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_global_threshold_is_the_concatenated_quantile(sparsity, dtype):
+    """The global L1 threshold found without concatenating the leaves is
+    the very float32 number ``jnp.quantile`` gives over their
+    concatenation."""
+    from repro.sparse.pruning import global_threshold
+    r = np.random.default_rng(7)
+    leaves = [jnp.asarray(r.standard_normal(s), dtype)
+              for s in [(33, 17), (64, 8), (5, 103)]]
+    want = jnp.quantile(jnp.concatenate(
+        [jnp.abs(l).reshape(-1) for l in leaves]).astype(jnp.float32),
+        sparsity)
+    assert float(global_threshold(leaves, sparsity)) == float(want)

@@ -26,6 +26,7 @@ import pathlib
 import sys
 import time
 
+import jax
 import pytest
 
 from repro.configs import get_smoke_config
@@ -51,7 +52,7 @@ def _run(eng, requests=3, seed=0):
     trace = poisson_trace(requests, rate=0.5, seed=seed,
                           vocab_size=CFG.vocab_size, prompt_len=(1, 4),
                           max_new=(2, 5))
-    with eng.mesh:
+    with jax.set_mesh(eng.mesh):
         for spec in trace:
             eng.submit(**spec)
         rep = eng.run()
@@ -157,7 +158,7 @@ def test_warmup_never_leaks_into_wall(monkeypatch):
     between warmup and the first step (compile tails, test-harness
     sleeps — anything pre-serving) must not appear in ``wall_s``."""
     eng = _engine()
-    with eng.mesh:
+    with jax.set_mesh(eng.mesh):
         eng.warmup()
         time.sleep(0.3)            # pre-serving dead time
         assert not eng._clock.started

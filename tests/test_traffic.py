@@ -25,6 +25,7 @@ import pathlib
 import subprocess
 import sys
 
+import jax
 import pytest
 
 from repro.configs import get_smoke_config
@@ -48,7 +49,7 @@ def _run(eng, requests=3, seed=0):
     trace = poisson_trace(requests, rate=0.5, seed=seed,
                           vocab_size=eng.cfg.vocab_size,
                           prompt_len=(1, 4), max_new=(2, 5))
-    with eng.mesh:
+    with jax.set_mesh(eng.mesh):
         for spec in trace:
             eng.submit(**spec)
         rep = eng.run()
@@ -228,6 +229,23 @@ def test_energy_and_roofline_projection():
     assert rl["decode"]["bottleneck"] in ("compute", "memory",
                                           "collective")
     assert rl["decode"]["memory_s"] > 0
+    # a CPU run has no roofline of its own: it names the v5e it projects to
+    from repro.launch.hlo_analysis import V5E
+    assert rl["decode"]["device_kind"] == V5E
+
+
+@pytest.mark.parametrize("kind,known", [("TPU v5 lite", True),
+                                        ("TPU v4", False), ("cpu", False)])
+def test_peaks_table_refuses_unknown_chips(kind, known):
+    """Peaks come from the table keyed by ``device_kind``; a chip it does
+    not hold is an error, never a silent v5e default."""
+    from repro.launch.hlo_analysis import peaks, roofline
+    if known:
+        assert peaks(kind)["hbm_bw"] == 819e9
+        assert roofline(1.0, 819e9, 0.0, kind)["memory_s"] == 1.0
+    else:
+        with pytest.raises(ValueError, match="no published peaks"):
+            roofline(1.0, 1.0, 0.0, kind)
 
 
 # --------------------------------------------------- tooling round-trip ----
