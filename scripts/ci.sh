@@ -56,21 +56,16 @@ PYTHONPATH=src python benchmarks/overload.py --smoke \
 
 echo "== telemetry smoke: trace/events/metrics artifacts + on==off token identity =="
 PYTHONPATH=src python scripts/telemetry_smoke.py --arch olmo-1b
-PYTHONPATH=src python scripts/trace_report.py \
-    /tmp/repro_telemetry_smoke/serve.trace.json --validate
 
 echo "== traffic observatory: artifact + budget gate + roofline merge (two archs) =="
 mkdir -p /tmp/repro_traffic_smoke
 for arch in olmo-1b granite-moe-3b-a800m; do
     PYTHONPATH=src python -m repro.launch.serve --arch "$arch" --smoke \
         --sparsity 0.5 --slots 2 --requests 6 --max-len 64 \
-        --traffic-out "/tmp/repro_traffic_smoke/$arch.traffic.json" \
-        --trace-out "/tmp/repro_traffic_smoke/$arch.trace.json"
+        --traffic-out "/tmp/repro_traffic_smoke/$arch.traffic.json"
     PYTHONPATH=src python scripts/traffic_report.py \
         "/tmp/repro_traffic_smoke/$arch.traffic.json" \
         --budget scripts/traffic_budget.json
-    PYTHONPATH=src python scripts/trace_report.py \
-        "/tmp/repro_traffic_smoke/$arch.trace.json" --validate --traffic
 done
 PYTHONPATH=src python benchmarks/roofline.py \
     --serve-artifacts /tmp/repro_traffic_smoke/*.traffic.json \

@@ -6,10 +6,11 @@ observability contract (DESIGN_SERVING.md §Observability):
 
 * served tokens and terminal states are **bit-identical** on vs off
   (telemetry never perturbs scheduling or numerics);
-* the Chrome trace parses, every phase span nests inside a step span
-  without overlap (``validate_trace``), and summed phase time covers
-  >= ``--min-coverage`` of the summed measured step wall — the 5 %
-  criterion: the phase taxonomy accounts for where step time goes;
+* the profiler trace reads back with one ``serve.step`` span per
+  engine step, every ``serve.<phase>`` span nested inside exactly one
+  of them without overlap (``read_steps``), and summed phase time covers
+  >= ``--min-coverage`` of the summed step wall — the 5 % criterion:
+  the phase taxonomy accounts for where step time goes;
 * the JSONL event log parses, every record matches the event schema,
   and timestamps are monotonic (``validate_events``);
 * the metrics snapshot is valid JSON carrying the registry schema tag.
@@ -24,15 +25,15 @@ import json
 import os
 import jax
 
-from repro.serve import (ServeEngine, poisson_trace, validate_events,
-                         validate_trace)
+from repro.serve import ServeEngine, poisson_trace, validate_events
+from repro.serve.telemetry import read_steps
 
 
 def run_once(arch: str, out_dir: str | None, *, requests: int,
              max_len: int, seed: int) -> tuple:
     kw = {}
     if out_dir is not None:
-        kw = {"trace_out": os.path.join(out_dir, "serve.trace.json"),
+        kw = {"trace_out": os.path.join(out_dir, "trace"),
               "events_out": os.path.join(out_dir, "serve.events.jsonl"),
               "metrics_out": os.path.join(out_dir, "serve.metrics.json")}
     eng = ServeEngine.from_arch(arch, smoke=True, num_slots=2,
@@ -80,15 +81,17 @@ def main():
           f"({sum(len(t) for _, _, t in served_on)} tokens over "
           f"{len(served_on)} requests)")
 
-    trace_path = os.path.join(args.out_dir, "serve.trace.json")
-    stats = validate_trace(trace_path)
-    cov = stats["agg_coverage"]
-    assert cov is not None and cov >= args.min_coverage, (
+    steps = read_steps(os.path.join(args.out_dir, "trace"))
+    assert len(steps) == eng_on.spans.steps, (
+        f"{len(steps)} serve.step spans for {eng_on.spans.steps} steps")
+    n_phases = sum(len(s["phases"]) for s in steps)
+    cov = (sum(p[2] for s in steps for p in s["phases"])
+           / sum(s["dur_ns"] for s in steps))
+    assert cov >= args.min_coverage, (
         f"phase coverage {cov} below the {args.min_coverage:.0%} floor "
         f"— the phase taxonomy is leaking step wall time")
-    print(f"trace OK: {stats['steps']} steps / {stats['phase_spans']} "
-          f"phase spans / {stats['requests']} request rows, phase/wall "
-          f"coverage {cov:.1%} (min step {stats['min_coverage']:.1%})")
+    print(f"trace OK: {len(steps)} steps / {n_phases} phase spans, "
+          f"phase/wall coverage {cov:.1%}")
 
     events_path = os.path.join(args.out_dir, "serve.events.jsonl")
     n = validate_events(events_path)

@@ -136,10 +136,11 @@ def serve_trace(arch: str, smoke: bool = True, slots: int = 4,
     step-level invariant auditor + packed-tensor integrity scan;
     ``faults`` injects a seeded ``repro.serve.FaultPlan`` (chaos
     testing — see DESIGN_SERVING.md §Failure semantics).
-    ``trace_out`` / ``events_out`` / ``metrics_out`` write the Chrome
-    trace-event JSON (perfetto-viewable step-phase + per-request spans),
-    the structured JSONL event log, and the metrics snapshot (`.prom`
-    for Prometheus text, else JSON) — see DESIGN_SERVING.md
+    ``trace_out`` / ``events_out`` / ``metrics_out`` write a profiler
+    trace directory (the engine's ``serve.*`` step and phase spans beside
+    the device's operations; Perfetto-viewable), the structured JSONL
+    event log, and the metrics snapshot (`.prom` for Prometheus text,
+    else JSON) — see DESIGN_SERVING.md
     §Observability.  ``traffic_out`` writes the memory-traffic
     attribution artifact (per-role ledger, per-phase byte counters,
     compiled-HLO cross-check, energy/roofline projection — the input to
@@ -341,10 +342,12 @@ def main():
                     help="inject a seeded FaultPlan.chaos() fault schedule "
                          "(page squeezes, forced preempts, eviction "
                          "storms, NaN logits, bitflips); implies --audit")
-    ap.add_argument("--trace-out", default=None,
-                    help="write a Chrome trace-event JSON (step-phase + "
-                         "per-request spans; open in ui.perfetto.dev or "
-                         "chrome://tracing)")
+    ap.add_argument("--trace-out", default=None, metavar="DIR",
+                    help="run a profiler session over the serving loop "
+                         "and write it under DIR (serve.* step and phase "
+                         "spans beside the device's operations; open "
+                         "plugins/profile/*/perfetto_trace.json.gz in "
+                         "ui.perfetto.dev)")
     ap.add_argument("--events-out", default=None,
                     help="write the structured JSONL event log "
                          "(lifecycle transitions, fallbacks, faults, "

@@ -13,8 +13,9 @@ from jax.sharding import PartitionSpec as P
 
 from repro.launch.sharding import bitmap_sharded, bitmap_specs, packed_specs
 from repro.models.config import ModelConfig
-from repro.models.model import (decode_step, forward, lm_head_weight,
-                                lm_loss, loss_fn, prefill_hidden)
+from repro.models.model import (LM_HEAD_SCOPE, decode_hidden, forward,
+                                head_logits, lm_head_weight, lm_loss,
+                                loss_fn, prefill_hidden)
 from repro.sparse.format import BitmapWeight, gather_bitmap
 from repro.train import optimizer as opt_lib
 
@@ -174,10 +175,18 @@ def build_serve_step(cfg: ModelConfig, impl: Optional[str] = None,
             b = pos.shape[0] if jnp.ndim(pos) else 1
             embeds = jax.random.normal(embed_rng, (b, 1, cfg.d_model),
                                        jnp.float32)
-        logits, new_cache = decode_step(params, cache, cfg, tokens, pos,
-                                        embeds=embeds, lm_weight=lm_weight,
-                                        packed=packed, lm_impl=impl,
-                                        page_tables=page_tables)
+        hidden, new_cache = decode_hidden(params, cache, cfg, tokens, pos,
+                                          embeds=embeds, packed=packed,
+                                          impl=impl, page_tables=page_tables)
+        with jax.named_scope(LM_HEAD_SCOPE):
+            next_tok, logits = _head_and_sample(
+                params, hidden[:, 0], pos, lm_weight, sample_keys,
+                temperature, top_ks)
+        return next_tok, logits, new_cache
+
+    def _head_and_sample(params, hidden, pos, lm_weight, sample_keys,
+                         temperature, top_ks):
+        logits = head_logits(params, cfg, hidden, lm_weight, impl)
         next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         if sample_keys is not None and temperature is not None:
             posv = jnp.broadcast_to(pos, next_tok.shape)
@@ -202,7 +211,7 @@ def build_serve_step(cfg: ModelConfig, impl: Optional[str] = None,
             sampled = jax.vmap(jax.random.categorical)(keys, scaled)
             next_tok = jnp.where(temperature > 0,
                                  sampled.astype(jnp.int32), next_tok)
-        return next_tok, logits, new_cache
+        return next_tok, logits
 
     return serve_step
 

@@ -24,6 +24,12 @@ from repro.models.config import BlockCfg, ModelConfig
 RWKV_MIX_RANK = 32
 RWKV_DECAY_RANK = 64
 
+#: ``jax.named_scope`` names of the serving programs' layers, carried in
+#: each HLO op's ``op_name`` metadata (and so in a profiler trace): the
+#: attention core (RoPE, K/V write, gather, scores, softmax, PV — not the
+#: q/k/v/o projections), the MLP/MoE block, and the head with sampling.
+ATTENTION_SCOPE, FFN_SCOPE, LM_HEAD_SCOPE = "attention", "ffn", "lm_head"
+
 # ------------------------------------------------------------- shapes ------
 
 
@@ -436,6 +442,20 @@ def _decode_attn(p: Dict, x: jax.Array, cache: Dict, cfg: ModelConfig,
     if cfg.qk_norm:
         q = L.norm(q, p["q_norm"], "rmsnorm")
         k = L.norm(k, p["k_norm"], "rmsnorm")
+    with jax.named_scope(ATTENTION_SCOPE):
+        o, new_cache = _decode_attn_core(q, k, v, cache, cfg, blk, pos,
+                                         page_table)
+    out = L.matmul_or_bitmap(o.reshape(b, 1, h * hd), p["wo"],
+                             pk.get("wo"), impl)
+    return out, new_cache
+
+
+def _decode_attn_core(q, k, v, cache: Dict, cfg: ModelConfig,
+                      blk: BlockCfg, pos: jax.Array,
+                      page_table: Optional[jax.Array]
+                      ) -> Tuple[jax.Array, Dict]:
+    """RoPE, the K/V cache write and attention of one decode step."""
+    b = q.shape[0]
     pos = jnp.asarray(pos)
     posv = jnp.broadcast_to(pos, (b,)) if pos.ndim == 0 else pos
     posb = posv[:, None]
@@ -467,9 +487,7 @@ def _decode_attn(p: Dict, x: jax.Array, cache: Dict, cfg: ModelConfig,
         k_att, v_att = k_cache, v_cache
     o = L.decode_attention(q, k_att, v_att, pos, window=blk.window,
                            ring=ring)
-    out = L.matmul_or_bitmap(o.reshape(b, 1, h * hd), p["wo"],
-                             pk.get("wo"), impl)
-    return out, {"k": k_cache, "v": v_cache}
+    return o, {"k": k_cache, "v": v_cache}
 
 
 def _prefill_attn(p: Dict, x: jax.Array, cache: Dict, cfg: ModelConfig,
@@ -508,6 +526,21 @@ def _prefill_attn(p: Dict, x: jax.Array, cache: Dict, cfg: ModelConfig,
     if cfg.qk_norm:
         q = L.norm(q, p["q_norm"], "rmsnorm")
         k = L.norm(k, p["k_norm"], "rmsnorm")
+    with jax.named_scope(ATTENTION_SCOPE):
+        o, new_cache = _prefill_attn_core(q, k, v, cache, cfg, blk, pos,
+                                          lens, page_table)
+    out = L.matmul_or_bitmap(o.reshape(b, c_chunk, h * hd), p["wo"],
+                             pk.get("wo"), impl)
+    return out, new_cache
+
+
+def _prefill_attn_core(q, k, v, cache: Dict, cfg: ModelConfig,
+                       blk: BlockCfg, pos: jax.Array, lens: jax.Array,
+                       page_table: Optional[jax.Array]
+                       ) -> Tuple[jax.Array, Dict]:
+    """RoPE, then the chunk's K/V cache writes and attention, one token
+    at a time (see ``_prefill_attn``)."""
+    c_chunk = q.shape[1]
     posv = jnp.asarray(pos)
     posb = posv[:, None] + jnp.arange(c_chunk)[None, :]      # (B, C)
     q = L.rope(q, posb, cfg.rope_theta)
@@ -545,9 +578,7 @@ def _prefill_attn(p: Dict, x: jax.Array, cache: Dict, cfg: ModelConfig,
         (q.swapaxes(0, 1), k.swapaxes(0, 1), v.swapaxes(0, 1),
          jnp.arange(c_chunk)))
     o = outs.swapaxes(0, 1)                                  # (B, C, Hq, hd)
-    out = L.matmul_or_bitmap(o.reshape(b, c_chunk, h * hd), p["wo"],
-                             pk.get("wo"), impl)
-    return out, {"k": k_cache, "v": v_cache}
+    return o, {"k": k_cache, "v": v_cache}
 
 
 def prefill_hidden(params: Dict, cache: Dict, cfg: ModelConfig,
@@ -596,21 +627,24 @@ def prefill_hidden(params: Dict, cache: Dict, cfg: ModelConfig,
                 raise NotImplementedError(
                     f"chunked prefill has no {blk.mixer} path; the engine "
                     f"falls back to teacher-forcing for this arch")
-            if blk.ffn == "mlp":
-                xn = L.norm(x, bp["mlp"].get("norm"), cfg.norm)
-                x = x + L.mlp(bp["mlp"], xn, cfg, packed=pw.get("mlp"),
-                              impl=impl)
-            elif blk.ffn == "moe":
-                xn = L.norm(x, bp["moe"].get("norm"), cfg.norm)
-                # per-token dispatch: capacity = ceil(S·k·cf/E) depends on
-                # the sequence length, so a (B, C) chunk through one MoE
-                # call would drop tokens differently than C decode steps —
-                # folding the chunk into the batch dim keeps the dispatch
-                # (and the tokens) bit-identical to decode
-                mo = L.moe_ffn(bp["moe"], xn.reshape(b * c_chunk, 1, d),
-                               cfg, packed=pw.get("moe"), impl=impl)
-                x = x + mo.reshape(b, c_chunk, d)
-            elif blk.ffn == "rwkv_cm":
+            with jax.named_scope(FFN_SCOPE):
+                if blk.ffn == "mlp":
+                    xn = L.norm(x, bp["mlp"].get("norm"), cfg.norm)
+                    x = x + L.mlp(bp["mlp"], xn, cfg, packed=pw.get("mlp"),
+                                  impl=impl)
+                elif blk.ffn == "moe":
+                    xn = L.norm(x, bp["moe"].get("norm"), cfg.norm)
+                    # per-token dispatch: capacity = ceil(S·k·cf/E)
+                    # depends on the sequence length, so a (B, C) chunk
+                    # through one MoE call would drop tokens differently
+                    # than C decode steps — folding the chunk into the
+                    # batch dim keeps the dispatch (and the tokens)
+                    # bit-identical to decode
+                    mo = L.moe_ffn(bp["moe"],
+                                   xn.reshape(b * c_chunk, 1, d), cfg,
+                                   packed=pw.get("moe"), impl=impl)
+                    x = x + mo.reshape(b, c_chunk, d)
+            if blk.ffn == "rwkv_cm":
                 raise NotImplementedError(
                     "chunked prefill has no rwkv_cm path; the engine "
                     "falls back to teacher-forcing for this arch")
@@ -683,21 +717,22 @@ def decode_hidden(params: Dict, cache: Dict, cfg: ModelConfig,
                                         packed=pw.get("rwkv"), impl=impl)
                 x = x + o
                 nc = st
-            if blk.ffn == "mlp":
-                xn = L.norm(x, bp["mlp"].get("norm"), cfg.norm)
-                x = x + L.mlp(bp["mlp"], xn, cfg, packed=pw.get("mlp"),
-                              impl=impl)
-            elif blk.ffn == "moe":
-                xn = L.norm(x, bp["moe"].get("norm"), cfg.norm)
-                x = x + L.moe_ffn(bp["moe"], xn, cfg, packed=pw.get("moe"),
+            with jax.named_scope(FFN_SCOPE):
+                if blk.ffn == "mlp":
+                    xn = L.norm(x, bp["mlp"].get("norm"), cfg.norm)
+                    x = x + L.mlp(bp["mlp"], xn, cfg, packed=pw.get("mlp"),
                                   impl=impl)
-            elif blk.ffn == "rwkv_cm":
-                xn = L.norm(x, bp["rwkv_cm"].get("norm"), cfg.norm)
-                x = x + ssm.rwkv_channel_mix(bp["rwkv_cm"], xn, cfg,
-                                             x_prev=pc["cm_x_prev"][:, None],
-                                             packed=pw.get("rwkv_cm"),
-                                             impl=impl)
-                nc["cm_x_prev"] = xn[:, 0]
+                elif blk.ffn == "moe":
+                    xn = L.norm(x, bp["moe"].get("norm"), cfg.norm)
+                    x = x + L.moe_ffn(bp["moe"], xn, cfg,
+                                      packed=pw.get("moe"), impl=impl)
+                elif blk.ffn == "rwkv_cm":
+                    xn = L.norm(x, bp["rwkv_cm"].get("norm"), cfg.norm)
+                    x = x + ssm.rwkv_channel_mix(
+                        bp["rwkv_cm"], xn, cfg,
+                        x_prev=pc["cm_x_prev"][:, None],
+                        packed=pw.get("rwkv_cm"), impl=impl)
+                    nc["cm_x_prev"] = xn[:, 0]
             new_cache[f"b{i}"] = nc
         return x, new_cache
 

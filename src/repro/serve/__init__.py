@@ -14,15 +14,14 @@ from repro.serve.prefill import PrefillJob, PrefillPlanner
 from repro.serve.request import (Request, RequestRejected, RequestState,
                                  TERMINAL_STATES)
 from repro.serve.scheduler import SlotScheduler
-from repro.serve.telemetry import (ChromeTrace, Clock, Counter, EventLog,
-                                   Gauge, Histogram, MetricsRegistry,
-                                   StepSpans, Telemetry, load_trace,
-                                   validate_events, validate_trace)
+from repro.serve.telemetry import (Clock, Counter, EventLog, Gauge,
+                                   Histogram, MetricsRegistry, StepSpans,
+                                   Telemetry, validate_events)
 from repro.serve.trace import RollingStat, percentiles, poisson_trace
 from repro.serve.traffic import TrafficLedger, role_of
 
 __all__ = [
-    "AuditViolation", "ChromeTrace", "Clock", "Counter",
+    "AuditViolation", "Clock", "Counter",
     "DeadlineExceeded", "EventLog", "Fault", "FaultPlan", "Gauge",
     "Histogram", "InvariantAuditor", "MetricsRegistry", "OutOfPages",
     "PackEntry", "PackedModel", "PagePool", "PagedKVCache", "PrefillJob",
@@ -30,6 +29,6 @@ __all__ = [
     "RequestState", "RollingStat", "ServeEngine", "ServeError",
     "ServeOverloaded", "SlotKVCache", "SlotScheduler", "StepSpans",
     "TERMINAL_STATES", "Telemetry", "TrafficLedger", "choose_block",
-    "load_trace", "pack_lm_head", "pack_model", "percentiles",
-    "poisson_trace", "role_of", "validate_events", "validate_trace",
+    "pack_lm_head", "pack_model", "percentiles", "poisson_trace",
+    "role_of", "validate_events",
 ]

@@ -57,6 +57,7 @@ from __future__ import annotations
 
 import math
 import os
+import contextlib
 import time
 import warnings
 from collections import deque
@@ -83,7 +84,8 @@ from repro.serve.paging import OutOfPages, PagedKVCache
 from repro.serve.prefill import PrefillPlanner
 from repro.serve.request import Request, RequestState
 from repro.serve.scheduler import SlotScheduler
-from repro.serve.telemetry import Clock, MetricsRegistry, Telemetry
+from repro.serve.telemetry import (SPAN_PREFIX, STEP_SPAN, Clock,
+                                   MetricsRegistry, Telemetry)
 from repro.serve.traffic import TrafficLedger
 from repro.sparse.format import BitmapWeight, pack_bitmap, shard_bitmap
 from repro.sparse.pruning import global_l1_prune, per_tensor_prune, \
@@ -254,15 +256,19 @@ class ServeEngine:
         to a fault-free run.
 
         ``trace_out`` / ``events_out`` / ``metrics_out``: telemetry
-        artifacts (``repro.serve.telemetry``), written by ``close()``.
-        Setting any of them turns step-phase spans on: Chrome
-        trace-event JSON with per-step phase + per-request lifecycle
-        spans, a structured JSONL event log, and a metrics-registry
-        snapshot (JSON, or Prometheus text for ``.prom`` paths).  All
-        three default off — telemetry-off serving is bit-identical and
-        allocation-free on the hot path (spans and events are plain
-        ``is not None`` checks; the metrics registry itself is always
-        on, since ``report()`` is rendered from it).
+        artifacts (``repro.serve.telemetry``), finished by ``close()``:
+        a profiler trace directory (the engine runs a
+        ``jax.profiler`` session from its first step to ``close()``:
+        its ``serve.*`` step and phase spans beside the device's
+        operations, on one clock; Perfetto-viewable), a structured JSONL
+        event log, and a metrics-registry snapshot (JSON, or Prometheus
+        text for ``.prom`` paths).  Setting any of them also feeds the
+        per-phase histograms.  All three default off — telemetry-off
+        serving is bit-identical (spans and events are plain ``is not
+        None`` checks; the metrics registry itself is always on, since
+        ``report()`` is rendered from it).  The ``serve.*`` profiler
+        spans are opened either way and record whenever any profiler
+        session is on.
         """
         self.cfg = cfg
         self.metrics = MetricsRegistry()
@@ -273,8 +279,7 @@ class ServeEngine:
         # _warn_fallback can fire
         self.telemetry: Optional[Telemetry] = None
         if trace_out or events_out or metrics_out:
-            self.telemetry = Telemetry(self.metrics, self._clock,
-                                       trace_out=trace_out,
+            self.telemetry = Telemetry(self.metrics, trace_out=trace_out,
                                        events_out=events_out,
                                        metrics_out=metrics_out)
         self.spans = (self.telemetry.spans
@@ -616,8 +621,8 @@ class ServeEngine:
         self.traffic = TrafficLedger(self)
 
         # ---- telemetry: every subsystem registers into the one
-        # registry; spans/events only exist when an output is asked for
-        # (telemetry-off keeps the hot path allocation-free) ----
+        # registry; phase histograms and events only exist when an
+        # output is asked for ----
         self.scheduler.register_metrics(m)
         self.kv.register_metrics(m)
         self.traffic.register_metrics(m)
@@ -684,13 +689,16 @@ class ServeEngine:
             written.extend(self.telemetry.close())
         return written
 
-    def _trace_counter(self, name: str, values: Dict[str, int]) -> None:
-        """Emit one Chrome-trace counter sample (per-phase HBM byte
-        track); no-op without ``--trace-out`` — one ``is None`` check."""
-        if self.telemetry is None or self.telemetry.trace is None:
-            return
-        self.telemetry.trace.counter(name, self._clock.now_or_zero(),
-                                     values)
+    @contextlib.contextmanager
+    def _phase(self, name: str):
+        """One host phase of ``step`` (``telemetry.PHASES``): the
+        profiler span ``serve.<name>`` and, with telemetry on, one
+        observation of the ``step.phase.<name>_s`` histogram."""
+        t0 = time.perf_counter()
+        with jax.profiler.TraceAnnotation(SPAN_PREFIX + name):
+            yield
+        if self.spans is not None:
+            self.spans.phase_done(name, time.perf_counter() - t0)
 
     def _register_report_views(self) -> None:
         """Register ``report()``'s top-level fields and sections as
@@ -862,8 +870,6 @@ class ServeEngine:
         self._emit(state.name.lower(), rid=req.rid,
                    tokens=len(req.tokens),
                    reason=str(error) if error is not None else None)
-        if self.telemetry is not None:
-            self.telemetry.request_done(req)
 
     def _due_depth(self) -> int:
         """Waiting requests whose arrival has come due."""
@@ -1002,8 +1008,6 @@ class ServeEngine:
         self.requests.append(req)
         self._emit("done", rid=req.rid, tokens=len(req.tokens),
                    latency_s=req.latency_s)
-        if self.telemetry is not None:
-            self.telemetry.request_done(req)
 
     def _recover_corruption(self, logits, decoding: List[int]) -> bool:
         """Integrity scan + quarantine + deterministic replay (the
@@ -1096,53 +1100,60 @@ class ServeEngine:
         position ``len(prompt) - 1`` — the next decode step consumes the
         final prompt token and samples the first generated token, just
         like the teacher-forcing path's last prompt step did.
+
+        Two phases: ``prefill`` holds the host work (planning, page
+        mapping, dispatch, routing the slots, which reads nothing the
+        call computes) and ``prefill_sync`` the wait for the call's
+        result, so device idle inside ``serve.prefill`` is host time.
         """
-        tokens, pos, lens, finished = self.planner.next_call()
-        if self.page_len:
-            # oldest slots first: if mapping runs the pool dry in
-            # preemptible mode, the youngest victims haven't mapped yet —
-            # their reclaimed pages go to the older requesters (a
-            # preempted slot's lane still scatters, into the trash page)
-            order = sorted(np.nonzero(lens)[0],
-                           key=lambda s: int(self._admit_seq[int(s)]))
-            for slot in order:
-                if int(slot) not in self.scheduler.active:
-                    continue
-                self._with_pages(
-                    lambda s=int(slot): self.kv.ensure_range(
-                        s, int(pos[s]), int(pos[s]) + int(lens[s])),
-                    int(slot))
-        hidden, cache = self._prefill(tokens, pos, lens)
-        self.kv.cache = cache
-        self._trace_counter("hbm.prefill",
-                            self.traffic.on_prefill(pos, lens))
-        jax.block_until_ready(hidden)
-        wall = self._wall()
-        if self.prefix_reuse:
-            # publish each advanced slot's fully-written blocks *now* —
-            # before any later chunk can ring-wrap over them
+        with self._phase("prefill"):
+            tokens, pos, lens, finished = self.planner.next_call()
+            if self.page_len:
+                # oldest slots first: if mapping runs the pool dry in
+                # preemptible mode, the youngest victims haven't mapped
+                # yet — their reclaimed pages go to the older requesters
+                # (a preempted slot's lane still scatters, into the trash
+                # page)
+                order = sorted(np.nonzero(lens)[0],
+                               key=lambda s: int(self._admit_seq[int(s)]))
+                for slot in order:
+                    if int(slot) not in self.scheduler.active:
+                        continue
+                    self._with_pages(
+                        lambda s=int(slot): self.kv.ensure_range(
+                            s, int(pos[s]), int(pos[s]) + int(lens[s])),
+                        int(slot))
+            hidden, cache = self._prefill(tokens, pos, lens)
+            self.kv.cache = cache
+            self.traffic.on_prefill(pos, lens)
+            if self.prefix_reuse:
+                # publish each advanced slot's fully-written blocks *now*
+                # — before any later chunk can ring-wrap over them
+                for slot in np.nonzero(lens)[0]:
+                    if int(slot) in self.scheduler.active:
+                        self.kv.register_prefix(
+                            int(slot), self._ingest[int(slot)],
+                            int(pos[slot]) + int(lens[slot]))
+            finished = [s for s in finished if s in self.scheduler.active]
+            for slot in finished:
+                ing = self._ingest[slot]
+                self._pos[slot] = len(ing) - 1
+                self._tok[slot] = ing[-1]
             for slot in np.nonzero(lens)[0]:
-                if int(slot) in self.scheduler.active:
-                    self.kv.register_prefix(
-                        int(slot), self._ingest[int(slot)],
-                        int(pos[slot]) + int(lens[slot]))
-        for slot in finished:
-            if slot not in self.scheduler.active:
-                continue               # preempted mid-call
-            req = self.scheduler.active[slot]
-            ing = self._ingest[slot]
-            self._pos[slot] = len(ing) - 1
-            self._tok[slot] = ing[-1]
-            if req.t_prefill_done is None:
-                req.t_prefill_done = wall
-                self._emit("prefill_done", rid=req.rid, slot=slot)
-        for slot in np.nonzero(lens)[0]:
-            if self.planner.in_prefill(int(slot)):
-                # park the passenger's decode write on the next unwritten
-                # prompt position: the next chunk rewrites that line
-                # before anything reads it
-                self._pos[slot] = self.planner.next_pos(int(slot))
-        self._c_prefill_steps.inc()
+                if self.planner.in_prefill(int(slot)):
+                    # park the passenger's decode write on the next
+                    # unwritten prompt position: the next chunk rewrites
+                    # that line before anything reads it
+                    self._pos[slot] = self.planner.next_pos(int(slot))
+            self._c_prefill_steps.inc()
+        with self._phase("prefill_sync"):
+            jax.block_until_ready(hidden)
+            wall = self._wall()
+            for slot in finished:
+                req = self.scheduler.active[slot]
+                if req.t_prefill_done is None:
+                    req.t_prefill_done = wall
+                    self._emit("prefill_done", rid=req.rid, slot=slot)
 
     def probe_logits(self, tokens: Sequence[int]) -> np.ndarray:
         """(num_slots, V) logits of one decode step feeding ``tokens``
@@ -1199,25 +1210,108 @@ class ServeEngine:
         the full-batch decode step (skipped only when every active slot
         is mid-prefill).
 
-        With telemetry on, every host-side stretch of this method sits
-        inside exactly one phase span (``telemetry.PHASES``): schedule →
-        [prefill] → [page_ensure → decode → host_sync → sample] →
-        [deadline_sweep] → [audit].  Spans bracket host code only — the
-        decode phase ends at dispatch, and device time surfaces in
-        ``host_sync`` (the existing block-until-ready point) — so the
-        per-step phase sum accounts for the step wall without adding
-        transfers or syncs.  Telemetry off: ``sp is None`` and every
-        bracket is a dead branch."""
-        self.warmup()
-        # the serving clock starts *after* warmup — one idempotent
-        # helper (telemetry.Clock), so no call path can leak compile
-        # time into the first timed step
-        self._clock.start()
-        sp = self.spans
+        The step is the profiler span ``serve.step`` (with its
+        ``step_num``), and every host-side stretch of it sits inside
+        exactly one phase span ``serve.<phase>`` (``telemetry.PHASES``):
+        schedule → [prefill → prefill_sync] → [page_ensure → decode →
+        host_sync → sample] → [deadline_sweep] → [audit].  Spans bracket
+        host code only — ``decode`` ends at dispatch, and device time
+        surfaces in ``host_sync`` / ``prefill_sync`` (the existing
+        block-until-ready points) — so they add no transfers or syncs."""
+        self._start()
         t_begin = time.perf_counter()
-        if sp is not None:
-            sp.step_begin(self._steps, t_begin)
-            sp.begin("schedule")
+        with jax.profiler.StepTraceAnnotation(STEP_SPAN,
+                                              step_num=self._steps):
+            self._step()
+        dt = time.perf_counter() - t_begin
+        if self.spans is not None:
+            self.spans.step_done(dt)
+        self._step_wall_ema = (dt if self._step_wall_ema is None
+                               else 0.8 * self._step_wall_ema + 0.2 * dt)
+        self._steps += 1
+
+    def _start(self) -> None:
+        """Warm up, then start the serving clock and the telemetry's
+        profiler session — both *after* warmup, through idempotent
+        helpers, so no call path can leak compile time into the first
+        timed step."""
+        self.warmup()
+        self._clock.start()
+        if self.telemetry is not None:
+            self.telemetry.start()
+
+    def _step(self) -> None:
+        with self._phase("schedule"):
+            self._schedule()
+
+        # at most one prefill call per engine step: a stream of long
+        # prompts interleaves chunk calls with decode steps instead of
+        # starving the decoding slots
+        prefilled = False
+        if self.planner is not None and self.planner.has_work:
+            self._prefill_call()
+            prefilled = True
+
+        in_prefill = (self.planner.in_prefill if self.planner is not None
+                      else lambda s: False)
+        decoding = [s for s in self.scheduler.active if not in_prefill(s)]
+        if decoding or not prefilled:
+            if self.page_len:
+                # map each decoding slot's current write page; mid-prefill
+                # passengers stay unmapped and scribble into the trash
+                # page (or an unwritten line their next chunk rewrites).
+                # Oldest first: in preemptible mode a dry pool preempts
+                # the youngest slots, which haven't mapped yet
+                with self._phase("page_ensure"):
+                    for slot in sorted(
+                            decoding, key=lambda s: int(self._admit_seq[s])):
+                        if slot not in self.scheduler.active:
+                            continue
+                        self._with_pages(
+                            lambda s=slot: self.kv.ensure(
+                                s, int(self._pos[s])), slot)
+                    decoding = [s for s in self.scheduler.active
+                                if not in_prefill(s)]
+            with self._phase("decode"):
+                self.traffic.on_decode(
+                    [int(self._pos[s]) for s in decoding])
+                nxt, logits, cache = self._decode(
+                    jnp.asarray(self._tok[:, None]), jnp.asarray(self._pos))
+                self.kv.cache = cache
+            with self._phase("host_sync"):
+                nxt_host = np.asarray(nxt)
+            wall = self._wall()
+            with self._phase("sample"):
+                self._commit(nxt_host, logits, decoding, in_prefill, wall)
+            self._c_decode_steps.inc()
+        elif self.audit:
+            # prefill-only step: no logits to check, but a fault may
+            # have corrupted tensors the prefill call just consumed
+            with self._phase("audit"):
+                self._recover_corruption(None, [])
+        if self._has_deadlines:
+            with self._phase("deadline_sweep"):
+                wall = self._wall()
+                for slot in list(self.scheduler.active):
+                    req = self.scheduler.active[slot]
+                    if self._deadline_passed(req, wall):
+                        self._release_slot(slot, RequestState.EXPIRED)
+                        self._abort(req, RequestState.EXPIRED,
+                                    error=DeadlineExceeded(
+                                        f"rid {req.rid}: exceeded its "
+                                        f"{req.deadline_ms:.0f}ms deadline "
+                                        f"mid-flight"))
+        if self.auditor is not None:
+            with self._phase("audit"):
+                try:
+                    self.auditor.check_step()
+                except Exception as e:
+                    self._emit("audit_violation", reason=str(e))
+                    raise
+
+    def _schedule(self) -> None:
+        """Fire scheduled faults, shed or expire due requests, and admit
+        waiting requests into free slots."""
         now = float(self._steps)
         if self.faults is not None:
             n_log = len(self.faults.log)
@@ -1312,157 +1406,58 @@ class ServeEngine:
                 req.t_prefill_done = req.t_admit
             self._emit("admit", rid=req.rid, slot=slot,
                        prefix_hit_tokens=shared)
-        if sp is not None:
-            sp.end()
 
-        # at most one prefill call per engine step: a stream of long
-        # prompts interleaves chunk calls with decode steps instead of
-        # starving the decoding slots
-        prefilled = False
-        if self.planner is not None and self.planner.has_work:
-            if sp is not None:
-                sp.begin("prefill")
-            self._prefill_call()
-            if sp is not None:
-                sp.end()
-            prefilled = True
-
-        in_prefill = (self.planner.in_prefill if self.planner is not None
-                      else lambda s: False)
-        decoding = [s for s in self.scheduler.active if not in_prefill(s)]
-        if decoding or not prefilled:
-            if self.page_len:
-                # map each decoding slot's current write page; mid-prefill
-                # passengers stay unmapped and scribble into the trash
-                # page (or an unwritten line their next chunk rewrites).
-                # Oldest first: in preemptible mode a dry pool preempts
-                # the youngest slots, which haven't mapped yet
-                if sp is not None:
-                    sp.begin("page_ensure")
-                for slot in sorted(decoding,
-                                   key=lambda s: int(self._admit_seq[s])):
-                    if slot not in self.scheduler.active:
-                        continue
-                    self._with_pages(
-                        lambda s=slot: self.kv.ensure(
-                            s, int(self._pos[s])), slot)
-                decoding = [s for s in self.scheduler.active
-                            if not in_prefill(s)]
-                if sp is not None:
-                    sp.end()
-            self._trace_counter("hbm.decode", self.traffic.on_decode(
-                [int(self._pos[s]) for s in decoding]))
-            if sp is not None:
-                sp.begin("decode")
-            nxt, logits, cache = self._decode(
-                jnp.asarray(self._tok[:, None]), jnp.asarray(self._pos))
-            self.kv.cache = cache
-            if sp is not None:
-                sp.end()
-                sp.begin("host_sync")
-            nxt_host = np.asarray(nxt)
-            if sp is not None:
-                sp.end()
-            wall = self._wall()
-
-            if sp is not None:
-                sp.begin("sample")
-            if self.audit and self._recover_corruption(logits, decoding):
-                # a corrupted tensor was quarantined and every active
-                # slot preempted: nothing from this step is committed —
-                # the requests replay deterministically through the now-
-                # clean (dense-fallback) path, emitting the exact tokens
-                # the uncorrupted step would have
-                pass
-            else:
-                self._c_slot_steps.inc(len(decoding))
-                for slot, req in list(self.scheduler.active.items()):
-                    if in_prefill(slot):
-                        continue
-                    ing = self._ingest[slot]
-                    p = int(self._pos[slot])
-                    self._pos[slot] = p + 1
-                    if (self.prefix_reuse
-                            and (p + 1) % self.page_len == 0):
-                        # a block boundary just filled: publish it
-                        # (prompt *and* generated blocks — identical
-                        # greedy requests reuse each other's
-                        # generations too)
-                        self.kv.register_prefix(slot, ing, p + 1)
-                    if p + 1 < len(ing):
-                        # still consuming prompt/recompute history:
-                        # teacher-force the next token (legacy walk, or
-                        # a preempted request replaying its generated
-                        # prefix)
-                        self._tok[slot] = ing[p + 1]
-                        if (p + 1 == len(ing) - 1
-                                and req.t_prefill_done is None):
-                            req.t_prefill_done = wall  # cache resident
-                            self._emit("prefill_done", rid=req.rid,
-                                       slot=slot)
-                        continue
-                    t = int(nxt_host[slot])
-                    req.tokens.append(t)
-                    ing.append(t)
-                    if req.t_first is None:
-                        req.t_first = wall
-                        if self.events is not None:
-                            self._emit("first_token", rid=req.rid,
-                                       slot=slot)
-                    self._tok[slot] = t
-                    if (len(req.tokens) >= req.max_new_tokens
-                            or p + 1 >= self.max_len):
-                        req.t_done = wall
-                        req.done_step = self._steps
-                        self._release_slot(slot, RequestState.DONE)
-                        self._retire(req)
-            if sp is not None:
-                sp.end()
-            self._c_decode_steps.inc()
-        elif self.audit:
-            # prefill-only step: no logits to check, but a fault may
-            # have corrupted tensors the prefill call just consumed
-            if sp is not None:
-                sp.begin("audit")
-            self._recover_corruption(None, [])
-            if sp is not None:
-                sp.end()
-        if self._has_deadlines:
-            if sp is not None:
-                sp.begin("deadline_sweep")
-            wall = self._wall()
-            for slot in list(self.scheduler.active):
-                req = self.scheduler.active[slot]
-                if self._deadline_passed(req, wall):
-                    self._release_slot(slot, RequestState.EXPIRED)
-                    self._abort(req, RequestState.EXPIRED,
-                                error=DeadlineExceeded(
-                                    f"rid {req.rid}: exceeded its "
-                                    f"{req.deadline_ms:.0f}ms deadline "
-                                    f"mid-flight"))
-            if sp is not None:
-                sp.end()
-        if self.auditor is not None:
-            if sp is not None:
-                sp.begin("audit")
-            try:
-                self.auditor.check_step()
-            except Exception as e:
-                self._emit("audit_violation", reason=str(e))
-                raise
-            if sp is not None:
-                sp.end()
-        dt = time.perf_counter() - t_begin
-        if sp is not None:
-            sp.step_end()
-        self._step_wall_ema = (dt if self._step_wall_ema is None
-                               else 0.8 * self._step_wall_ema + 0.2 * dt)
-        self._steps += 1
+    def _commit(self, nxt_host: np.ndarray, logits, decoding: List[int],
+                in_prefill, wall: float) -> None:
+        """Commit one decode step's tokens: advance every decoding slot,
+        teacher-force prompt/recompute history, append sampled tokens and
+        retire finished requests."""
+        if self.audit and self._recover_corruption(logits, decoding):
+            # a corrupted tensor was quarantined and every active slot
+            # preempted: nothing from this step is committed — the
+            # requests replay deterministically through the now-clean
+            # (dense-fallback) path, emitting the exact tokens the
+            # uncorrupted step would have
+            return
+        self._c_slot_steps.inc(len(decoding))
+        for slot, req in list(self.scheduler.active.items()):
+            if in_prefill(slot):
+                continue
+            ing = self._ingest[slot]
+            p = int(self._pos[slot])
+            self._pos[slot] = p + 1
+            if self.prefix_reuse and (p + 1) % self.page_len == 0:
+                # a block boundary just filled: publish it (prompt *and*
+                # generated blocks — identical greedy requests reuse
+                # each other's generations too)
+                self.kv.register_prefix(slot, ing, p + 1)
+            if p + 1 < len(ing):
+                # still consuming prompt/recompute history: teacher-force
+                # the next token (legacy walk, or a preempted request
+                # replaying its generated prefix)
+                self._tok[slot] = ing[p + 1]
+                if p + 1 == len(ing) - 1 and req.t_prefill_done is None:
+                    req.t_prefill_done = wall  # cache resident
+                    self._emit("prefill_done", rid=req.rid, slot=slot)
+                continue
+            t = int(nxt_host[slot])
+            req.tokens.append(t)
+            ing.append(t)
+            if req.t_first is None:
+                req.t_first = wall
+                if self.events is not None:
+                    self._emit("first_token", rid=req.rid, slot=slot)
+            self._tok[slot] = t
+            if (len(req.tokens) >= req.max_new_tokens
+                    or p + 1 >= self.max_len):
+                req.t_done = wall
+                req.done_step = self._steps
+                self._release_slot(slot, RequestState.DONE)
+                self._retire(req)
 
     def run(self) -> dict:
         """Drive until every submitted request has drained; report stats."""
-        self.warmup()
-        self._clock.start()
+        self._start()
         while self.scheduler.has_work:
             if not self.scheduler.active:
                 # idle: fast-forward the step clock to the next arrival

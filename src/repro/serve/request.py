@@ -156,31 +156,3 @@ class Request:
         if self.t_prefill_done is None or self.t_first is None:
             return None
         return self.t_first - self.t_prefill_done
-
-    def timeline(self):
-        """Lifecycle as trace rows: ``(spans, instants)`` where spans is
-        ``[(name, t_begin, t_end), ...]`` over QUEUED / PREFILL / DECODE
-        and instants marks each preemption.  Tolerant of partial marks —
-        an aborted request emits only the phases it reached, each closed
-        at the latest timestamp it recorded."""
-        marks = [t for t in (self.t_due, self.t_admit,
-                             self.t_prefill_done, self.t_done)
-                 if t is not None]
-        if not marks:
-            return [], []
-        end = max(marks)
-        spans = []
-        if self.t_due is not None:
-            spans.append(("QUEUED", self.t_due,
-                          self.t_admit if self.t_admit is not None
-                          else end))
-        if self.t_admit is not None:
-            spans.append(("PREFILL", self.t_admit,
-                          self.t_prefill_done
-                          if self.t_prefill_done is not None else end))
-        if self.t_prefill_done is not None:
-            spans.append(("DECODE", self.t_prefill_done,
-                          self.t_done if self.t_done is not None
-                          else end))
-        instants = [("preempt", t) for t in self.t_preempt]
-        return spans, instants

@@ -1,11 +1,10 @@
-"""Serving telemetry: one metrics registry, phase-timed step spans, and
-a structured event log.
+"""Serving telemetry: one metrics registry, step-phase profiler spans,
+and a structured event log.
 
 The paper's headline claims are *traffic* claims (EIM/SIDR cut SRAM
 access 86 % vs SparTen), and EIE-style designs live or die on
 per-component access counts made visible, not inferred — so the serving
-engine's observability is a subsystem, not an afterthought.  Three
-layers, all optional and all off by default:
+engine's observability is a subsystem, not an afterthought:
 
 * **Metrics registry** (`MetricsRegistry`): typed ``Counter`` /
   ``Gauge`` / ``Histogram`` metrics (histograms reuse the seeded
@@ -18,17 +17,18 @@ layers, all optional and all off by default:
   as a Prometheus text page (``to_prometheus()``) or a JSON snapshot
   (``--metrics-out``).
 
-* **Step-phase spans** (`StepSpans`): monotonic-clock brackets around
-  the host-side phases of ``ServeEngine.step`` — schedule /
-  deadline-sweep / page-ensure / prefill / decode / host-sync / sample
-  / audit — accumulated into per-phase histograms and, when
-  ``--trace-out`` is set, emitted as Chrome trace-event JSON
-  (perfetto-viewable) together with per-request lifecycle spans
-  (QUEUED → PREFILL → DECODE) and instant markers for preemptions,
-  faults and quarantines.  Spans bracket only host-side code; device
-  time surfaces in the ``host_sync`` phase (the existing
-  block-until-ready point), so enabling tracing adds no host
-  transfers and no extra synchronization.
+* **Step-phase spans**: every ``ServeEngine.step`` is a
+  ``jax.profiler.StepTraceAnnotation`` named ``serve.step`` and each of
+  its host-side phases (``PHASES``: schedule / prefill / prefill-sync /
+  page-ensure / decode / host-sync / sample / deadline-sweep / audit) a
+  ``TraceAnnotation`` ``serve.<phase>`` inside it.  They are recorded
+  whenever a profiler session is on — the engine's own (``--trace-out
+  DIR``) or any caller's — on the profiler's clock, beside the device's
+  operations.  Spans bracket host code only: ``decode`` ends at
+  dispatch, and device time surfaces in ``host_sync`` (and
+  ``prefill_sync``), the existing block-until-ready points.  With
+  telemetry on they also feed the registry's per-phase histograms
+  (`StepSpans`).
 
 * **Event log** (`EventLog`): one JSONL schema unifying lifecycle
   transitions, fallback warnings, fault injections and audit
@@ -36,10 +36,10 @@ layers, all optional and all off by default:
   step, a ``kind`` from ``EVENT_KINDS`` and (where applicable) the
   rid, so "what happened to request 1234" is one grep.
 
-Telemetry-off is the default and is bit-identical and allocation-free
-on the hot path: the engine holds ``spans is None`` / ``events is
-None`` and every bracket is a plain ``is not None`` check — no span
-objects, no context managers, no host transfers (asserted by test).
+Telemetry-off is the default and is bit-identical: the engine holds
+``spans is None`` / ``events is None``; what remains on the hot path is
+one ``TraceAnnotation`` per span, which records nothing (and costs a
+few microseconds) while no profiler session is on.
 
 ``Clock`` is the serving wall clock: started exactly once, *after*
 warmup, through one idempotent ``start()`` — hoisted here from the two
@@ -48,18 +48,19 @@ into the first timed step again.
 """
 from __future__ import annotations
 
+import glob
 import json
 import math
 import os
 import time
-from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
+from typing import Callable, Dict, List, Optional, Union
 
 from repro.serve.trace import RollingStat
 
 __all__ = [
     "Clock", "Counter", "Gauge", "Histogram", "MetricsRegistry",
-    "ChromeTrace", "StepSpans", "EventLog", "Telemetry", "PHASES",
-    "EVENT_KINDS", "load_trace", "validate_trace", "validate_event",
+    "StepSpans", "EventLog", "Telemetry", "PHASES", "STEP_SPAN",
+    "SPAN_PREFIX", "EVENT_KINDS", "read_steps", "validate_event",
     "validate_events",
 ]
 
@@ -101,11 +102,6 @@ class Clock:
         """``now()``, or 0.0 before the clock starts (pre-run events)."""
         return time.perf_counter() - self._t0 if self._t0 is not None \
             else 0.0
-
-    def rel(self, t_abs: float) -> float:
-        """Convert an absolute ``perf_counter`` stamp to clock time."""
-        assert self._t0 is not None
-        return t_abs - self._t0
 
 
 # -------------------------------------------------------------- metrics ----
@@ -312,193 +308,30 @@ class MetricsRegistry:
                       allow_nan=False)
 
 
-# --------------------------------------------------------- chrome trace ----
-
-#: pid/tid layout of the exported trace: engine step + phase spans on
-#: one track, each request's lifecycle on its own thread of a second
-#: process (perfetto renders them as one row per rid).
-PID_ENGINE, TID_STEP = 1, 0
-PID_REQUESTS = 2
-
-
-class ChromeTrace:
-    """Chrome trace-event JSON accumulator (perfetto / chrome://tracing
-    loadable).  Timestamps are serving-clock seconds converted to the
-    format's microseconds; events buffer in memory and ``write()`` dumps
-    the standard ``{"traceEvents": [...]}`` envelope."""
-
-    def __init__(self) -> None:
-        self.events: List[Dict] = []
-        self._named_threads: set = set()
-        self._meta(PID_ENGINE, None, "serve_engine")
-        self._meta(PID_ENGINE, TID_STEP, "step")
-        self._meta(PID_REQUESTS, None, "requests")
-
-    def _meta(self, pid: int, tid: Optional[int], name: str) -> None:
-        if tid is None:
-            self.events.append({"ph": "M", "name": "process_name",
-                                "pid": pid, "tid": 0,
-                                "args": {"name": name}})
-        else:
-            self.events.append({"ph": "M", "name": "thread_name",
-                                "pid": pid, "tid": tid,
-                                "args": {"name": name}})
-
-    def ensure_thread(self, pid: int, tid: int, name: str) -> None:
-        if (pid, tid) not in self._named_threads:
-            self._named_threads.add((pid, tid))
-            self._meta(pid, tid, name)
-
-    def complete(self, name: str, t0_s: float, dur_s: float, *,
-                 pid: int = PID_ENGINE, tid: int = TID_STEP,
-                 cat: str = "phase",
-                 args: Optional[Dict] = None) -> None:
-        ev = {"ph": "X", "name": name, "cat": cat,
-              "ts": t0_s * 1e6, "dur": max(dur_s, 0.0) * 1e6,
-              "pid": pid, "tid": tid}
-        if args:
-            ev["args"] = args
-        self.events.append(ev)
-
-    def counter(self, name: str, t_s: float, values: Dict[str, float], *,
-                pid: int = PID_ENGINE, tid: int = TID_STEP,
-                cat: str = "traffic") -> None:
-        """One counter-track sample (ph "C"): perfetto renders each
-        ``values`` key as a stacked series under ``name`` — the per-phase
-        HBM byte tracks."""
-        self.events.append({"ph": "C", "name": name, "cat": cat,
-                            "ts": t_s * 1e6, "pid": pid, "tid": tid,
-                            "args": dict(values)})
-
-    def instant(self, name: str, t_s: float, *, pid: int = PID_ENGINE,
-                tid: int = TID_STEP, cat: str = "marker",
-                args: Optional[Dict] = None) -> None:
-        ev = {"ph": "i", "name": name, "cat": cat, "ts": t_s * 1e6,
-              "s": "t", "pid": pid, "tid": tid}
-        if args:
-            ev["args"] = args
-        self.events.append(ev)
-
-    def write(self, path: str) -> None:
-        with open(path, "w") as f:
-            json.dump({"traceEvents": self.events,
-                       "displayTimeUnit": "ms"}, f)
-
-
-def load_trace(path: str) -> List[Dict]:
-    with open(path) as f:
-        data = json.load(f)
-    assert isinstance(data, dict) and "traceEvents" in data, \
-        f"{path}: not a Chrome trace-event file"
-    return data["traceEvents"]
-
-
-def validate_trace(events_or_path) -> Dict:
-    """Structural validation of an exported trace (the CI smoke's
-    contract):
-
-    * every phase span nests inside exactly one step span (no phase
-      leaks across a step boundary), and phases within a step do not
-      overlap one another;
-    * per step, the phase durations sum to at most the step duration,
-      and the coverage fraction is reported (the smoke asserts >= 95 %:
-      the phase taxonomy accounts for where step wall time goes);
-    * request spans (QUEUED/PREFILL/DECODE per tid) appear in lifecycle
-      order.
-
-    Returns summary stats; raises ``ValueError`` on violation.
-    """
-    events = (load_trace(events_or_path)
-              if isinstance(events_or_path, str) else events_or_path)
-    eps = 5.0  # us of float slack on span edges
-    steps = sorted((e for e in events
-                    if e.get("ph") == "X" and e.get("cat") == "step"),
-                   key=lambda e: e["ts"])
-    phases = [e for e in events
-              if e.get("ph") == "X" and e.get("cat") == "phase"]
-    by_step: Dict[int, List[Dict]] = {i: [] for i in range(len(steps))}
-    for p in phases:
-        host = None
-        for i, s in enumerate(steps):
-            if (s["ts"] - eps <= p["ts"]
-                    and p["ts"] + p["dur"] <= s["ts"] + s["dur"] + eps):
-                host = i
-                break
-        if host is None:
-            raise ValueError(
-                f"phase span {p['name']} at ts={p['ts']:.1f}us nests in "
-                f"no step span")
-        by_step[host].append(p)
-    coverage = []
-    phase_us = step_us = 0.0
-    for i, s in enumerate(steps):
-        ph = sorted(by_step[i], key=lambda e: e["ts"])
-        for a, b in zip(ph, ph[1:]):
-            if a["ts"] + a["dur"] > b["ts"] + eps:
-                raise ValueError(
-                    f"phases {a['name']} and {b['name']} overlap inside "
-                    f"step {i}")
-        total = sum(p["dur"] for p in ph)
-        if s["dur"] > 0:
-            if total > s["dur"] + eps * max(1, len(ph)):
-                raise ValueError(
-                    f"step {i}: phase durations sum past the step wall "
-                    f"({total:.1f}us > {s['dur']:.1f}us)")
-            coverage.append(total / s["dur"])
-            phase_us += total
-            step_us += s["dur"]
-    order = {"QUEUED": 0, "PREFILL": 1, "DECODE": 2}
-    req_spans: Dict[int, List[Dict]] = {}
-    for e in events:
-        if e.get("ph") == "X" and e.get("cat") == "request":
-            req_spans.setdefault(e["tid"], []).append(e)
-    for tid, spans in req_spans.items():
-        spans.sort(key=lambda e: (e["ts"], order.get(e["name"], 9)))
-        ranks = [order.get(e["name"], -1) for e in spans]
-        if -1 in ranks or ranks != sorted(ranks):
-            raise ValueError(
-                f"request tid={tid}: lifecycle spans out of order: "
-                f"{[e['name'] for e in spans]}")
-    return {
-        "steps": len(steps),
-        "phase_spans": len(phases),
-        "requests": len(req_spans),
-        "min_coverage": min(coverage) if coverage else None,
-        "mean_coverage": (sum(coverage) / len(coverage)
-                          if coverage else None),
-        # duration-weighted: a scheduler hiccup between brackets in one
-        # microsecond-scale step can crater min_coverage without the
-        # taxonomy actually leaking time — this is the 5 % criterion
-        "agg_coverage": phase_us / step_us if step_us else None,
-    }
-
-
 # ----------------------------------------------------------- step spans ----
 
 #: The step-phase taxonomy (DESIGN_SERVING.md §Observability).  Phases
 #: are sequential and non-overlapping inside one step; together they
-#: cover (nearly) the whole host-side step wall, so their histograms
-#: answer "where does a step's time go".
-PHASES = ("schedule", "prefill", "page_ensure", "decode", "host_sync",
-          "sample", "deadline_sweep", "audit")
+#: cover (nearly) the whole host-side step wall.  Each is a profiler span
+#: ``serve.<phase>`` inside the step's ``serve.step`` span.
+PHASES = ("schedule", "prefill", "prefill_sync", "page_ensure", "decode",
+          "host_sync", "sample", "deadline_sweep", "audit")
+
+#: Profiler span names: one ``STEP_SPAN`` per ``ServeEngine.step`` call
+#: (a ``jax.profiler.StepTraceAnnotation`` carrying ``step_num``), and
+#: ``SPAN_PREFIX + phase`` for each phase inside it.
+STEP_SPAN = "serve.step"
+SPAN_PREFIX = "serve."
 
 _PHASE_SEEDS = {p: 0x7e1e + i for i, p in enumerate(PHASES)}
 
 
 class StepSpans:
-    """Phase-timed spans around ``ServeEngine.step``'s host-side code.
+    """The registry histograms that the engine's phase spans feed when
+    telemetry is on: ``step.phase.<p>_s`` per phase and ``step.wall_s``
+    per step, all host seconds on ``time.perf_counter``."""
 
-    ``begin(name)`` / ``end()`` bracket one phase at a time (phases
-    never nest — the step span is the only parent); each bracket costs
-    two ``perf_counter`` reads and one histogram observe.  With a
-    ``ChromeTrace`` attached, every phase and step also emits a
-    complete event on the engine track.
-    """
-
-    def __init__(self, registry: MetricsRegistry, clock: Clock,
-                 trace: Optional[ChromeTrace] = None):
-        self.clock = clock
-        self.trace = trace
+    def __init__(self, registry: MetricsRegistry):
         self.h_phase = {
             p: registry.histogram(
                 f"step.phase.{p}_s", seed=_PHASE_SEEDS[p],
@@ -507,51 +340,58 @@ class StepSpans:
         self.h_step = registry.histogram(
             "step.wall_s", seed=0x57e9,
             help="host-side wall seconds per engine step")
-        self.h_coverage = registry.histogram(
-            "step.phase_coverage", seed=0xc04e,
-            help="fraction of the step wall covered by phase spans")
         self.steps = 0
-        self._t_step: Optional[float] = None
-        self._step_idx = 0
-        self._acc = 0.0
-        self._t_phase: Optional[float] = None
-        self._phase: Optional[str] = None
 
-    def step_begin(self, step: int, t_abs: Optional[float] = None) -> None:
-        self._t_step = time.perf_counter() if t_abs is None else t_abs
-        self._step_idx = step
-        self._acc = 0.0
+    def phase_done(self, name: str, seconds: float) -> None:
+        self.h_phase[name].observe(seconds)
 
-    def begin(self, name: str) -> None:
-        assert self._phase is None, \
-            f"phase {name} opened inside {self._phase}"
-        self._phase = name
-        self._t_phase = time.perf_counter()
-
-    def end(self) -> None:
-        t1 = time.perf_counter()
-        name, t0 = self._phase, self._t_phase
-        assert name is not None, "StepSpans.end() with no open phase"
-        self._phase = None
-        dt = t1 - t0
-        self._acc += dt
-        self.h_phase[name].observe(dt)
-        if self.trace is not None:
-            self.trace.complete(name, self.clock.rel(t0), dt,
-                                cat="phase")
-
-    def step_end(self) -> None:
-        assert self._phase is None, \
-            f"step ended with phase {self._phase} still open"
-        t1 = time.perf_counter()
-        dur = t1 - self._t_step
-        self.h_step.observe(dur)
-        self.h_coverage.observe(self._acc / dur if dur > 0 else 1.0)
+    def step_done(self, seconds: float) -> None:
+        self.h_step.observe(seconds)
         self.steps += 1
-        if self.trace is not None:
-            self.trace.complete("step", self.clock.rel(self._t_step),
-                                dur, cat="step",
-                                args={"step": self._step_idx})
+
+
+def read_steps(trace_dir: str) -> List[Dict]:
+    """The ``serve.step`` spans of the newest profiler trace under
+    ``trace_dir`` in time order, each as ``{"step", "start_ns",
+    "dur_ns", "phases": [(name, start_ns, dur_ns), ...]}`` with the
+    ``serve.<phase>`` spans (names without the prefix) nested in it.
+
+    Raises ``ValueError`` unless every phase span nests in exactly one
+    step span and the phases of one step do not overlap — the contract
+    the CI smoke checks."""
+    from jax.profiler import ProfileData
+    files = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")),
+        key=os.path.getmtime)
+    if not files:
+        raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
+    steps, phases = [], []
+    for plane in ProfileData.from_file(files[-1]).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name == STEP_SPAN:
+                    steps.append({"step": dict(e.stats).get("step_num"),
+                                  "start_ns": e.start_ns,
+                                  "dur_ns": e.duration_ns, "phases": []})
+                elif e.name.startswith(SPAN_PREFIX):
+                    phases.append((e.name[len(SPAN_PREFIX):], e.start_ns,
+                                   e.duration_ns))
+    steps.sort(key=lambda s: s["start_ns"])
+    for ph in phases:
+        _, t0, dur = ph
+        hosts = [s for s in steps if s["start_ns"] <= t0
+                 and t0 + dur <= s["start_ns"] + s["dur_ns"]]
+        if len(hosts) != 1:
+            raise ValueError(f"phase span {ph} nests in {len(hosts)} "
+                             f"step spans, not one")
+        hosts[0]["phases"].append(ph)
+    for s in steps:
+        s["phases"].sort(key=lambda p: p[1])
+        for a, b in zip(s["phases"], s["phases"][1:]):
+            if a[1] + a[2] > b[1]:
+                raise ValueError(f"phases {a[0]} and {b[0]} overlap inside "
+                                 f"step {s['step']}")
+    return steps
 
 
 # ------------------------------------------------------------ event log ----
@@ -643,61 +483,49 @@ class EventLog:
 # ------------------------------------------------------------ telemetry ----
 
 class Telemetry:
-    """The engine's telemetry bundle: step spans (always, when
-    telemetry is on — they feed the registry's phase histograms), a
-    Chrome trace accumulator when ``trace_out`` is set, and an event
-    log when ``events_out`` is set.  ``close()`` writes every
-    configured artifact (idempotent)."""
+    """The engine's telemetry bundle: the phase histograms (always, when
+    telemetry is on), an event log when ``events_out`` is set, and a
+    profiler session writing to the directory ``trace_out`` when that is
+    set.  ``close()`` writes every configured artifact (idempotent)."""
 
-    def __init__(self, registry: MetricsRegistry, clock: Clock, *,
+    def __init__(self, registry: MetricsRegistry, *,
                  trace_out: Optional[str] = None,
                  events_out: Optional[str] = None,
                  metrics_out: Optional[str] = None):
         self.registry = registry
-        self.clock = clock
         self.trace_out = trace_out
         self.events_out = events_out
         self.metrics_out = metrics_out
-        self.trace = ChromeTrace() if trace_out else None
         self.events = EventLog() if events_out else None
-        self.spans = StepSpans(registry, clock, trace=self.trace)
+        self.spans = StepSpans(registry)
+        self._tracing = False
         self._closed = False
 
-    def request_done(self, req) -> None:
-        """Emit a retired/aborted request's lifecycle spans + instant
-        markers onto its own trace thread (one perfetto row per rid)."""
-        if self.trace is None:
-            return
-        spans, instants = req.timeline()
-        if not spans and not instants:
-            return
-        tid = req.rid
-        self.trace.ensure_thread(PID_REQUESTS, tid, f"rid {req.rid}")
-        args = {"rid": req.rid, "state": req.state.name,
-                "tokens": len(req.tokens)}
-        if req.first_token_s is not None:
-            args["first_token_ms"] = round(req.first_token_s * 1e3, 3)
-        for name, t0, t1 in spans:
-            self.trace.complete(name, t0, t1 - t0, pid=PID_REQUESTS,
-                                tid=tid, cat="request", args=args)
-        for name, t in instants:
-            self.trace.instant(name, t, pid=PID_REQUESTS, tid=tid,
-                               cat="request", args={"rid": req.rid})
+    def start(self) -> None:
+        """Open the profiler session for ``trace_out`` (once; the engine
+        calls this when it starts serving, after warmup).  The trace holds
+        the engine's ``serve.*`` spans and the device's operations on one
+        clock; Perfetto opens the ``perfetto_trace.json.gz`` it writes."""
+        if self.trace_out and not self._tracing and not self._closed:
+            import jax
+            jax.profiler.start_trace(self.trace_out,
+                                     create_perfetto_trace=True)
+            self._tracing = True
 
     def close(self) -> List[str]:
-        """Write every configured artifact; returns the paths written.
-        Safe to call more than once (later calls are no-ops)."""
+        """Stop the profiler session and write every configured artifact;
+        returns the paths written.  Safe to call more than once (later
+        calls are no-ops)."""
         if self._closed:
             return []
         self._closed = True
         written = []
-        for path, fn in (
-                (self.trace_out,
-                 lambda p: self.trace.write(p)),
-                (self.events_out,
-                 lambda p: self.events.write(p)),
-                (self.metrics_out,
-                 lambda p: self.registry.write(p))):
+        if self._tracing:
+            import jax
+            jax.profiler.stop_trace()
+            written.append(self.trace_out)
+        for path, fn in ((self.events_out, lambda p: self.events.write(p)),
+                         (self.metrics_out, self.registry.write)):
             if path:
                 d = os.path.dirname(path)
                 if d:

@@ -15,9 +15,7 @@ This module is the traffic-side counterpart of the PR-8 telemetry spine:
   (``int(round(bytes × activated_scale))``), so the ledger sums to the
   ``weight_stream`` aggregates to the byte — pinned by test.  Per-phase
   byte counters live in the engine's ``MetricsRegistry`` (always on,
-  like every other subsystem counter) and, with ``--trace-out``, are
-  emitted as Chrome trace counter tracks (``hbm.decode`` /
-  ``hbm.prefill``).
+  like every other subsystem counter).
 
 * **Cross-check** — ``crosscheck()`` lowers the engine's own jitted
   decode/prefill steps, runs the while-aware HLO analyzer
@@ -229,11 +227,10 @@ class TrafficLedger:
 
     # ------------------------------------------------------- step hooks ----
 
-    def on_decode(self, positions: Sequence[int]) -> Dict[str, int]:
+    def on_decode(self, positions: Sequence[int]) -> None:
         """Account one decode step: the full weight stream (stack +
         head) plus per-slot KV line reads up to each live position and
-        one line write per decoding slot.  Returns the step's byte
-        deltas for the trace counter track."""
+        one line write per decoding slot."""
         weight, _, _ = self._totals()
         read = 0
         for p in positions:
@@ -243,11 +240,8 @@ class TrafficLedger:
         self._c[("decode", "weight")].inc(weight)
         self._c[("decode", "kv_read")].inc(read)
         self._c[("decode", "kv_write")].inc(write)
-        return {"weight_bytes": weight, "kv_read_bytes": read,
-                "kv_write_bytes": write}
 
-    def on_prefill(self, pos: Sequence[int],
-                   lens: Sequence[int]) -> Dict[str, int]:
+    def on_prefill(self, pos: Sequence[int], lens: Sequence[int]) -> None:
         """Account one batched prefill call: the stack streams once (no
         head in the prefill step), each active lane writes ``len`` KV
         lines and attends over its whole resident prefix."""
@@ -264,8 +258,6 @@ class TrafficLedger:
         self._c[("prefill", "weight")].inc(stack)
         self._c[("prefill", "kv_read")].inc(read)
         self._c[("prefill", "kv_write")].inc(write)
-        return {"weight_bytes": stack, "kv_read_bytes": read,
-                "kv_write_bytes": write}
 
     # ------------------------------------------------------- projections ----
 

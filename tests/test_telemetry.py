@@ -12,17 +12,22 @@ Pillars:
   every pre-existing section and field with unchanged names and types
   across the knob matrix ({paged, prefix_reuse, preempt, audit} ×
   {packed, dense}), so downstream bench parsers can't silently break;
-* **off == on** — telemetry-off holds no span/event objects (the hot
-  path stays allocation-free) and serves bit-identical tokens to a
-  telemetry-on run of the same trace;
-* **artifacts** — the Chrome trace validates (phases nest in steps,
-  no overlap, lifecycle order), the JSONL event log matches the schema
-  with monotonic timestamps, and ``_bench_io`` merges sections
-  atomically without clobbering its neighbours.
+* **off == on** — telemetry-off holds no histogram/event objects and
+  serves bit-identical tokens to a telemetry-on run of the same trace;
+* **profiler spans** — one ``serve.step`` per ``step()`` call with its
+  ``serve.<phase>`` spans nested in it, with telemetry off (a caller's
+  profiler session) and on (``trace_out``), and the serving programs
+  carry the ``attention`` / ``ffn`` / ``lm_head`` scopes in their
+  ``op_name`` metadata;
+* **artifacts** — the profiler trace reads back (phases nest in steps,
+  no overlap), the JSONL event log matches the schema with monotonic
+  timestamps, and ``_bench_io`` merges sections atomically without
+  clobbering its neighbours.
 """
 import json
 import math
 import pathlib
+import re
 import sys
 import time
 
@@ -31,8 +36,9 @@ import pytest
 
 from repro.configs import get_smoke_config
 from repro.serve import (Clock, MetricsRegistry, ServeEngine,
-                         poisson_trace, validate_events, validate_trace)
-from repro.serve.telemetry import (EVENT_KINDS, PHASES, validate_event)
+                         poisson_trace, validate_events)
+from repro.serve.telemetry import (EVENT_KINDS, PHASES, read_steps,
+                                   validate_event)
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent
                        / "benchmarks"))
@@ -299,42 +305,99 @@ def test_telemetry_off_is_allocation_free_and_identical(tmp_path):
 
     eng_on = _engine(paged=True, page_len=8, prefill_chunk=8,
                      prefix_reuse=True, audit=True,
-                     trace_out=str(tmp_path / "t.json"),
+                     trace_out=str(tmp_path / "trace"),
                      events_out=str(tmp_path / "e.jsonl"),
                      metrics_out=str(tmp_path / "m.json"))
-    _, served_on = _run(eng_on, requests=4)
+    try:
+        _, served_on = _run(eng_on, requests=4)
+    finally:
+        paths = eng_on.close()
     assert served_on == served_off
-    paths = eng_on.close()
     assert [pathlib.Path(p).name for p in paths] == \
-        ["t.json", "e.jsonl", "m.json"]
+        ["trace", "e.jsonl", "m.json"]
     assert eng_on.close() == []    # idempotent
 
-    stats = validate_trace(str(tmp_path / "t.json"))
-    assert stats["steps"] > 0 and stats["requests"] == 4
-    assert stats["agg_coverage"] > 0.5
+    steps = read_steps(str(tmp_path / "trace"))
+    assert len(steps) == eng_on.spans.steps > 0
+    phase_ns = sum(p[2] for s in steps for p in s["phases"])
+    assert phase_ns > 0.5 * sum(s["dur_ns"] for s in steps)
     n = validate_events(str(tmp_path / "e.jsonl"))
     assert n > 0
 
 
 def test_trace_spans_and_phases(tmp_path):
-    eng = _engine(prefill_chunk=8, trace_out=str(tmp_path / "t.json"))
-    _run(eng, requests=3)
-    eng.close()
-    from repro.serve import load_trace
-    events = load_trace(str(tmp_path / "t.json"))
-    names = {e["name"] for e in events
-             if e.get("ph") == "X" and e.get("cat") == "phase"}
+    eng = _engine(prefill_chunk=8, trace_out=str(tmp_path / "trace"))
+    try:
+        _run(eng, requests=3)
+    finally:
+        eng.close()
+    steps = read_steps(str(tmp_path / "trace"))
+    names = {p[0] for s in steps for p in s["phases"]}
     assert names <= set(PHASES)
-    assert {"schedule", "decode", "sample", "host_sync"} <= names
-    req_names = {e["name"] for e in events
-                 if e.get("ph") == "X" and e.get("cat") == "request"}
-    assert req_names <= {"QUEUED", "PREFILL", "DECODE"}
+    assert {"schedule", "prefill", "prefill_sync", "decode", "sample",
+            "host_sync"} <= names
     # registry histograms accumulated the same spans (step() calls,
     # not report()["steps"] — that gauge includes idle fast-forward)
     h = eng.metrics.get("step.wall_s")
-    assert h.count == eng.spans.steps > 0
-    cov = eng.metrics.get("step.phase_coverage")
-    assert cov.mean > 0.5
+    assert h.count == eng.spans.steps == len(steps) > 0
+    for p in PHASES:
+        assert eng.metrics.get(f"step.phase.{p}_s").count == sum(
+            q[0] == p for s in steps for q in s["phases"])
+    # the phases account for most of the step wall
+    phase_s = sum(eng.metrics.get(f"step.phase.{p}_s").sum for p in PHASES)
+    assert phase_s > 0.5 * h.sum
+
+
+@pytest.mark.parametrize("telemetry", [False, True], ids=["off", "on"])
+def test_phase_spans_nest_in_one_step_per_call(tmp_path, telemetry):
+    """One ``serve.step`` span per ``step()`` call, numbered by the
+    engine's step, each phase span inside exactly one of them — whether
+    the engine runs its own profiler session (``trace_out``) or a caller
+    does with telemetry off."""
+    trace_dir = str(tmp_path / "trace")
+    eng = _engine(paged=True, page_len=8, prefill_chunk=8,
+                  **({"trace_out": trace_dir} if telemetry else {}))
+    trace = poisson_trace(3, rate=0.5, seed=1, vocab_size=CFG.vocab_size,
+                          prompt_len=(1, 12), max_new=(2, 5))
+    numbers = []
+    with jax.set_mesh(eng.mesh):
+        for spec in trace:
+            eng.submit(**{**spec, "arrival": 0})
+        eng.warmup()
+        if not telemetry:
+            jax.profiler.start_trace(trace_dir)
+        try:
+            while eng.scheduler.has_work:
+                numbers.append(eng._steps)
+                eng.step()
+        finally:
+            if telemetry:
+                eng.close()
+            else:
+                jax.profiler.stop_trace()
+    steps = read_steps(trace_dir)
+    assert [s["step"] for s in steps] == numbers
+    assert all(s["phases"] and s["phases"][0][0] == "schedule"
+               for s in steps)
+    names = {p[0] for s in steps for p in s["phases"]}
+    assert {"prefill", "prefill_sync", "page_ensure", "decode",
+            "host_sync", "sample"} <= names <= set(PHASES)
+
+
+@pytest.mark.parametrize("program,scopes", [
+    ("decode", ("attention", "ffn", "lm_head")),
+    ("prefill", ("attention", "ffn"))])
+def test_programs_carry_named_scopes(program, scopes):
+    """The compiled decode and prefill programs name their layers in the
+    ``op_name`` metadata the profiler attaches to each device op."""
+    eng = _engine(paged=True, page_len=8, prefill_chunk=8)
+    with jax.set_mesh(eng.mesh):
+        hlo = eng.traffic._lowered(program).compile().as_text()
+    names = re.findall(r'op_name="([^"]*)"', hlo)
+    for scope in scopes:
+        assert any(f"/{scope}/" in n for n in names), scope
+    if program == "prefill":
+        assert not any("/lm_head/" in n for n in names)
 
 
 def test_event_log_schema(tmp_path):

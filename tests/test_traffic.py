@@ -16,9 +16,10 @@ The load-bearing guarantees:
 * **off == on** — a ``traffic_out``-less engine serves bit-identical
   tokens and holds no artifact state; the ledger's counters live in
   the always-on registry like every other subsystem's;
-* the trace gains ``hbm.*`` counter tracks that reconcile with the
-  registry totals, and the artifact round-trips through
-  ``scripts/traffic_report.py``'s budget gate.
+* the registry's per-phase byte counters reconcile with the report and
+  with the profiler trace's ``serve.decode`` / ``serve.prefill`` spans,
+  and the artifact round-trips through ``scripts/traffic_report.py``'s
+  budget gate.
 """
 import json
 import pathlib
@@ -29,7 +30,8 @@ import jax
 import pytest
 
 from repro.configs import get_smoke_config
-from repro.serve import ServeEngine, load_trace, poisson_trace
+from repro.serve import ServeEngine, poisson_trace
+from repro.serve.telemetry import read_steps
 from repro.serve.traffic import (CROSSCHECK_BANDS, TRAFFIC_KINDS,
                                  TRAFFIC_PHASES, role_of)
 
@@ -157,10 +159,13 @@ def test_crosscheck_floor_scales_with_dispatch():
 # -------------------------------------------------------- phase hooks ----
 
 def test_phase_counters_accumulate_and_match_trace(tmp_path):
-    trace_path = tmp_path / "t.json"
+    trace_dir = str(tmp_path / "trace")
     eng = _engine(paged=True, page_len=8, prefill_chunk=8,
-                  trace_out=str(trace_path))
-    rep, _ = _run(eng, requests=4)
+                  trace_out=trace_dir)
+    try:
+        rep, _ = _run(eng, requests=4)
+    finally:
+        eng.close()
     ph = rep["traffic"]["phases"]
     assert ph["decode"]["steps"] > 0
     assert ph["decode"]["weight_bytes"] \
@@ -172,19 +177,15 @@ def test_phase_counters_accumulate_and_match_trace(tmp_path):
     stack = (rep["traffic"]["weight"]["sparse_bytes_per_step"]
              - rep["traffic"]["per_role"]["head"]["sparse_bytes"])
     assert ph["prefill"]["weight_bytes"] == ph["prefill"]["calls"] * stack
-    eng.close()
-    events = load_trace(str(trace_path))
-    by_track = {}
-    for e in events:
-        if e.get("ph") == "C" and e.get("cat") == "traffic":
-            for k, v in e["args"].items():
-                by_track.setdefault((e["name"], k), 0)
-                by_track[(e["name"], k)] += v
-    for phase, track in (("decode", "hbm.decode"),
-                         ("prefill", "hbm.prefill")):
+    for phase in TRAFFIC_PHASES:
         for kind in TRAFFIC_KINDS:
-            assert by_track[(track, f"{kind}_bytes")] \
-                == ph[phase][f"{kind}_bytes"], (phase, kind)
+            name = f"traffic.{phase}.{kind}_bytes"
+            assert eng.metrics.get(name).value \
+                == ph[phase][f"{kind}_bytes"], name
+    # one accounted decode step / prefill call per traced phase span
+    spans = [p[0] for s in read_steps(trace_dir) for p in s["phases"]]
+    assert spans.count("decode") == ph["decode"]["steps"]
+    assert spans.count("prefill") == ph["prefill"]["calls"]
 
 
 def test_registry_counters_registered():
