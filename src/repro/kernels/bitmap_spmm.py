@@ -16,12 +16,24 @@ TPU adaptation of the paper's EIM + SIDR (DESIGN.md §2):
   dimension (its index map ignores i) — the SIDR column-broadcast;
 * output-stationary f32 accumulator in VMEM across the K grid axis.
 
-Grid: (G, M/BM, N/BN, K/BK), K innermost (sequential accumulation); G is
-the group axis of a group-stacked weight (1 for a plain matrix).
+Grid: (G, M/bm, N/BN, KT/TK), the K strips innermost (sequential
+accumulation); G is the group axis of a group-stacked weight (1 for a
+plain matrix).  One grid step takes a *strip* of TK consecutive K-tiles
+of one output column block: its blocks are (bm, TK·BK) of x and
+(TK, ·, BN) of bitmap words and value rows, and its body expands the TK
+tiles in K order, ``STRIP_UNROLL`` to a loop iteration so that
+independent tiles' expansion chains overlap, accumulating each
+``x[:, t·BK:(t+1)·BK] @ W_t`` into one f32 accumulator.  TK is worked out
+from the shapes (``strip_tiles``): the largest divisor of KT whose
+double-buffered blocks fit ``STRIP_VMEM_BUDGET``.  At olmo-1b's widths
+it is the whole K, so x's block index is constant across the column
+blocks and x is fetched once per call.  A shape whose blocks fit only
+one tile per step runs one tile per step (TK = 1).
 """
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -33,6 +45,17 @@ from repro.sparse.format import _TILE_ND, WORD_ROWS, BitmapWeight
 
 #: lane width of a TPU vector register: the tile's column width on chip
 LANES = 128
+#: VMEM bytes the double-buffered x, bitmap and value blocks of one grid
+#: step may take: half the 16 MiB default scoped limit, the rest left to
+#: the tiles being expanded, the accumulator and the output block
+STRIP_VMEM_BUDGET = 8 * 2 ** 20
+#: K-tiles of a strip expanded per loop iteration, statically unrolled
+#: so that the scheduler overlaps independent tiles' expansion chains.
+#: Timed alone on a v5e at olmo-1b's widths and M = 16, a 128x128 tile
+#: took 0.86 us at 1 tile per iteration, 0.69 at 2, 0.62 at 4, 0.49 at 8
+#: and 0.42 at 16; 32 gained only at K = 8192 (0.39) for twice the
+#: compile time (benchmarks/spmm_tile_time.py)
+STRIP_UNROLL = 16
 
 
 def tile_fits_chip(k: int, n: int, block: Tuple[int, int]) -> Optional[str]:
@@ -94,29 +117,85 @@ def _decompress_tile(words, vals, bk: int, dtype):
 
 
 def _kernel(x_ref, bits_ref, vals_ref, o_ref, acc_ref, *, bk: int,
-            n_k: int):
-    k = pl.program_id(3)
+            n_s: int):
+    s = pl.program_id(3)
+    tk = bits_ref.shape[0]
 
-    @pl.when(k == 0)
+    @pl.when(s == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w_tile = _decompress_tile(bits_ref[...], vals_ref[...], bk, x_ref.dtype)
-    acc_ref[...] += jnp.dot(x_ref[...], w_tile,
-                            preferred_element_type=jnp.float32)
+    def tile(t, acc):
+        w_tile = _decompress_tile(bits_ref[t], vals_ref[t], bk, x_ref.dtype)
+        x = (x_ref[:, t * bk:(t + 1) * bk] if isinstance(t, int)
+             else x_ref[:, pl.ds(pl.multiple_of(t * bk, bk), bk)])
+        return acc + jnp.dot(x, w_tile, preferred_element_type=jnp.float32)
 
-    @pl.when(k == n_k - 1)
+    # u tiles per iteration, statically unrolled (Mosaic lowers a loop
+    # only whole or not unrolled at all)
+    u = max(d for d in range(1, min(STRIP_UNROLL, tk) + 1) if tk % d == 0)
+
+    def tiles(i, acc):
+        for j in range(u):
+            acc = tile(i * u + j, acc)
+        return acc
+
+    if tk == u:
+        acc_ref[...] = tiles(0, acc_ref[...])
+    else:
+        acc_ref[...] = jax.lax.fori_loop(0, tk // u, tiles, acc_ref[...])
+
+    @pl.when(s == n_s - 1)
     def _store():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-def _sublane(dtype) -> int:
-    """Minimum second-to-minor tile size for the dtype (f32: 8, bf16: 16)."""
-    return {2: 16, 1: 32}.get(jnp.dtype(dtype).itemsize, 8)
+def _sublane(itemsize: int) -> int:
+    """Minimum second-to-minor tile size for an item size (f32: 8,
+    bf16: 16)."""
+    return {2: 16, 1: 32}.get(itemsize, 8)
 
 
 def _round_up(x: int, mult: int) -> int:
     return -(-x // mult) * mult
+
+
+def strip_tiles(kt: int, bk: int, nw: int, q: int, bn: int, bm: int,
+                x_itemsize: int, v_itemsize: int) -> int:
+    """K-tiles per grid step (TK): the largest divisor of ``kt`` whose
+    double-buffered blocks — x (bm, TK·bk), bitmap words (TK, nw, bn)
+    int32 and value rows (TK, q, bn), each padded to its VMEM tiling —
+    fit ``STRIP_VMEM_BUDGET``; 1 when no larger divisor fits."""
+    per_tile = 2 * (bm * bk * x_itemsize
+                    + _round_up(nw, 8) * bn * 4
+                    + _round_up(q, _sublane(v_itemsize)) * bn
+                    * v_itemsize)
+    return max(d for d in range(1, kt + 1)
+               if kt % d == 0 and (d == 1
+                                   or d * per_tile <= STRIP_VMEM_BUDGET))
+
+
+def _row_block(m: int, bm: int, x_dtype) -> int:
+    """The row tile a call with M = ``m`` runs at: ``bm``, or for an M
+    that ``bm`` does not divide, M rounded up to the dtype's sublane
+    multiple when that is smaller (the small-M path)."""
+    if m % bm == 0:
+        return bm
+    return min(bm, _round_up(m, _sublane(jnp.dtype(x_dtype).itemsize)))
+
+
+def grid_steps(w: BitmapWeight, m: int, x_dtype, bm: int = 128) -> int:
+    """Grid steps of the ``bitmap_spmm_grouped`` calls that stream every
+    matrix held in ``w`` once, at ``m`` rows per matrix.  Leading axes of
+    ``w``'s arrays (periods, groups, shards) count one matrix each; the
+    tile counts are the per-shard ones its arrays hold."""
+    kt, nt, q, bn = w.values.shape[-4:]
+    nw = w.packed_bits.shape[-2]
+    bm = _row_block(m, bm, x_dtype)
+    tk = strip_tiles(kt, w.block[0], nw, q, bn, bm,
+                     jnp.dtype(x_dtype).itemsize, w.values.dtype.itemsize)
+    mats = math.prod(w.values.shape[:-4])
+    return mats * -(-m // bm) * nt * (kt // tk)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "interpret", "out_dtype"))
@@ -147,26 +226,29 @@ def bitmap_spmm_grouped(x: jax.Array, w: BitmapWeight, *, bm: int = 128,
     out_dtype = out_dtype or x.dtype
 
     if m % bm != 0:
-        bm = min(bm, _round_up(m, _sublane(x.dtype)))
+        bm = _row_block(m, bm, x.dtype)
         m_pad = _round_up(m, bm)
         if m_pad != m:
             xp = jnp.pad(x, ((0, 0), (0, m_pad - m), (0, 0)))
             return bitmap_spmm_grouped(xp, w, bm=bm, interpret=interpret,
                                        out_dtype=out_dtype)[:, :m]
+    tk = strip_tiles(kt, bk, nw, q, bn, bm, x.dtype.itemsize,
+                     w.values.dtype.itemsize)
+    n_s = kt // tk
 
     sq = pl.Squeezed()
     return pl.pallas_call(
-        functools.partial(_kernel, bk=bk, n_k=kt),
-        grid=(g, m // bm, nt, kt),
+        functools.partial(_kernel, bk=bk, n_s=n_s),
+        grid=(g, m // bm, nt, n_s),
         in_specs=[
-            pl.BlockSpec((sq, bm, bk), lambda gi, i, j, kq: (gi, i, kq)),
-            pl.BlockSpec((sq, sq, sq, nw, bn),
-                         lambda gi, i, j, kq: (gi, kq, j, 0, 0)),
-            pl.BlockSpec((sq, sq, sq, q, bn),
-                         lambda gi, i, j, kq: (gi, kq, j, 0, 0)),
+            pl.BlockSpec((sq, bm, tk * bk), lambda gi, i, j, s: (gi, i, s)),
+            pl.BlockSpec((sq, tk, sq, nw, bn),
+                         lambda gi, i, j, s: (gi, s, j, 0, 0)),
+            pl.BlockSpec((sq, tk, sq, q, bn),
+                         lambda gi, i, j, s: (gi, s, j, 0, 0)),
         ],
         out_specs=pl.BlockSpec((sq, bm, bn),
-                               lambda gi, i, j, kq: (gi, i, j)),
+                               lambda gi, i, j, s: (gi, i, j)),
         out_shape=jax.ShapeDtypeStruct((g, m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         compiler_params=pltpu.CompilerParams(

@@ -290,6 +290,12 @@ def paged_gather(pool: jax.Array, page_table: jax.Array) -> jax.Array:
 # ----------------------------------------------------------------- MoE -----
 
 
+def expert_capacity(tokens: int, cfg: ModelConfig) -> int:
+    """Rows each expert takes from a dispatch over ``tokens`` tokens."""
+    return int(tokens * cfg.top_k * cfg.capacity_factor
+               / cfg.num_experts) + 1
+
+
 def expert_matmul_or_bitmap(h: jax.Array, w: jax.Array, bw, impl
                             ) -> jax.Array:
     """Per-expert GEMM ``h[..., e, :, :] @ w[e]`` for expert stacks.
@@ -320,7 +326,7 @@ def _moe_ffn_global(params: dict, x: jax.Array, cfg: ModelConfig,
     b, s, d = x.shape
     e, k = cfg.num_experts, cfg.top_k
     t = b * s
-    cap = int(t * k * cfg.capacity_factor / e) + 1
+    cap = expert_capacity(t, cfg)
     xt = x.reshape(t, d)
     logits = matmul_or_bitmap(xt, params["router"], pk.get("router"), impl)
     probs = jax.nn.softmax(logits.astype(jnp.float32), -1)
@@ -375,7 +381,7 @@ def moe_ffn(params: dict, x: jax.Array, cfg: ModelConfig,
     pk = packed or {}
     b, s, d = x.shape
     e, k = cfg.num_experts, cfg.top_k
-    cap = int(s * k * cfg.capacity_factor / e) + 1
+    cap = expert_capacity(s, cfg)
 
     logits = matmul_or_bitmap(x, params["router"], pk.get("router"), impl)
     probs = jax.nn.softmax(logits.astype(jnp.float32), -1)

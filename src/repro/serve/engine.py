@@ -78,8 +78,8 @@ from repro.serve.cache import SlotKVCache
 from repro.serve.errors import (DeadlineExceeded, RequestRejected,
                                 ServeOverloaded)
 from repro.serve.faults import FaultPlan, InvariantAuditor
-from repro.serve.packed import (PackedModel, block_reason, choose_block,
-                                pack_model)
+from repro.serve.packed import (ROUTED_EXPERT, PackedModel, block_reason,
+                                choose_block, pack_model)
 from repro.serve.paging import OutOfPages, PagedKVCache
 from repro.serve.prefill import PrefillPlanner
 from repro.serve.request import Request, RequestState
@@ -1530,12 +1530,32 @@ class ServeEngine:
         if self.head_shard_fallback:
             shard_fb["lm_head"] = self.head_shard_fallback
         return {**rep,
+                "spmm_grid_steps_per_step": self._spmm_grid_steps(),
                 "sparse_bytes_per_step": sparse,
                 "dense_bytes_per_step": dense,
                 "reduction": dense / sparse if sparse else 1.0,
                 "device_sparse_bytes_per_step": dev_sparse,
                 "device_dense_bytes_per_step": dev_dense,
                 "shard_fallbacks": shard_fb}
+
+    def _spmm_grid_steps(self) -> int:
+        """``bitmap_spmm`` grid steps of one decode step on the Pallas
+        path: each packed matrix once, at the decode rows (``num_slots``;
+        a routed expert stack at each expert's capacity over them), and
+        the packed head."""
+        from repro.kernels.bitmap_spmm import grid_steps
+        from repro.models.layers import expert_capacity
+        dt = jnp.dtype(self.cfg.compute_dtype)
+        rows = self.num_slots
+        leaves = self.packed.leaves() if self.packed is not None else []
+        steps = 0
+        for path, bw in leaves:
+            routed = tuple(path.split("/")[-2:]) in ROUTED_EXPERT
+            m = rows * expert_capacity(1, self.cfg) if routed else rows
+            steps += grid_steps(bw, m, dt)
+        if self.lm_weight is not None:
+            steps += grid_steps(self.lm_weight, rows, dt)
+        return steps
 
     def prefill_report(self) -> dict:
         """The prefill section: chunk-call accounting + the step split."""

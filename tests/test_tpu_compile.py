@@ -17,6 +17,7 @@ from repro.kernels.bitmap_spmm import bitmap_spmm, bitmap_spmm_grouped
 from repro.sparse.format import WORD_ROWS, BitmapWeight
 
 SLOTS, CHUNK = 4, 32                        # decode M, prefill M / slot
+CELL_SLOTS = 16                 # the olmo-1b.gen benchmark cell's decode M
 OLMO = {"wq": (2048, 2048), "wo": (2048, 2048), "w_up": (2048, 8192),
         "w_down": (8192, 2048), "head": (2048, 50304)}
 GRANITE_EXPERTS, GRANITE_D, GRANITE_FF = 40, 1536, 512
@@ -66,6 +67,18 @@ def test_bitmap_spmm_compiles_at_olmo_widths(one_chip, name, m):
     compiled = jax.jit(lambda x, w: bitmap_spmm(x, w)).lower(
         x, _weight(k, n, one_chip)).compile()
     _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("name", ["w_down"])
+def test_bitmap_spmm_compiles_at_cell_decode_rows(one_chip, name):
+    """K = 8192 in one strip of 64 tiles (four unrolled loop iterations)
+    at the benchmark cell's 16 decode rows, with bf16 values."""
+    k, n = OLMO[name]
+    x = jax.ShapeDtypeStruct((CELL_SLOTS, k), jnp.bfloat16,
+                             sharding=one_chip)
+    w = _weight(k, n, one_chip, dtype=jnp.bfloat16)
+    _assert_kernel(jax.jit(lambda x, w: bitmap_spmm(x, w)).lower(
+        x, w).compile())
 
 
 @pytest.mark.parametrize("values_dtype", [jnp.float32, jnp.bfloat16])

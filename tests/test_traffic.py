@@ -110,6 +110,22 @@ def test_ledger_tracks_quarantine():
         == ws["sparse_bytes_per_step"]
 
 
+@pytest.mark.parametrize("arch,knobs,steps", [
+    # 2 periods x 7 matrices, each one (K, N) tile, and the head's two
+    # 128-wide column blocks
+    ("olmo-1b", {}, 2 * 7 + 2),
+    # 2 periods x (4 attention matrices + 3 stacks of 5 experts), each
+    # one tile at 2 slots x 1 capacity row; the head serves dense
+    ("granite-moe-3b-a800m", {}, 2 * (4 + 3 * 5)),
+    ("olmo-1b", {"stream_weights": False, "bitmap_head": False}, 0),
+], ids=["olmo-1b", "granite-moe-3b-a800m", "dense"])
+def test_weight_stream_counts_spmm_grid_steps(arch, knobs, steps):
+    """``spmm_grid_steps_per_step`` equals the hand count of the
+    ``bitmap_spmm`` grid steps one decode step runs."""
+    ws = _engine(arch, **knobs).weight_stream_report()
+    assert ws["spmm_grid_steps_per_step"] == steps
+
+
 # ----------------------------------- modeled vs compiled (hlo_counters) ----
 
 @pytest.mark.parametrize("arch", ARCHS)
