@@ -1,5 +1,5 @@
 """granite-moe-3b-a800m [moe]: 32L d=1536 24H (GQA kv=8) per-expert d_ff=512,
-MoE 40 experts top-8, vocab=49155.  [hf:ibm-granite/granite-3.0-1b-a400m]
+MoE 40 experts top-8, vocab=49155.  [hf:ibm-granite/granite-3.0-3b-a800m-base]
 """
 from repro.models.config import BlockCfg, ModelConfig
 

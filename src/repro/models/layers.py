@@ -7,7 +7,7 @@ sequences, sort-based MoE dispatch with static capacity.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -289,11 +289,26 @@ def paged_gather(pool: jax.Array, page_table: jax.Array) -> jax.Array:
 
 # ----------------------------------------------------------------- MoE -----
 
+#: ``jax.named_scope`` names of ``moe_ffn``'s four stages (inside the
+#: block's ``ffn`` scope): the router with its softmax and top-k, the
+#: sort, rank and scatter into the capacity buffers, the three grouped
+#: expert products, and the gather and gate-weighted sum back per token
+MOE_ROUTE_SCOPE, MOE_DISPATCH_SCOPE, MOE_EXPERTS_SCOPE, MOE_COMBINE_SCOPE = (
+    "moe_route", "moe_dispatch", "moe_experts", "moe_combine")
+
 
 def expert_capacity(tokens: int, cfg: ModelConfig) -> int:
     """Rows each expert takes from a dispatch over ``tokens`` tokens."""
     return int(tokens * cfg.top_k * cfg.capacity_factor
                / cfg.num_experts) + 1
+
+
+def moe_rows(tokens: int, cfg: ModelConfig) -> Tuple[int, int]:
+    """Rows of ``moe_ffn``'s dispatch of one batch row of ``tokens``
+    tokens: the routed (token, expert) pairs, and the rows the grouped
+    expert products compute (every expert's capacity buffer)."""
+    return (tokens * cfg.top_k,
+            cfg.num_experts * expert_capacity(tokens, cfg))
 
 
 def expert_matmul_or_bitmap(h: jax.Array, w: jax.Array, bw, impl
@@ -381,50 +396,58 @@ def moe_ffn(params: dict, x: jax.Array, cfg: ModelConfig,
     pk = packed or {}
     b, s, d = x.shape
     e, k = cfg.num_experts, cfg.top_k
-    cap = expert_capacity(s, cfg)
+    _, buf_rows = moe_rows(s, cfg)
+    cap = buf_rows // e
 
-    logits = matmul_or_bitmap(x, params["router"], pk.get("router"), impl)
-    probs = jax.nn.softmax(logits.astype(jnp.float32), -1)
-    gate, expert_idx = jax.lax.top_k(probs, k)           # (B, S, k)
-    gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+    with jax.named_scope(MOE_ROUTE_SCOPE):
+        logits = matmul_or_bitmap(x, params["router"], pk.get("router"),
+                                  impl)
+        probs = jax.nn.softmax(logits.astype(jnp.float32), -1)
+        gate, expert_idx = jax.lax.top_k(probs, k)       # (B, S, k)
+        gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
 
-    flat_e = expert_idx.reshape(b, s * k)
-    order = jnp.argsort(flat_e, axis=-1, stable=True)    # (B, S*k)
-    sorted_e = jnp.take_along_axis(flat_e, order, axis=-1)
-    # rank within expert group = position − first occurrence (sorted rows);
-    # §Perf iter 4: the one-hot/cumsum rank cost (B, S·k, E) int traffic
-    # (~900 GB/step for moonshot); searchsorted is O(S·k·log)
-    first = jax.vmap(lambda row: jnp.searchsorted(row, row, side="left")
-                     )(sorted_e)
-    rank = jnp.arange(s * k)[None, :] - first
-    keep = rank < cap
-    slot = sorted_e * cap + jnp.where(keep, rank, 0)     # (B, S*k)
-    src = order // k                                      # token id per row
+    with jax.named_scope(MOE_DISPATCH_SCOPE):
+        flat_e = expert_idx.reshape(b, s * k)
+        order = jnp.argsort(flat_e, axis=-1, stable=True)    # (B, S*k)
+        sorted_e = jnp.take_along_axis(flat_e, order, axis=-1)
+        # rank within expert group = position − first occurrence (sorted
+        # rows); §Perf iter 4: the one-hot/cumsum rank cost (B, S·k, E)
+        # int traffic (~900 GB/step for moonshot); searchsorted is
+        # O(S·k·log)
+        first = jax.vmap(lambda row: jnp.searchsorted(row, row,
+                                                      side="left"))(sorted_e)
+        rank = jnp.arange(s * k)[None, :] - first
+        keep = rank < cap
+        slot = sorted_e * cap + jnp.where(keep, rank, 0)     # (B, S*k)
+        src = order // k                                      # token id
 
-    rows = jnp.arange(b)[:, None]
-    gathered = jnp.take_along_axis(x, src[..., None], axis=1)  # (B,S*k,D)
-    buf = jnp.zeros((b, e * cap, d), x.dtype)
-    buf = buf.at[rows, slot].add(
-        jnp.where(keep[..., None], gathered, 0))
-    buf = shard_utils.hint(buf.reshape(b, e, cap, d), "batch")
+        rows = jnp.arange(b)[:, None]
+        gathered = jnp.take_along_axis(x, src[..., None], axis=1)
+        buf = jnp.zeros((b, buf_rows, d), x.dtype)
+        buf = buf.at[rows, slot].add(
+            jnp.where(keep[..., None], gathered, 0))
+        buf = shard_utils.hint(buf.reshape(b, e, cap, d), "batch")
 
-    h = activation(expert_matmul_or_bitmap(buf, params["w_gate"],
-                                           pk.get("w_gate"), impl), cfg.act)
-    h = h * expert_matmul_or_bitmap(buf, params["w_up"], pk.get("w_up"),
-                                    impl)
-    # §Perf iter 4: gather h across the F shards so the w_down contraction
-    # and the whole combine run locally on D shards (no capacity-buffer AR)
-    h = shard_utils.hint(h, "batch", None, None, None)
-    y = expert_matmul_or_bitmap(h, params["w_down"], pk.get("w_down"),
-                                impl).reshape(b, e * cap, d)
-    y = shard_utils.hint(y, "batch", None, "model")
+    with jax.named_scope(MOE_EXPERTS_SCOPE):
+        h = activation(expert_matmul_or_bitmap(
+            buf, params["w_gate"], pk.get("w_gate"), impl), cfg.act)
+        h = h * expert_matmul_or_bitmap(buf, params["w_up"], pk.get("w_up"),
+                                        impl)
+        # §Perf iter 4: gather h across the F shards so the w_down
+        # contraction and the whole combine run locally on D shards (no
+        # capacity-buffer AR)
+        h = shard_utils.hint(h, "batch", None, None, None)
+        y = expert_matmul_or_bitmap(h, params["w_down"], pk.get("w_down"),
+                                    impl).reshape(b, buf_rows, d)
+        y = shard_utils.hint(y, "batch", None, "model")
 
-    out_tok = jnp.take_along_axis(y, slot[..., None], axis=1)  # (B,S*k,D)
-    gval = jnp.take_along_axis(gate.reshape(b, s * k), order, axis=-1)
-    contrib = jnp.where(keep[..., None], out_tok, 0).astype(jnp.float32)
-    out = jnp.zeros((b, s, d), jnp.float32)
-    out = out.at[rows, src].add(contrib * gval[..., None])
-    return out.astype(x.dtype)
+    with jax.named_scope(MOE_COMBINE_SCOPE):
+        out_tok = jnp.take_along_axis(y, slot[..., None], axis=1)
+        gval = jnp.take_along_axis(gate.reshape(b, s * k), order, axis=-1)
+        contrib = jnp.where(keep[..., None], out_tok, 0).astype(jnp.float32)
+        out = jnp.zeros((b, s, d), jnp.float32)
+        out = out.at[rows, src].add(contrib * gval[..., None])
+        return out.astype(x.dtype)
 
 
 def matmul_or_bitmap(h: jax.Array, w: jax.Array, bw, impl) -> jax.Array:

@@ -27,8 +27,11 @@ RWKV_DECAY_RANK = 64
 #: ``jax.named_scope`` names of the serving programs' layers, carried in
 #: each HLO op's ``op_name`` metadata (and so in a profiler trace): the
 #: attention core (RoPE, K/V write, gather, scores, softmax, PV — not the
-#: q/k/v/o projections), the MLP/MoE block, and the head with sampling.
+#: q/k/v/o projections), the MLP/MoE block, and the head with sampling;
+#: inside ``ffn`` an MoE block's four stages (``layers.moe_ffn``).
 ATTENTION_SCOPE, FFN_SCOPE, LM_HEAD_SCOPE = "attention", "ffn", "lm_head"
+MOE_SCOPES = (L.MOE_ROUTE_SCOPE, L.MOE_DISPATCH_SCOPE, L.MOE_EXPERTS_SCOPE,
+              L.MOE_COMBINE_SCOPE)
 
 # ------------------------------------------------------------- shapes ------
 

@@ -1529,14 +1529,31 @@ class ServeEngine:
         shard_fb = dict(rep.get("shard_fallbacks", {}))
         if self.head_shard_fallback:
             shard_fb["lm_head"] = self.head_shard_fallback
-        return {**rep,
-                "spmm_grid_steps_per_step": self._spmm_grid_steps(),
-                "sparse_bytes_per_step": sparse,
-                "dense_bytes_per_step": dense,
-                "reduction": dense / sparse if sparse else 1.0,
-                "device_sparse_bytes_per_step": dev_sparse,
-                "device_dense_bytes_per_step": dev_dense,
-                "shard_fallbacks": shard_fb}
+        out = {**rep,
+               "spmm_grid_steps_per_step": self._spmm_grid_steps(),
+               "sparse_bytes_per_step": sparse,
+               "dense_bytes_per_step": dense,
+               "reduction": dense / sparse if sparse else 1.0,
+               "device_sparse_bytes_per_step": dev_sparse,
+               "device_dense_bytes_per_step": dev_dense,
+               "shard_fallbacks": shard_fb}
+        rows = self._expert_row_use_pct()
+        if rows is not None:
+            out["expert_row_use_pct"] = rows
+        return out
+
+    def _expert_row_use_pct(self) -> Optional[float]:
+        """Share of the rows the grouped expert products compute in one
+        decode step that carry a routed (token, expert) pair, in percent:
+        ``layers.moe_rows`` of the decode's dispatch, one token per slot
+        (the share is the same for every slot); None without experts."""
+        from repro.models.layers import moe_rows
+        cfg = self.cfg
+        if not (cfg.num_experts and any(b.ffn == "moe"
+                                         for b in cfg.pattern)):
+            return None
+        routed, computed = moe_rows(1, cfg)
+        return 100.0 * routed / computed
 
     def _spmm_grid_steps(self) -> int:
         """``bitmap_spmm`` grid steps of one decode step on the Pallas

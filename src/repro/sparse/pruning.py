@@ -11,6 +11,7 @@ from typing import Any, Callable, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def _is_prunable(path: tuple, leaf: jax.Array,
@@ -48,20 +49,26 @@ def _kth_magnitude(leaves: Tuple[jax.Array, ...], k: jax.Array) -> jax.Array:
     return jax.lax.bitcast_convert_type(lo, jnp.float32)
 
 
-def _position(q, n: int):
-    """``jnp.quantile``'s (linear) sample position: the two ranks and
-    their weights, in its float32 arithmetic."""
-    pos = q * (jnp.float32(n) - 1)
-    low, high = jnp.floor(pos), jnp.ceil(pos)
-    high_w = pos - low
-    return (jnp.clip(low, 0, n - 1), jnp.clip(high, 0, n - 1),
-            1 - high_w, high_w)
+def _position(q: float, n: int) -> Tuple[int, int]:
+    """The two ranks ``jnp.quantile`` (linear) interpolates between over
+    ``n`` values, as host ints: its float32 sample position
+    ``q * (n - 1)``, floored and ceiled, clamped to ``[0, n - 1]`` in
+    float32 as it does, so a count past 2**31 - 1 overflows nothing.
+    Where float32 rounds ``n - 1`` up to ``n``, the rank is held to
+    ``n - 1``, as ``jnp.quantile``'s gather clamps it."""
+    last = np.float32(n) - np.float32(1)
+    pos = np.float32(q) * last
+    return tuple(min(int(np.clip(r, 0, last)), n - 1)
+                 for r in (np.floor(pos), np.ceil(pos)))
 
 
 @functools.partial(jax.jit, static_argnames="n")
 def _interpolate(lo_v, hi_v, q, n: int):
-    _, _, low_w, high_w = _position(q, n)
-    return lo_v * low_w + hi_v * high_w
+    """``jnp.quantile``'s linear interpolation, in its float32
+    arithmetic."""
+    pos = q * (jnp.float32(n) - 1)
+    high_w = pos - jnp.floor(pos)
+    return lo_v * (1 - high_w) + hi_v * high_w
 
 
 def global_threshold(leaves: Sequence[jax.Array],
@@ -70,35 +77,41 @@ def global_threshold(leaves: Sequence[jax.Array],
     together, without concatenating them: the two order statistics it
     interpolates come from ``_kth_magnitude``, and the interpolation is
     one jitted expression like ``jnp.quantile``'s own, so the threshold
-    is the same float32 number."""
-    n = sum(l.size for l in leaves)
+    is the same float32 number.  The bisection counts in uint32, so the
+    leaves may hold up to 2**32 - 1 weights."""
+    n = sum(int(l.size) for l in leaves)
+    if n >= 2 ** 32:
+        raise ValueError(f"{n} prunable weights overflow the uint32 count")
     q = jnp.float32(sparsity)
-    low, high, _, _ = _position(q, n)
+    low, high = _position(sparsity, n)
     leaves = tuple(leaves)
-    lo_v = _kth_magnitude(leaves, jnp.uint32(int(low)))
-    hi_v = _kth_magnitude(leaves, jnp.uint32(int(high)))
+    lo_v = _kth_magnitude(leaves, jnp.uint32(low))
+    hi_v = _kth_magnitude(leaves, jnp.uint32(high))
     return _interpolate(lo_v, hi_v, q, n)
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _prune_leaf(leaf, thresh):
+    return jnp.where(jnp.abs(leaf) <= thresh, 0, leaf)
 
 
 def global_l1_prune(params: Any, sparsity: float,
                     predicate: Callable | None = None) -> Any:
-    """Zero the globally-smallest |w| fraction across all prunable leaves."""
+    """Zero the globally-smallest |w| fraction across all prunable leaves.
+
+    Each prunable leaf is donated to its pruned copy, so the device never
+    holds two copies of the weights: the caller's prunable leaves are
+    consumed, and only the returned tree may be used."""
     if sparsity <= 0:
         return params
-    leaves = jax.tree_util.tree_leaves_with_path(params)
-    prunable = [(p, l) for p, l in leaves if _is_prunable(p, l, predicate)]
-    if not prunable:
+    flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+    which = [_is_prunable(p, l, predicate) for p, l in flat]
+    if not any(which):
         return params
-    thresh = global_threshold([l for _, l in prunable], sparsity)
-
-    flat_paths = {jax.tree_util.keystr(p) for p, _ in prunable}
-
-    def prune_leaf(path, leaf):
-        if jax.tree_util.keystr(path) in flat_paths:
-            return jnp.where(jnp.abs(leaf) <= thresh, 0, leaf)
-        return leaf
-
-    return jax.tree_util.tree_map_with_path(prune_leaf, params)
+    thresh = global_threshold([l for (_, l), w in zip(flat, which) if w],
+                              sparsity)
+    return jax.tree_util.tree_unflatten(treedef, [
+        _prune_leaf(l, thresh) if w else l for (_, l), w in zip(flat, which)])
 
 
 def per_tensor_prune(w: jax.Array, sparsity: float) -> jax.Array:

@@ -72,3 +72,57 @@ def test_global_threshold_is_the_concatenated_quantile(sparsity, dtype):
         [jnp.abs(l).reshape(-1) for l in leaves]).astype(jnp.float32),
         sparsity)
     assert float(global_threshold(leaves, sparsity)) == float(want)
+
+
+def test_global_prune_counts_past_int32():
+    """granite-3.0-3b-a800m at its published 32 layers holds 3,223,191,552
+    projection weights (3,223,289,856 leaves the prune counts, with the
+    period-stacked norm scales), past 2**31 - 1: the quantile's ranks are
+    host ints in its float32 arithmetic, and the whole prune traces at
+    that size (shapes only, nothing allocated)."""
+    import jax
+    from repro.configs import get_config
+    from repro.models.model import param_structs
+    from repro.sparse.pruning import _is_prunable, _position, global_l1_prune
+
+    params = param_structs(get_config("granite-moe-3b-a800m"))
+    counted = [(jax.tree_util.keystr(p), int(np.prod(l.shape)))
+               for p, l in jax.tree_util.tree_leaves_with_path(params)
+               if _is_prunable(p, l, None)]
+    assert sum(s for p, s in counted if "norm" not in p) == 3_223_191_552
+    n = sum(s for _, s in counted)
+    pos = np.float32(0.75) * (np.float32(n) - np.float32(1))
+    low, high = _position(0.75, n)
+    assert (low, high) == (int(np.floor(pos)), int(np.ceil(pos)))
+    assert 2 ** 31 - 1 < low <= high < n
+    # float32 rounds n - 1 up to n at these counts: the rank stays an index
+    assert _position(1.0, 3_223_191_552) == (3_223_191_551,) * 2
+
+    out = jax.eval_shape(lambda p: global_l1_prune(p, 0.75), params)
+    assert jax.tree.map(lambda s: (s.shape, s.dtype), out) == \
+        jax.tree.map(lambda s: (s.shape, s.dtype), params)
+
+
+def test_global_prune_donates_and_zeroes_at_or_below_the_threshold():
+    """Each prunable leaf is donated to its pruned copy (the caller's leaf
+    is consumed), and the copy is ``where(|w| <= threshold, 0, w)`` with
+    the global threshold, computed here on a copy; other leaves pass
+    through untouched."""
+    import jax
+    from repro.sparse.pruning import global_l1_prune, global_threshold
+    r = np.random.default_rng(3)
+    params = {
+        "embed": jnp.asarray(r.standard_normal((16, 8)), jnp.bfloat16),
+        "a": jnp.asarray(r.standard_normal((4, 32, 16)), jnp.bfloat16),
+        "b": jnp.asarray(r.standard_normal((16, 24)), jnp.bfloat16),
+        "norm": jnp.asarray(r.standard_normal((8,)), jnp.bfloat16)}
+    copy = jax.tree.map(jnp.copy, params)
+    thresh = global_threshold([copy["a"], copy["b"]], 0.75)
+    got = global_l1_prune(params, 0.75)
+    assert params["a"].is_deleted() and params["b"].is_deleted()
+    for k in ("a", "b"):
+        want = jnp.where(jnp.abs(copy[k]) <= thresh, 0, copy[k])
+        np.testing.assert_array_equal(np.asarray(got[k]), np.asarray(want))
+    assert float(jnp.mean(got["a"] == 0)) > 0.5
+    for k in ("embed", "norm"):
+        assert got[k] is params[k]

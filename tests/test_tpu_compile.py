@@ -5,9 +5,12 @@ chip's own compiler refuses a block that breaks its tiling rules or an
 operation it cannot lower, which interpret mode never checks.  Shapes are
 olmo-1b's published projections and LM head at the decode slot count and
 the prefill chunk, and granite-moe-3b-a800m's expert stacks through the
-grouped kernel.  Each test asserts the kernel is in the compiled program
-as a ``tpu_custom_call``.
+grouped kernel, and granite-3.0-3b-a800m's whole decode step at its
+published 32 layers.  Each test asserts the kernel is in the compiled
+program as a ``tpu_custom_call``.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -103,3 +106,50 @@ def test_grouped_kernel_compiles_at_granite_expert_widths(one_chip, k, n,
     compiled = jax.jit(lambda x, w: bitmap_spmm_grouped(x, w)).lower(
         x, w).compile()
     _assert_kernel(compiled)
+
+
+def test_granite_decode_step_compiles_at_published_depth(one_chip):
+    """The whole serving decode step of granite-3.0-3b-a800m (32 layers,
+    GQA 24/8, 40 experts top-8, dense router and head) over the benchmark
+    cell's 16 slots and paged KV cache, every attention projection and
+    expert stack packed: the grouped expert kernels, the MoE dispatch and
+    the step around them compile for the chip."""
+    from repro.configs import get_config
+    from repro.launch.steps import build_serve_step
+    from repro.models.model import (MOE_SCOPES, cache_structs,
+                                    paged_layout, param_structs)
+    cfg = dataclasses.replace(get_config("granite-moe-3b-a800m"),
+                              param_dtype="bfloat16")
+    max_len, page_len = 1024, 16
+    place = lambda t: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    p, d, e = cfg.num_periods, cfg.d_model, cfg.num_experts
+    hq = cfg.num_heads * cfg.resolved_head_dim
+    hkv = cfg.num_kv_heads * cfg.resolved_head_dim
+    w = lambda k, n, lead: _weight(k, n, one_chip, lead=lead,
+                                   dtype=jnp.bfloat16)
+    packed = {"b0": {
+        "attn": {"norm": None, "wq": w(d, hq, (p,)), "wk": w(d, hkv, (p,)),
+                 "wv": w(d, hkv, (p,)), "wo": w(hq, d, (p,))},
+        "moe": {"norm": None, "router": None,
+                "w_gate": w(d, cfg.d_ff, (p, e)),
+                "w_up": w(d, cfg.d_ff, (p, e)),
+                "w_down": w(cfg.d_ff, d, (p, e))}}}
+    tables = {b: jax.ShapeDtypeStruct((CELL_SLOTS, n), jnp.int32,
+                                      sharding=one_chip)
+              for b, n in paged_layout(cfg, max_len, page_len).items()}
+    tok = jax.ShapeDtypeStruct((CELL_SLOTS, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((CELL_SLOTS,), jnp.int32, sharding=one_chip)
+    step = build_serve_step(cfg, impl="pallas")
+    compiled = jax.jit(
+        lambda params, cache, tok, pos, packed, tables: step(
+            params, cache, tok, pos, packed=packed, page_tables=tables)
+    ).lower(place(param_structs(cfg)),
+            place(cache_structs(cfg, CELL_SLOTS, max_len, page_len)),
+            tok, pos, packed, tables).compile()
+    hlo = compiled.as_text()
+    # q, k, v, o and the three grouped expert products, once in the scan
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 7
+    for scope in MOE_SCOPES:
+        assert f"/{scope}/" in hlo, scope
