@@ -168,6 +168,17 @@ def scan_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return out.astype(q.dtype)
 
 
+def cache_line_positions(pc: jax.Array, c: int, ring: bool) -> jax.Array:
+    """(B, C) position held by each of a cache's C lines once position
+    ``pc`` ((B, 1)) is written: line i itself, or in a ring the latest
+    position p <= pc with p % C == i (negative for a line still cold)."""
+    slots = jnp.arange(c)[None, :]
+    if not ring:
+        return jnp.broadcast_to(slots, (pc.shape[0], c))
+    base = pc - (pc % c)
+    return jnp.where(slots <= (pc % c), base + slots, base - c + slots)
+
+
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      pos: jax.Array, *, window: Optional[int] = None,
                      ring: bool = False) -> jax.Array:
@@ -196,15 +207,7 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     s = s.reshape(b, hq, c)
     pos = jnp.asarray(pos)
     pc = (jnp.broadcast_to(pos, (b,)) if pos.ndim == 0 else pos)[:, None]
-    slots = jnp.arange(c)[None, :]
-    if ring:
-        # slot i holds the latest position p <= pos with p % C == i;
-        # cold slots imply p < 0 and must be masked out
-        base = pc - (pc % c)
-        slot_pos = jnp.where(slots <= (pc % c), base + slots,
-                             base - c + slots)
-    else:
-        slot_pos = jnp.broadcast_to(slots, (b, c))
+    slot_pos = cache_line_positions(pc, c, ring)
     valid = (slot_pos <= pc) & (slot_pos >= 0)
     if window is not None:
         valid &= (pc - slot_pos) < window
@@ -214,35 +217,91 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      preferred_element_type=jnp.float32)
     return out.reshape(b, 1, hq, d).astype(q.dtype)
 
+
+def chunk_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
+                    k: jax.Array, v: jax.Array, pos: jax.Array, *,
+                    window: Optional[int] = None, ring: bool = False
+                    ) -> jax.Array:
+    """Causal attention of a C-token chunk in one blocked call.
+
+    q: (B, C, Hq, D); k_cache/v_cache: (B, S, Hkv, D), the cache as it
+    stood before the chunk; k/v: (B, C, Hkv, D), the chunk's own lines
+    (read in the cache dtype, as once written); pos: (B,) position of
+    each slot's first chunk token.  Query t (at ``pos + t``) attends
+
+    * cache lines holding positions in ``[0, pos - 1]`` (ring lines read
+      at ``pos - 1``, as ``cache_line_positions`` maps them) and inside
+      its window, and
+    * chunk lines j <= t with t - j < window,
+
+    the (position, K/V) pairs ``decode_attention`` sees at ``pos + t``
+    once the chunk's lines up to t are written: only the order of the
+    softmax and value sums differs.  A padding lane's output (a token
+    past its slot's chunk length) is unspecified.  Dtypes follow
+    ``decode_attention``: K/V in the cache dtype, f32 scores, softmax and
+    accumulation, and repeated KV is never materialised for GQA.
+    """
+    b, s, hkv, d = k_cache.shape
+    c, hq = q.shape[1], q.shape[2]
+    g = hq // hkv
+    scale = d ** -0.5
+    qh = q.reshape(b, c, hkv, g, d).astype(k_cache.dtype)
+    s_old = jnp.einsum("btkgd,bskd->bkgts", qh, k_cache,
+                       preferred_element_type=jnp.float32) * scale
+    s_new = jnp.einsum("btkgd,bjkd->bkgtj", qh, k.astype(k_cache.dtype),
+                       preferred_element_type=jnp.float32) * scale
+    pos = jnp.asarray(pos)
+    t = jnp.arange(c)
+    last = (pos - 1)[:, None]                                   # (B, 1)
+    line_pos = cache_line_positions(last, s, ring)              # (B, S)
+    old_ok = ((line_pos <= last) & (line_pos >= 0))[:, None, :]
+    new_ok = (t[None, :] <= t[:, None])[None]                   # (1, C, C)
+    if window is not None:
+        q_pos = (pos[:, None] + t[None, :])[:, :, None]         # (B, C, 1)
+        old_ok = old_ok & ((q_pos - line_pos[:, None, :]) < window)
+        new_ok = new_ok & ((t[:, None] - t[None, :]) < window)[None]
+    scores = jnp.concatenate(
+        [jnp.where(old_ok[:, None, None], s_old, -1e30),
+         jnp.where(new_ok[:, None, None], s_new, -1e30)], axis=-1)
+    p = jax.nn.softmax(scores, axis=-1).astype(v_cache.dtype)
+    out = (jnp.einsum("bkgts,bskd->btkgd", p[..., :s], v_cache,
+                      preferred_element_type=jnp.float32)
+           + jnp.einsum("bkgtj,bjkd->btkgd", p[..., s:],
+                        v.astype(v_cache.dtype),
+                        preferred_element_type=jnp.float32))
+    return out.reshape(b, c, hq, d).astype(q.dtype)
+
+
 def paged_kv_update(k_pool: jax.Array, v_pool: jax.Array, k: jax.Array,
                     v: jax.Array, page_table: jax.Array,
                     write_slot: jax.Array,
                     valid: Optional[jax.Array] = None
                     ) -> tuple[jax.Array, jax.Array]:
-    """Write one decode step's K/V lines through a page table.
+    """Write T K/V lines per batch row through a page table.
 
     k_pool/v_pool: (NP, L, Hkv, D) page pools (NP physical pages of L
-    tokens; page 0 is the reserved trash page).  k/v: (B, 1, Hkv, D).
+    tokens; page 0 is the reserved trash page).  k/v: (B, T, Hkv, D).
     page_table: (B, S) int32 physical page ids, 0 = unmapped.
-    write_slot: (B,) logical token slot in [0, S*L).
+    write_slot: (B, T) logical token slots in [0, S*L) ((B,) when T = 1).
 
     Rows whose logical page is unmapped (idle batch slots decoding at
     position 0) resolve to page 0 and scribble into the trash line —
     live pages are only ever written by the slot that owns them, so
     distinct rows never collide outside the trash page.
 
-    ``valid`` ((B,) bool) additionally routes masked-off rows to the
-    trash page — chunked prefill runs a fixed-width batch where slots
-    past their chunk length must not touch live pages.
+    ``valid`` (write_slot's shape, bool) additionally routes masked-off
+    lines to the trash page — chunked prefill runs a fixed-width batch
+    where lanes past their chunk length must not touch live pages.
     """
     page_len = k_pool.shape[1]
+    write_slot = write_slot.reshape(k.shape[:2])
     pi = write_slot // page_len
     off = write_slot % page_len
-    phys = jnp.take_along_axis(page_table, pi[:, None], axis=1)[:, 0]
+    phys = jnp.take_along_axis(page_table, pi, axis=1)
     if valid is not None:
-        phys = jnp.where(valid, phys, 0)
-    k_pool = k_pool.at[phys, off].set(k[:, 0].astype(k_pool.dtype))
-    v_pool = v_pool.at[phys, off].set(v[:, 0].astype(v_pool.dtype))
+        phys = jnp.where(valid.reshape(phys.shape), phys, 0)
+    k_pool = k_pool.at[phys, off].set(k.astype(k_pool.dtype))
+    v_pool = v_pool.at[phys, off].set(v.astype(v_pool.dtype))
     return k_pool, v_pool
 
 
@@ -250,28 +309,27 @@ def slot_kv_update(k_cache: jax.Array, v_cache: jax.Array, k: jax.Array,
                    v: jax.Array, write_slot: jax.Array,
                    valid: Optional[jax.Array] = None
                    ) -> tuple[jax.Array, jax.Array]:
-    """Write one K/V line per batch row into the contiguous slotted cache.
+    """Write T K/V lines per batch row into the contiguous slotted cache.
 
-    k_cache/v_cache: (B, C, Hkv, D); k/v: (B, 1, Hkv, D); write_slot: (B,)
-    cache line per row.  ``valid`` ((B,) bool) drops masked-off rows from
-    the scatter entirely (the contiguous layout has no trash line, so
-    chunked prefill's padding lanes redirect out of bounds and are
-    dropped) — decode's unconditional write passes no mask and keeps its
-    exact scatter.
+    k_cache/v_cache: (B, C, Hkv, D); k/v: (B, T, Hkv, D); write_slot:
+    (B, T) cache lines ((B,) when T = 1).  ``valid`` (write_slot's shape,
+    bool) drops masked-off lines from the scatter entirely (the
+    contiguous layout has no trash line, so chunked prefill's padding
+    lanes redirect out of bounds and are dropped) — decode's
+    unconditional write passes no mask and keeps its exact scatter.
     """
-    b = k_cache.shape[0]
-    bidx = jnp.arange(b)
+    bidx = jnp.arange(k_cache.shape[0])[:, None]
+    write_slot = write_slot.reshape(k.shape[:2])
     if valid is None:
-        k_cache = k_cache.at[bidx, write_slot].set(
-            k[:, 0].astype(k_cache.dtype))
-        v_cache = v_cache.at[bidx, write_slot].set(
-            v[:, 0].astype(v_cache.dtype))
+        k_cache = k_cache.at[bidx, write_slot].set(k.astype(k_cache.dtype))
+        v_cache = v_cache.at[bidx, write_slot].set(v.astype(v_cache.dtype))
         return k_cache, v_cache
-    slot = jnp.where(valid, write_slot, k_cache.shape[1])   # OOB when masked
-    k_cache = k_cache.at[bidx, slot].set(
-        k[:, 0].astype(k_cache.dtype), mode="drop")
-    v_cache = v_cache.at[bidx, slot].set(
-        v[:, 0].astype(v_cache.dtype), mode="drop")
+    slot = jnp.where(valid.reshape(write_slot.shape), write_slot,
+                     k_cache.shape[1])                      # OOB when masked
+    k_cache = k_cache.at[bidx, slot].set(k.astype(k_cache.dtype),
+                                         mode="drop")
+    v_cache = v_cache.at[bidx, slot].set(v.astype(v_cache.dtype),
+                                         mode="drop")
     return k_cache, v_cache
 
 

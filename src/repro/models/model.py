@@ -507,13 +507,13 @@ def _prefill_attn(p: Dict, x: jax.Array, cache: Dict, cfg: ModelConfig,
 
     The q/k/v/o projections run batched over the whole chunk (M = B·C
     rows through ``matmul_or_bitmap`` — where the compressed weight
-    stream amortizes), while the cache write + attention core scan the
-    chunk one token at a time.  Each inner step writes token t's K/V
-    line and then attends token t against the cache — exactly the state
-    the decode path would see at that position, so chunked prefill is
-    bit-identical to teacher-forcing the prompt through decode steps
-    (ring wraps, windows and paging included, with no layout-dependent
-    re-association of the softmax).
+    stream amortizes), and so does the attention core: one blocked
+    causal call over the chunk (``_prefill_attn_core``).  The contract
+    against teacher-forcing the prompt through decode steps (ring wraps,
+    windows and paging included): the cache writes are bit-identical,
+    each token's attention output attends the same (position, K/V)
+    pairs and differs only by the order of the softmax and value sums,
+    and the served tokens match.
     """
     b, c_chunk, d = x.shape
     hd = cfg.resolved_head_dim
@@ -537,50 +537,56 @@ def _prefill_attn(p: Dict, x: jax.Array, cache: Dict, cfg: ModelConfig,
     return out, new_cache
 
 
+def latest_writes(slot: jax.Array, valid: jax.Array) -> jax.Array:
+    """(B, C) bool: the valid chunk tokens that are the latest valid
+    token to write their cache line ``slot`` ((B, C)), so one scatter
+    writes each line once, whatever order it applies duplicates in."""
+    t = jnp.arange(slot.shape[1])
+    later = ((slot[:, None, :] == slot[:, :, None])
+             & (t[None, :] > t[:, None])[None] & valid[:, None, :])
+    return valid & ~later.any(axis=-1)
+
+
 def _prefill_attn_core(q, k, v, cache: Dict, cfg: ModelConfig,
                        blk: BlockCfg, pos: jax.Array, lens: jax.Array,
                        page_table: Optional[jax.Array]
                        ) -> Tuple[jax.Array, Dict]:
-    """RoPE, then the chunk's K/V cache writes and attention, one token
-    at a time (see ``_prefill_attn``)."""
+    """RoPE, then the whole chunk's attention in one blocked causal call
+    against the cache as it stood before the chunk (one gather of each
+    paged pool), then the chunk's K/V lines written in one scatter (see
+    ``_prefill_attn``).
+
+    Padding lanes (t >= lens) write to the trash page (paged) or are
+    dropped (contiguous).  Where several chunk tokens map to one cache
+    line (a chunk wider than a ring), only the latest is written and the
+    others are masked like padding, so the cache never depends on the
+    order of a scatter's duplicate targets.
+    """
     c_chunk = q.shape[1]
     posv = jnp.asarray(pos)
-    posb = posv[:, None] + jnp.arange(c_chunk)[None, :]      # (B, C)
+    t = jnp.arange(c_chunk)
+    posb = posv[:, None] + t[None, :]                        # (B, C)
     q = L.rope(q, posb, cfg.rope_theta)
     k = L.rope(k, posb, cfg.rope_theta)
     if page_table is not None:
         plen = cache["k"].shape[1]
         cap, ring = paged_addressing(page_table.shape[1], plen, blk.window)
+        k_old = L.paged_gather(cache["k"], page_table)
+        v_old = L.paged_gather(cache["v"], page_table)
     else:
         cap = cache["k"].shape[1]
         ring = blk.window is not None and cap == blk.window
-
-    def tok_step(carry, xs):
-        k_cache, v_cache = carry
-        q_t, k_t, v_t, t = xs                   # (B, H/Hkv, hd), scalar t
-        pos_t = posv + t
-        valid = t < lens
-        slot = (pos_t % cap) if ring else jnp.clip(pos_t, 0, cap - 1)
-        if page_table is not None:
-            k_cache, v_cache = L.paged_kv_update(
-                k_cache, v_cache, k_t[:, None], v_t[:, None], page_table,
-                slot, valid=valid)
-            k_att = L.paged_gather(k_cache, page_table)
-            v_att = L.paged_gather(v_cache, page_table)
-        else:
-            k_cache, v_cache = L.slot_kv_update(
-                k_cache, v_cache, k_t[:, None], v_t[:, None], slot,
-                valid=valid)
-            k_att, v_att = k_cache, v_cache
-        o = L.decode_attention(q_t[:, None], k_att, v_att, pos_t,
-                               window=blk.window, ring=ring)
-        return (k_cache, v_cache), o[:, 0]
-
-    (k_cache, v_cache), outs = jax.lax.scan(
-        tok_step, (cache["k"], cache["v"]),
-        (q.swapaxes(0, 1), k.swapaxes(0, 1), v.swapaxes(0, 1),
-         jnp.arange(c_chunk)))
-    o = outs.swapaxes(0, 1)                                  # (B, C, Hq, hd)
+        k_old, v_old = cache["k"], cache["v"]
+    o = L.chunk_attention(q, k_old, v_old, k, v, posv, window=blk.window,
+                          ring=ring)
+    slot = (posb % cap) if ring else jnp.clip(posb, 0, cap - 1)
+    live = latest_writes(slot, t[None, :] < lens[:, None])
+    if page_table is not None:
+        k_cache, v_cache = L.paged_kv_update(
+            cache["k"], cache["v"], k, v, page_table, slot, valid=live)
+    else:
+        k_cache, v_cache = L.slot_kv_update(
+            cache["k"], cache["v"], k, v, slot, valid=live)
     return o, {"k": k_cache, "v": v_cache}
 
 

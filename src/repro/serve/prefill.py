@@ -32,12 +32,14 @@ The planner is pure host-side bookkeeping — the device work is
 Invariants (equivalence-tested in tests/test_prefill.py and the
 full-matrix test in tests/test_packed_streaming.py):
 
-* **Bit-identical to teacher-forcing** — ``prefill_hidden`` writes then
-  attends one token at a time inside the chunk, so the cache state (and
-  therefore every sampled token) equals the ``prefill_chunk=0`` legacy
-  walk at every position, across windows/ring wraps, MoE (chunk folded
-  into the batch dim so capacity matches decode), contiguous and paged
-  caches, dense and packed weight streams.
+* **Matches teacher-forcing** — ``prefill_hidden`` attends the whole
+  chunk in one blocked causal call and writes its K/V lines in one
+  scatter: the cache is bit-identical to the ``prefill_chunk=0`` legacy
+  walk's, each token attends the same (position, K/V) pairs (only the
+  order of the softmax and value sums differs), and the served tokens
+  match, across windows/ring wraps, MoE (chunk folded into the batch
+  dim so capacity matches decode), contiguous and paged caches, dense
+  and packed weight streams.
 * **The last prompt token is never prefilled** — it feeds the first
   real decode step, which samples the first generated token exactly
   like the teacher-forcing path did.

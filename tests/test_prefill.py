@@ -1,6 +1,11 @@
 """Chunked batched prefill: token equivalence against the teacher-forcing
 oracle (contiguous and paged, ragged prompt lengths), planner accounting,
-typed budget rejection, TTFT decomposition, recorded fallbacks."""
+typed budget rejection, TTFT decomposition, recorded fallbacks.
+
+The contract with teacher-forcing: the cache writes are bit-identical,
+attention outputs differ only by summation order (both pinned against
+the old token-by-token walk in ``test_prefill_attention.py``), and the
+served tokens match (pinned here)."""
 import warnings
 
 import numpy as np
@@ -37,9 +42,10 @@ def _run_tokens(cfg, trace, *, sparsity=0.0, **engine_kw):
 def test_prefill_matches_teacher_forcing(arch, sparsity):
     """Chunked prefill is token-identical to the legacy teacher-forced
     prompt walk on identical ragged traces — full attention, sliding
-    windows and MoE, pruned or not, contiguous *and* paged.  The inner
-    per-token write-then-attend scan sees exactly the cache state the
-    decode path would, so equivalence is bit-level, not approximate."""
+    windows and MoE, pruned or not, contiguous *and* paged.  The blocked
+    attention call writes a bit-identical cache and attends each token
+    to the (position, K/V) pairs decode would, so only summation order
+    differs, and the served tokens are identical."""
     cfg = get_smoke_config(arch)
     trace = _ragged_trace(cfg)
     base, _ = _run_tokens(cfg, trace, sparsity=sparsity)
@@ -57,8 +63,8 @@ def test_prefill_matches_teacher_forcing(arch, sparsity):
 
 def test_chunk_wider_than_window_and_page():
     """A chunk that wraps a sliding-window ring *within one call* (and
-    spans page boundaries) still matches the oracle: the inner scan
-    overwrites ring lines in exactly decode's order."""
+    spans page boundaries) still matches the oracle: only the latest
+    chunk token per ring line is written, as decode's order leaves it."""
     cfg = get_smoke_config("gemma3-4b")        # window=8 local blocks
     trace = poisson_trace(4, rate=0.5, seed=5, vocab_size=cfg.vocab_size,
                           prompt_len=(18, 28), max_new=(3, 6))

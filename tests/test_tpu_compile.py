@@ -5,8 +5,9 @@ chip's own compiler refuses a block that breaks its tiling rules or an
 operation it cannot lower, which interpret mode never checks.  Shapes are
 olmo-1b's published projections and LM head at the decode slot count and
 the prefill chunk, and granite-moe-3b-a800m's expert stacks through the
-grouped kernel, and granite-3.0-3b-a800m's whole decode step at its
-published 32 layers.  Each test asserts the kernel is in the compiled
+grouped kernel, granite-3.0-3b-a800m's whole decode step at its
+published 32 layers, and olmo-1b's whole chunked-prefill step at the
+benchmark cell's shapes.  Each test asserts the kernel is in the compiled
 program as a ``tpu_custom_call``.
 """
 import dataclasses
@@ -153,3 +154,46 @@ def test_granite_decode_step_compiles_at_published_depth(one_chip):
     assert hlo.count('custom_call_target="tpu_custom_call"') == 7
     for scope in MOE_SCOPES:
         assert f"/{scope}/" in hlo, scope
+
+
+def test_olmo_prefill_step_compiles_at_cell_shapes(one_chip):
+    """The whole chunked-prefill step of olmo-1b (16 layers, MHA 16
+    heads) at the olmo-1b.gen cell's shapes: 16 slots of a 32-token
+    chunk over a paged KV cache (``max_len`` 1024, ``page_len`` 16), the
+    blocked causal attention call and every projection packed: the
+    prefill kernels and the attention core around them compile for the
+    chip."""
+    from repro.configs import get_config
+    from repro.launch.steps import build_prefill_step
+    from repro.models.model import (ATTENTION_SCOPE, cache_structs,
+                                    paged_layout, param_structs)
+    cfg = dataclasses.replace(get_config("olmo-1b"), param_dtype="bfloat16")
+    max_len, page_len = 1024, 16
+    place = lambda t: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    p, d, ff = cfg.num_periods, cfg.d_model, cfg.d_ff
+    w = lambda k, n: _weight(k, n, one_chip, lead=(p,), dtype=jnp.bfloat16)
+    packed = {"b0": {
+        "attn": {"norm": None, "wq": w(d, d), "wk": w(d, d), "wv": w(d, d),
+                 "wo": w(d, d)},
+        "mlp": {"norm": None, "w_gate": w(d, ff), "w_up": w(d, ff),
+                "w_down": w(ff, d)}}}
+    tables = {b: jax.ShapeDtypeStruct((CELL_SLOTS, n), jnp.int32,
+                                      sharding=one_chip)
+              for b, n in paged_layout(cfg, max_len, page_len).items()}
+    tok = jax.ShapeDtypeStruct((CELL_SLOTS, CHUNK), jnp.int32,
+                               sharding=one_chip)
+    vec = jax.ShapeDtypeStruct((CELL_SLOTS,), jnp.int32, sharding=one_chip)
+    step = build_prefill_step(cfg, impl="pallas")
+    compiled = jax.jit(
+        lambda params, cache, tok, pos, lens, packed, tables: step(
+            params, cache, tok, pos, lens, packed=packed,
+            page_tables=tables)
+    ).lower(place(param_structs(cfg)),
+            place(cache_structs(cfg, CELL_SLOTS, max_len, page_len)),
+            tok, vec, vec, packed, tables).compile()
+    hlo = compiled.as_text()
+    # q, k, v, o and the three MLP products, once in the period scan
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 7
+    assert f"/{ATTENTION_SCOPE}/" in hlo
