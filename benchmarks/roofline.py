@@ -7,16 +7,9 @@ Reads the dry-run JSONs and derives, per device:
 plus MODEL_FLOPS (6·N·D train / 2·N·D inference, N_active for MoE), the
 useful-compute ratio, the dominant term, and a one-line "what would move
 it".  Emits the markdown table EXPERIMENTS.md §Roofline embeds.
-
-With ``--serve-artifacts``, it additionally consumes serving traffic
-artifacts (``--traffic-out`` JSONs): each artifact carries per-phase
-roofline terms measured from the engine's traffic ledger, and the rows
-are merged as a ``roofline`` section into ``BENCH_serve.json`` via
-``_bench_io`` — the serving-side counterpart of the dry-run table.
 """
 from __future__ import annotations
 
-import argparse
 import glob
 import json
 import os
@@ -24,12 +17,6 @@ from typing import Dict, List
 
 from repro.configs import SHAPES
 from repro.launch.hlo_analysis import V5E, peaks, roofline
-
-try:
-    from _bench_io import bench_timer, merge_section
-except ImportError:                                    # package import
-    from benchmarks._bench_io import bench_timer, merge_section
-
 
 def model_flops_per_device(rec: Dict) -> float:
     shape = SHAPES[rec["shape"]]
@@ -110,68 +97,7 @@ def markdown_table(rows: List[Dict], mesh_filter: str = "16x16") -> str:
     return "\n".join(out)
 
 
-def serve_roofline_rows(paths: List[str]) -> List[Dict]:
-    """Per-(arch × phase) roofline rows from serving traffic artifacts.
-
-    The artifact's roofline terms are ledger-measured (bytes per decode
-    step / prefill call actually accounted during the run), so these
-    rows reflect serving reality rather than a dry-run lowering."""
-    rows = []
-    for path in paths:
-        with open(path) as f:
-            doc = json.load(f)
-        if doc.get("schema") != "repro.serve.traffic/v1":
-            raise ValueError(f"{path}: not a traffic artifact "
-                             f"(schema={doc.get('schema')!r})")
-        tr = doc["traffic"]
-        cx = tr.get("crosscheck") or {}
-        for phase, terms in tr["roofline"].items():
-            row = {
-                "arch": doc["arch"], "phase": phase,
-                "sparsity": doc.get("sparsity", 0.0),
-                "compute_s": terms["compute_s"],
-                "memory_s": terms["memory_s"],
-                "bottleneck": terms["bottleneck"],
-                "step_s": terms["step_time_overlapped_s"],
-                "weight_sparse_bytes_per_step":
-                    tr["weight"]["sparse_bytes_per_step"],
-                "pj_per_token": tr["energy"]["pj_per_token"],
-                "tops_per_watt": tr["energy"]["tops_per_watt"],
-            }
-            if phase in cx:
-                row["modeled_vs_compiled_ratio"] = cx[phase]["ratio"]
-            rows.append(row)
-    return rows
-
-
-def serve_main(paths: List[str], out: str) -> None:
-    with bench_timer("roofline") as t:
-        rows = serve_roofline_rows(paths)
-    result = {"rows": rows,
-              "phases": sorted({r["phase"] for r in rows}),
-              "archs": sorted({r["arch"] for r in rows})}
-    merge_section(out, "roofline", result, wall_s=t.wall_s)
-    for r in rows:
-        print(f"  {r['arch']:<24s} {r['phase']:<8s} "
-              f"{r['bottleneck']}-bound "
-              f"(compute {r['compute_s'] * 1e6:.2f}us / memory "
-              f"{r['memory_s'] * 1e6:.2f}us)")
-
-
-def main(argv: "List[str] | None" = None):
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--serve-artifacts", nargs="+", default=None,
-                    help="serving traffic artifacts (--traffic-out "
-                         "JSONs); merges a per-phase roofline section "
-                         "into the serve benchmark JSON instead of "
-                         "reading dry-run records")
-    ap.add_argument("--out", default="benchmarks/BENCH_serve.json",
-                    help="benchmark JSON to merge the serving roofline "
-                         "section into")
-    args = ap.parse_args(argv)
-    if args.serve_artifacts:
-        serve_main(args.serve_artifacts, args.out)
-        return
+def main():
     recs = load_records()
     if not recs:
         print("no dry-run records found — run repro.launch.dryrun first")

@@ -60,7 +60,7 @@ def main() -> None:
 
     print("\n== [5/6] Roofline from dry-run artifacts ==")
     t0 = time.time()
-    roofline.main([])
+    roofline.main()
     _csv("roofline", (time.time() - t0) * 1e6, "see table above")
 
     print("\n== [6/6] Sparse serving (paper technique on decode) ==")

@@ -18,7 +18,7 @@ PYTHONPATH=src python benchmarks/bitmap_streaming.py --smoke \
     --sparsities 0.0 0.75 --slots 2 --requests 8 --max-len 32 --repeats 2 \
     --out BENCH_serve.json
 
-echo "== spmd smoke: sharded serving on 8 fake devices (mp=4 vs mp=1 bit-identical, per-device ledger gate) =="
+echo "== spmd smoke: sharded serving on 8 fake devices (mp=4 vs mp=1 bit-identical, per-device weight stream) =="
 PYTHONPATH=src python scripts/spmd_smoke.py --arch olmo-1b --mp 4
 
 echo "== bench smoke: sharded serving cell -> BENCH_serve.json (model_parallel) =="
@@ -56,19 +56,5 @@ PYTHONPATH=src python benchmarks/overload.py --smoke \
 
 echo "== telemetry smoke: trace/events/metrics artifacts + on==off token identity =="
 PYTHONPATH=src python scripts/telemetry_smoke.py --arch olmo-1b
-
-echo "== traffic observatory: artifact + budget gate + roofline merge (two archs) =="
-mkdir -p /tmp/repro_traffic_smoke
-for arch in olmo-1b granite-moe-3b-a800m; do
-    PYTHONPATH=src python -m repro.launch.serve --arch "$arch" --smoke \
-        --sparsity 0.5 --slots 2 --requests 6 --max-len 64 \
-        --traffic-out "/tmp/repro_traffic_smoke/$arch.traffic.json"
-    PYTHONPATH=src python scripts/traffic_report.py \
-        "/tmp/repro_traffic_smoke/$arch.traffic.json" \
-        --budget scripts/traffic_budget.json
-done
-PYTHONPATH=src python benchmarks/roofline.py \
-    --serve-artifacts /tmp/repro_traffic_smoke/*.traffic.json \
-    --out BENCH_serve.json
 
 echo "CI OK"

@@ -11,8 +11,7 @@ tensor-parallel (``mp=4``, weights column/row-sharded, KV data-sharded
   temperature-sampled requests alike);
 * zero fallbacks blamed on ``model_parallel`` (those paths are gone)
   and zero per-tensor shard fallbacks on the smoke shapes;
-* the per-device weight ledger: traffic's device columns equal the
-  engine's by construction, and summed over sharded manifest entries
+* the per-device weight stream: summed over sharded manifest entries
   the per-device packed bytes are the totals floor-divided by mp.
 
 Exit status is the CI contract: non-zero on any violated guarantee.
@@ -52,17 +51,12 @@ def _run(arch: str, mp: int, sparsity: float, requests: int,
     return eng, rep, {r.rid: list(r.tokens) for r in reqs}
 
 
-def _gate_ledger(eng, rep, mp: int) -> None:
+def _gate_weight_stream(eng, rep, mp: int) -> None:
     ws = rep["weight_stream"]
-    tw = rep["traffic"]["weight"]
     assert ws["shards"] == mp, (ws["shards"], mp)
     assert ws["shard_fallbacks"] == {}, ws["shard_fallbacks"]
     for key, reason in rep["fallbacks"].items():
         assert "model_parallel" not in reason, (key, reason)
-    # ledger == engine on the device columns (single-sourced accounting)
-    for col in ("sparse_bytes_per_step", "device_sparse_bytes_per_step",
-                "device_dense_bytes_per_step"):
-        assert tw[col] == ws[col], (col, tw[col], ws[col])
     # per-device packed bytes == totals / mp, floor-div per tensor
     dev = tot = n = 0
     for e in eng.packed.manifest:
@@ -98,8 +92,8 @@ def main():
     assert dict(engN.mesh.shape) == {"data": ndev // args.mp,
                                      "model": args.mp}
     assert toks == base, "mp=%d tokens diverged from mp=1" % args.mp
-    _gate_ledger(eng1, rep1, 1)
-    _gate_ledger(engN, repN, args.mp)
+    _gate_weight_stream(eng1, rep1, 1)
+    _gate_weight_stream(engN, repN, args.mp)
 
     ws = repN["weight_stream"]
     print(f"[{args.arch}] mesh {dict(engN.mesh.shape)}: "

@@ -41,7 +41,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.sparse.format import _TILE_ND, WORD_ROWS, BitmapWeight
+from repro.sparse.format import TILE_ND, WORD_ROWS, BitmapWeight
 
 #: lane width of a TPU vector register: the tile's column width on chip
 LANES = 128
@@ -286,16 +286,11 @@ def shard_slice(w: BitmapWeight, s: int) -> BitmapWeight:
     k, n = w.shape
     shape = (k, n // shards) if mode == "col" else (k // shards, n)
 
-    def take(leaf, name):
-        if leaf is None:
-            return None
-        return jnp.take(leaf, s, axis=leaf.ndim - _TILE_ND[name] - 1)
+    def take(leaf):
+        return jnp.take(leaf, s, axis=leaf.ndim - TILE_ND - 1)
 
-    return BitmapWeight(
-        packed_bits=take(w.packed_bits, "packed_bits"),
-        values=take(w.values, "values"),
-        shape=shape, block=w.block,
-        dense_cache=take(w.dense_cache, "dense_cache"))
+    return BitmapWeight(packed_bits=take(w.packed_bits),
+                        values=take(w.values), shape=shape, block=w.block)
 
 
 def hbm_traffic_model(x_shape: Tuple[int, int], w: BitmapWeight,
@@ -304,16 +299,11 @@ def hbm_traffic_model(x_shape: Tuple[int, int], w: BitmapWeight,
 
     Activations are re-fetched once per output-column block (grid reuse
     pattern above); weights once per output-row block; outputs written once.
-    Used by the roofline adjustment in benchmarks/roofline.py.
+    Used by benchmarks/kernel_bench.py.
 
     Decode shapes (M < bm) follow the kernel's small-M path: one row
     block (mt = 1), so the whole compressed weight streams exactly once
     per step — the regime where the bitmap format pays off most.
-
-    The ``components`` sub-dict breaks the totals into the per-tensor
-    terms (activation re-fetches, output writes, sparse vs dense weight
-    streams) that the serving traffic ledger (``serve/traffic.py``)
-    attributes per role; the top-level keys keep their legacy meaning.
     """
     m, k = x_shape
     _, n = w.shape
@@ -327,12 +317,4 @@ def hbm_traffic_model(x_shape: Tuple[int, int], w: BitmapWeight,
         "sparse_bytes": x_bytes + out_bytes + w_sparse,
         "dense_bytes": x_bytes + out_bytes + w_dense,
         "weight_compression": w.compression,
-        "components": {
-            "x_bytes": x_bytes,
-            "out_bytes": out_bytes,
-            "w_sparse_bytes": w_sparse,
-            "w_dense_bytes": w_dense,
-            "col_blocks": nt,
-            "row_blocks": mt,
-        },
     }

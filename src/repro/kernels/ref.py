@@ -12,14 +12,10 @@ from repro.sparse.format import (BitmapWeight, BlockSparseWeight,
 def bitmap_spmm_ref(x: jax.Array, w: BitmapWeight) -> jax.Array:
     """Oracle for ``bitmap_spmm``; also the serve-time xla dispatch.
 
-    When the weight carries a pack-time ``dense_cache`` the EIM re-sort
-    is skipped — decompression is a pack-time cost on backends without
-    the Pallas kernel (see ``BitmapWeight``); without it the full
-    software decompression runs, which is what the kernel parity tests
-    exercise.
+    Decodes the bitmap (the EIM re-sort, ``unpack_bitmap``) inside the
+    call, so a served step reads the same packed arrays the kernel does.
     """
-    dense = (w.dense_cache if w.dense_cache is not None
-             else unpack_bitmap(w)).astype(x.dtype)
+    dense = unpack_bitmap(w).astype(x.dtype)
     return jnp.dot(x, dense, preferred_element_type=jnp.float32).astype(
         x.dtype)
 
@@ -28,10 +24,9 @@ def bitmap_spmm_grouped_ref(x: jax.Array, w: BitmapWeight) -> jax.Array:
     """Oracle for ``bitmap_spmm_grouped``; also the serve-time xla
     dispatch for group-stacked weights (MoE expert stacks, RWKV lerp
     stacks).  x: (G, M, K); W leaves lead with G.  Returns (G, M, N).
-    Like ``bitmap_spmm_ref``, a pack-time ``dense_cache`` short-circuits
-    the software EIM re-sort."""
-    dense = (w.dense_cache if w.dense_cache is not None
-             else unpack_bitmap_stacked(w)).astype(x.dtype)
+    Decodes through ``unpack_bitmap_stacked``, as ``bitmap_spmm_ref``
+    does through ``unpack_bitmap``."""
+    dense = unpack_bitmap_stacked(w).astype(x.dtype)
     return jnp.einsum("gmk,gkn->gmn", x, dense,
                       preferred_element_type=jnp.float32).astype(x.dtype)
 

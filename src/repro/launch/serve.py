@@ -107,7 +107,6 @@ def serve_trace(arch: str, smoke: bool = True, slots: int = 4,
                 trace_out: str | None = None,
                 events_out: str | None = None,
                 metrics_out: str | None = None,
-                traffic_out: str | None = None,
                 verbose: bool = True) -> dict:
     """Continuous-batching mode: seeded Poisson arrivals into the engine.
 
@@ -141,11 +140,7 @@ def serve_trace(arch: str, smoke: bool = True, slots: int = 4,
     the device's operations; Perfetto-viewable), the structured JSONL
     event log, and the metrics snapshot (`.prom` for Prometheus text,
     else JSON) — see DESIGN_SERVING.md
-    §Observability.  ``traffic_out`` writes the memory-traffic
-    attribution artifact (per-role ledger, per-phase byte counters,
-    compiled-HLO cross-check, energy/roofline projection — the input to
-    ``scripts/traffic_report.py`` and the CI budget gate).  All four
-    default off; off is bit-identical.
+    §Observability.  All three default off; off is bit-identical.
     """
     eng = ServeEngine.from_arch(arch, smoke=smoke, num_slots=slots,
                                 max_len=max_len, sparsity=sparsity,
@@ -166,8 +161,7 @@ def serve_trace(arch: str, smoke: bool = True, slots: int = 4,
                                 audit=audit, faults=faults,
                                 trace_out=trace_out,
                                 events_out=events_out,
-                                metrics_out=metrics_out,
-                                traffic_out=traffic_out)
+                                metrics_out=metrics_out)
     trace = make_trace(eng, requests, rate, seed, max_new=max_new)
     rep, shed_at_submit = run_trace(eng, trace, temperature)
     for path in eng.close():
@@ -182,28 +176,6 @@ def serve_trace(arch: str, smoke: bool = True, slots: int = 4,
               f"({ws['reduction']:.2f}x)")
         if rep["head_fallback"]:
             print(f"  head fallback: {rep['head_fallback']}")
-        tr = rep["traffic"]
-        td, tp = tr["phases"]["decode"], tr["phases"]["prefill"]
-        en = tr["energy"]
-        print(f"traffic: decode {td['weight_bytes']/1e6:.2f}MB weights + "
-              f"{(td['kv_read_bytes'] + td['kv_write_bytes'])/1e6:.2f}MB "
-              f"KV over {td['steps']} steps"
-              + (f", prefill {tp['weight_bytes']/1e6:.2f}MB weights over "
-                 f"{tp['calls']} calls" if tp["calls"] else "")
-              + f" | {en['pj_per_token']/1e6:.2f}uJ/token "
-                f"({en['tops_per_watt']:.2f} TOPS/W vs dense "
-                f"{en['tops_per_watt_dense']:.2f})")
-        if tr["crosscheck"] is not None:
-            for ph in ("decode", "prefill"):
-                if ph in tr["crosscheck"]:
-                    cx = tr["crosscheck"][ph]
-                    lo, hi = cx["tolerance"]
-                    print(f"  {ph} modeled-vs-compiled: "
-                          f"{cx['modeled']['total_bytes']/1e6:.2f}MB vs "
-                          f"{cx['compiled_bytes']/1e6:.2f}MB "
-                          f"(ratio {cx['ratio']:.2f}, band "
-                          f"[{lo:g}, {hi:g}] "
-                          f"{'ok' if cx['within_band'] else 'VIOLATED'})")
         if sparsity > 0:
             print(f"serving at {eng.weight_sparsity:.2%} weight sparsity "
                   f"(head compression {eng.head_compression:.2f}x)")
@@ -355,12 +327,6 @@ def main():
     ap.add_argument("--metrics-out", default=None,
                     help="write a metrics snapshot at exit: Prometheus "
                          "text if the path ends in .prom, else JSON")
-    ap.add_argument("--traffic-out", default=None,
-                    help="write the memory-traffic attribution artifact "
-                         "at exit (per-role HBM ledger, per-phase byte "
-                         "counters, compiled-HLO cross-check, energy + "
-                         "roofline projection); feed to "
-                         "scripts/traffic_report.py")
     ap.add_argument("--model-parallel", type=int, default=1)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
@@ -383,7 +349,6 @@ def main():
                 audit=args.audit or faults is not None, faults=faults,
                 trace_out=args.trace_out, events_out=args.events_out,
                 metrics_out=args.metrics_out,
-                traffic_out=args.traffic_out,
                 seed=args.seed, model_parallel=args.model_parallel)
 
 

@@ -25,7 +25,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.models.config import ModelConfig
 from repro.models.model import cache_structs, param_structs
-from repro.sparse.format import _TILE_ND, BitmapWeight
+from repro.sparse.format import TILE_ND, BitmapWeight
 
 # (regex over path, spec over the *unstacked* leaf dims)
 _RULES = [
@@ -255,20 +255,15 @@ def bitmap_specs(bw: Optional[BitmapWeight], mesh) -> Any:
         return None
     live = bitmap_sharded(bw, mesh)
 
-    def spec(leaf, tile_nd):
-        if leaf is None:
-            return None
+    def spec(leaf):
         if not live:
             return P()
         axes: list = [None] * leaf.ndim
-        axes[leaf.ndim - tile_nd - 1] = "model"
+        axes[leaf.ndim - TILE_ND - 1] = "model"
         return P(*axes)
 
-    return dataclasses.replace(
-        bw,
-        packed_bits=spec(bw.packed_bits, _TILE_ND["packed_bits"]),
-        values=spec(bw.values, _TILE_ND["values"]),
-        dense_cache=spec(bw.dense_cache, _TILE_ND["dense_cache"]))
+    return dataclasses.replace(bw, packed_bits=spec(bw.packed_bits),
+                               values=spec(bw.values))
 
 
 def packed_specs(tree: Any, mesh) -> Any:

@@ -18,7 +18,6 @@ from repro.serve.telemetry import (Clock, Counter, EventLog, Gauge,
                                    Histogram, MetricsRegistry, StepSpans,
                                    Telemetry, validate_events)
 from repro.serve.trace import RollingStat, percentiles, poisson_trace
-from repro.serve.traffic import TrafficLedger, role_of
 
 __all__ = [
     "AuditViolation", "Clock", "Counter",
@@ -28,7 +27,7 @@ __all__ = [
     "PrefillPlanner", "PrefixBlock", "Request", "RequestRejected",
     "RequestState", "RollingStat", "ServeEngine", "ServeError",
     "ServeOverloaded", "SlotKVCache", "SlotScheduler", "StepSpans",
-    "TERMINAL_STATES", "Telemetry", "TrafficLedger", "choose_block",
+    "TERMINAL_STATES", "Telemetry", "choose_block",
     "pack_lm_head", "pack_model", "percentiles", "poisson_trace",
-    "role_of", "validate_events",
+    "validate_events",
 ]

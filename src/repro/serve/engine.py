@@ -56,7 +56,6 @@ the affected step deterministically instead of serving garbage.
 from __future__ import annotations
 
 import math
-import os
 import contextlib
 import time
 import warnings
@@ -86,7 +85,6 @@ from repro.serve.request import Request, RequestState
 from repro.serve.scheduler import SlotScheduler
 from repro.serve.telemetry import (SPAN_PREFIX, STEP_SPAN, Clock,
                                    MetricsRegistry, Telemetry)
-from repro.serve.traffic import TrafficLedger
 from repro.sparse.format import BitmapWeight, pack_bitmap, shard_bitmap
 from repro.sparse.pruning import global_l1_prune, per_tensor_prune, \
     sparsity_of
@@ -101,8 +99,8 @@ def _head_block(d_model: int, vocab: int, chip: bool = False,
 
 
 def pack_lm_head(params, cfg: ModelConfig, sparsity: float = 0.0,
-                 cache_dense: bool = False, shards: int = 1,
-                 chip: bool = False) -> Optional[BitmapWeight]:
+                 shards: int = 1, chip: bool = False
+                 ) -> Optional[BitmapWeight]:
     """Prune (per-tensor) + pack the (D, V) LM head once for serving.
 
     ``shards > 1`` asks for the vocab-split (column-parallel) sharded
@@ -121,13 +119,25 @@ def pack_lm_head(params, cfg: ModelConfig, sparsity: float = 0.0,
     if shards > 1 and cfg.vocab_size % shards == 0:
         sblock = _head_block(cfg.d_model, cfg.vocab_size // shards, chip)
         if sblock is not None:
-            bw = pack_bitmap(wf, block=sblock, cache_dense=cache_dense)
+            bw = pack_bitmap(wf, block=sblock)
             return shard_bitmap(bw, shards, "col")
-    return pack_bitmap(wf, block=block, cache_dense=cache_dense)
+    return pack_bitmap(wf, block=block)
 
 
 class ServeEngine:
-    """Continuous-batching decode over ``num_slots`` batch slots."""
+    """Continuous-batching decode over ``num_slots`` batch slots.
+
+    Its 28 keyword options, each documented in ``__init__``: shape and
+    placement (``num_slots``, ``max_len``, ``seed``, ``model_parallel``,
+    ``devices``, ``impl``), the packed weights (``sparsity``,
+    ``stream_weights``, ``bitmap_head``, ``head_sparsity``), sampling
+    (``top_k``), the KV cache (``paged``, ``page_len``,
+    ``page_pool_tokens``, ``kv_shards``, ``prefix_reuse``), prefill
+    (``prefill_chunk``), admission and lifecycle (``preempt``,
+    ``max_preempts``, ``history``, ``deadline_ms``, ``max_queue``,
+    ``ttft_budget_ms``), fault handling (``audit``, ``faults``) and
+    telemetry (``trace_out``, ``events_out``, ``metrics_out``).
+    """
 
     def __init__(self, cfg: ModelConfig, *, num_slots: int = 4,
                  max_len: int = 128, sparsity: float = 0.0, seed: int = 0,
@@ -148,8 +158,7 @@ class ServeEngine:
                  faults: Optional[FaultPlan] = None,
                  trace_out: Optional[str] = None,
                  events_out: Optional[str] = None,
-                 metrics_out: Optional[str] = None,
-                 traffic_out: Optional[str] = None):
+                 metrics_out: Optional[str] = None):
         """``devices``: the devices the engine's (data, model) mesh spans
         (default: every visible device — more than ``model_parallel``
         of them adds a data axis and routes the steps through
@@ -305,13 +314,10 @@ class ServeEngine:
 
         # pack once, cache on the engine: every decode step streams the
         # stack + head through the bitmap-compressed kernels/ops path.
-        # On the xla (non-TPU) dispatch the pack also renders the dense
-        # oracle view, so serving pays no per-step software decompression;
-        # on the compiled TPU kernel a tile the chip refuses serves dense
+        # On the compiled TPU kernel a tile the chip refuses serves dense
         # with a typed reason.
         from repro.kernels.ops import default_impl
         impl = impl or default_impl()
-        cache_dense = impl == "xla"
         chip = impl == "pallas"
         self.stream_fallback: Optional[str] = None
         mp_actual = int(self.mesh.shape.get("model", 1))
@@ -327,8 +333,8 @@ class ServeEngine:
             self.stream_fallback = "stream_weights=False"
             self.fallbacks["stream"] = self.stream_fallback
         self.packed: Optional[PackedModel] = (
-            pack_model(self.params, cache_dense=cache_dense,
-                       shards=(mp_actual if self._spmd else 1), chip=chip)
+            pack_model(self.params, shards=(mp_actual if self._spmd else 1),
+                       chip=chip)
             if stream_weights else None)
         if self._spmd and self.packed is not None:
             # place each sharded leaf's shard axis on its own model-axis
@@ -345,7 +351,6 @@ class ServeEngine:
         if bitmap_head:
             self.lm_weight = pack_lm_head(
                 self.params, cfg, self.head_sparsity,
-                cache_dense=cache_dense,
                 shards=(mp_actual if self._spmd else 1), chip=chip)
             if (self._spmd and mp_actual > 1
                     and self.lm_weight is not None
@@ -612,20 +617,11 @@ class ServeEngine:
         self.auditor: Optional[InvariantAuditor] = (
             InvariantAuditor(self) if audit else None)
 
-        # ---- traffic observatory: the ledger is always on (host-int
-        # counters in the registry, like every other subsystem);
-        # ``traffic_out`` additionally writes the attribution +
-        # compiled-HLO cross-check artifact at close() ----
-        self.traffic_out = traffic_out
-        self._traffic_written = False
-        self.traffic = TrafficLedger(self)
-
         # ---- telemetry: every subsystem registers into the one
         # registry; phase histograms and events only exist when an
         # output is asked for ----
         self.scheduler.register_metrics(m)
         self.kv.register_metrics(m)
-        self.traffic.register_metrics(m)
         if self.planner is not None:
             self.planner.register_metrics(m)
         if self.packed is not None:
@@ -674,20 +670,11 @@ class ServeEngine:
 
     def close(self) -> List[str]:
         """Write the configured telemetry artifacts (``--trace-out`` /
-        ``--events-out`` / ``--metrics-out`` / ``--traffic-out``);
-        idempotent, returns the paths written.  An artifacts-off engine
-        returns []."""
-        written: List[str] = []
-        if self.traffic_out and not self._traffic_written:
-            self._traffic_written = True
-            d = os.path.dirname(self.traffic_out)
-            if d:
-                os.makedirs(d, exist_ok=True)
-            self.traffic.write(self.traffic_out)
-            written.append(self.traffic_out)
-        if self.telemetry is not None:
-            written.extend(self.telemetry.close())
-        return written
+        ``--events-out`` / ``--metrics-out``); idempotent, returns the
+        paths written.  An artifacts-off engine returns []."""
+        if self.telemetry is None:
+            return []
+        return self.telemetry.close()
 
     @contextlib.contextmanager
     def _phase(self, name: str):
@@ -739,7 +726,6 @@ class ServeEngine:
         m.view("head_compression", lambda: self.head_compression)
         m.view("head_fallback", lambda: self.head_fallback)
         m.view("weight_stream", self.weight_stream_report)
-        m.view("traffic", self.traffic.report)
         m.view("paging", self.paging_report)
         m.view("cache_resets", lambda: self.kv.resets)
         m.view("lifecycle", self.lifecycle_report)
@@ -1053,16 +1039,14 @@ class ServeEngine:
             self.quarantined[path] = reason
             self.auditor.drop(path)
             self._emit("quarantine", tensor=path, reason=reason)
-        # a quarantine flips manifest entries to dense — the traffic
-        # ledger's cached role rows are stale now
-        self.traffic.invalidate()
         if self.page_len:
             self.kv.flush_prefix()
         for slot in list(self.scheduler.active):
             self._preempt_slot(slot)
         return True
 
-    def _decode(self, tok: jnp.ndarray, pos: jnp.ndarray):
+    def _decode_args(self, tok: jnp.ndarray, pos: jnp.ndarray):
+        """The ``(args, kwargs)`` of one call of the jitted decode step."""
         packed = self.packed.blocks if self.packed is not None else None
         kw = dict(lm_weight=self.lm_weight, packed=packed)
         if self.page_len:
@@ -1075,21 +1059,47 @@ class ServeEngine:
         if self.cfg.frontend == "frames":
             # device-side frame embeddings: fold the step counter into a
             # carried key — no host RNG (and no host sync) in the hot loop
-            ekey = jax.random.fold_in(self._embed_key, self._steps)
-            return self._jit_step(self.params, self.kv.cache, None, pos,
-                                  embed_rng=ekey, **kw)
-        return self._jit_step(self.params, self.kv.cache, tok, pos, **kw)
+            kw["embed_rng"] = jax.random.fold_in(self._embed_key,
+                                                 self._steps)
+            tok = None
+        return (self.params, self.kv.cache, tok, pos), kw
 
-    def _prefill(self, tokens: np.ndarray, pos: np.ndarray,
-                 lens: np.ndarray):
-        """One jitted chunked-prefill call over the fixed (B, C) batch."""
+    def _decode(self, tok: jnp.ndarray, pos: jnp.ndarray):
+        args, kw = self._decode_args(tok, pos)
+        return self._jit_step(*args, **kw)
+
+    def _prefill_args(self, tokens: np.ndarray, pos: np.ndarray,
+                      lens: np.ndarray):
+        """The ``(args, kwargs)`` of one call of the jitted prefill step."""
         packed = self.packed.blocks if self.packed is not None else None
         kw = dict(packed=packed)
         if self.page_len:
             kw["page_tables"] = self.kv.tables()
-        return self._jit_prefill(self.params, self.kv.cache,
-                                 jnp.asarray(tokens), jnp.asarray(pos),
-                                 jnp.asarray(lens), **kw)
+        return (self.params, self.kv.cache, jnp.asarray(tokens),
+                jnp.asarray(pos), jnp.asarray(lens)), kw
+
+    def _prefill(self, tokens: np.ndarray, pos: np.ndarray,
+                 lens: np.ndarray):
+        """One jitted chunked-prefill call over the fixed (B, C) batch."""
+        args, kw = self._prefill_args(tokens, pos, lens)
+        return self._jit_prefill(*args, **kw)
+
+    def lower(self, phase: str) -> jax.stages.Lowered:
+        """Lower the engine's jitted ``"decode"`` or ``"prefill"`` step
+        with the arguments the next call would take (the current slot
+        tokens and positions; an all-masked chunk for prefill).
+        Lowering runs nothing, so the live cache is untouched."""
+        if phase == "decode":
+            args, kw = self._decode_args(jnp.asarray(self._tok[:, None]),
+                                         jnp.asarray(self._pos))
+            return self._jit_step.lower(*args, **kw)
+        if phase == "prefill" and self._jit_prefill is not None:
+            z = np.zeros(self.num_slots, np.int32)
+            args, kw = self._prefill_args(
+                np.zeros((self.num_slots, self.prefill_chunk), np.int32),
+                z, z)
+            return self._jit_prefill.lower(*args, **kw)
+        raise ValueError(f"no {phase!r} step to lower")
 
     def _prefill_call(self) -> None:
         """Run the planner's next batched chunk call and route results.
@@ -1125,7 +1135,6 @@ class ServeEngine:
                         int(slot))
             hidden, cache = self._prefill(tokens, pos, lens)
             self.kv.cache = cache
-            self.traffic.on_prefill(pos, lens)
             if self.prefix_reuse:
                 # publish each advanced slot's fully-written blocks *now*
                 # — before any later chunk can ring-wrap over them
@@ -1273,8 +1282,6 @@ class ServeEngine:
                     decoding = [s for s in self.scheduler.active
                                 if not in_prefill(s)]
             with self._phase("decode"):
-                self.traffic.on_decode(
-                    [int(self._pos[s]) for s in decoding])
                 nxt, logits, cache = self._decode(
                     jnp.asarray(self._tok[:, None]), jnp.asarray(self._pos))
                 self.kv.cache = cache
@@ -1481,7 +1488,7 @@ class ServeEngine:
         with ``num_slots`` slots each routing to ``top_k`` experts, a
         decode step touches at most ``min(E, num_slots × top_k)`` experts
         — not once per stored expert (accounting rule in
-        DESIGN_PACKED.md §traffic model).
+        DESIGN_PACKED.md §6).
         """
         head_dense = (self.cfg.d_model * self.cfg.vocab_size
                       * np.dtype(np.float32).itemsize)

@@ -18,12 +18,11 @@ recovery path in ``serve/`` gets exercised on demand, deterministically:
     drives requeue/replay regardless of pool pressure;
   - ``evict_storm``: flush the entire shared-prefix cache — drives
     cold re-registration and COW bookkeeping after mass eviction;
-  - ``nan_logits``: poison the packed LM head's value payload (and its
-    ``dense_cache`` — the xla oracle path reads it) with NaN — drives
-    the sampler-corruption detection path;
+  - ``nan_logits``: poison the packed LM head's value payload with
+    NaN — drives the sampler-corruption detection path;
   - ``bitflip``: flip one bit in a seeded packed tensor's value or
-    bitmap array (mirrored into ``dense_cache``) — drives per-tensor
-    integrity detection and dense quarantine.
+    bitmap array — drives per-tensor integrity detection and dense
+    quarantine.
 
   Faults mutate *weights and allocator state only* — never the request
   queue — so with ``audit=True`` every fault is recoverable and the
@@ -193,10 +192,7 @@ class FaultPlan:
             return self._skip(step, f.kind,
                               "no packed LM head to poison")
         engine.lm_weight = dataclasses.replace(
-            bw,
-            values=jnp.full_like(bw.values, jnp.nan),
-            dense_cache=(jnp.full_like(bw.dense_cache, jnp.nan)
-                         if bw.dense_cache is not None else None))
+            bw, values=jnp.full_like(bw.values, jnp.nan))
         self.log.append({"step": step, "kind": f.kind, "fired": True,
                          "tensor": "lm_head"})
 
@@ -214,23 +210,13 @@ class FaultPlan:
             path, bw = hit[0]
         else:
             path, bw = leaves[int(self._rng.integers(len(leaves)))]
-        arr = bw.values if f.field == "values" else bw.packed_bits
-        host = np.array(arr)
+        field = "values" if f.field == "values" else "packed_bits"
+        host = np.array(getattr(bw, field))
         flat = host.view(np.uint8).reshape(-1)
         bit = int(self._rng.integers(flat.size * 8))
         flat[bit // 8] ^= np.uint8(1 << (bit % 8))
-        fields = {f.field if f.field == "values" else "packed_bits":
-                  jnp.asarray(host)}
-        if bw.dense_cache is not None:
-            # the xla oracle dispatches dense_cache, so mirror some
-            # corruption there too — which tensor is corrupt is what
-            # matters (detection is via the canonical packed arrays)
-            dc = np.array(bw.dense_cache)
-            dcf = dc.view(np.uint8).reshape(-1)
-            dcf[bit // 8 % dcf.size] ^= np.uint8(1 << (bit % 8))
-            fields["dense_cache"] = jnp.asarray(dc)
-        engine.packed.replace_leaf(path,
-                                   dataclasses.replace(bw, **fields))
+        engine.packed.replace_leaf(
+            path, dataclasses.replace(bw, **{field: jnp.asarray(host)}))
         self.log.append({"step": step, "kind": f.kind, "fired": True,
                          "tensor": path, "field": f.field, "bit": bit})
 
@@ -256,8 +242,7 @@ class FaultPlan:
 
 
 def _checksum(bw) -> int:
-    """CRC32 over a BitmapWeight's canonical arrays (bits + values);
-    ``dense_cache`` is a derived rendering and excluded."""
+    """CRC32 over a BitmapWeight's arrays (bits + values)."""
     crc = 0
     for arr in (bw.packed_bits, bw.values):
         crc = zlib.crc32(np.asarray(arr).tobytes(), crc)

@@ -34,19 +34,15 @@ Invariants (DESIGN_PACKED.md has the full subsystem doc):
 * **Every fallback carries a reason** — a tensor that cannot pack is
   served dense with the reason recorded in the manifest (no valid tile,
   unexpected rank, not a GEMM operand, …); nothing silently degrades.
-* **Modeled bytes are the compressed stream the kernel actually
-  fetches** — a pack-time ``dense_cache`` (the xla-oracle rendering)
-  never counts toward ``hbm_bytes``.
+* **A packed tensor has one form** — ``packed_bits`` and ``values``,
+  which every dispatch reads and ``hbm_bytes`` counts.
 * **Router-gated expert stacks account per *activated* expert** — a
   gather-dispatch serving engine streams only the experts the router
   selected, so ``stream_report(activated_experts=...)`` scales those
   entries by ``min(E, activated) / E`` (the engine passes
   ``num_slots × top_k``, the per-step worst case) whether the stack
   packed or fell back; always-active group stacks (rwkv ``mix_B``) and
-  everything else count in full.  Note the repo's capacity-dispatch
-  reference *executes* all stored experts (like the xla oracle, it
-  models the accelerator's dataflow rather than reproducing it) —
-  DESIGN_PACKED.md §6 spells out modeled vs executed.
+  everything else count in full.
 
 This is the paper's regime end-to-end: EIE runs *every* FC layer from
 compressed storage; here the entire decode stack — MoE expert stacks
@@ -257,9 +253,7 @@ def entry_device_bytes(e: PackEntry, attr: str,
                        activated: Optional[int]) -> int:
     """Per-device per-step bytes for one manifest row: the exact
     aggregate accounting (``int(round(bytes × activated_scale))``)
-    divided by the tensor's shard count — single-sourced so the traffic
-    ledger's per-device rows sum to the engine's device aggregates by
-    construction."""
+    divided by the tensor's shard count."""
     b = int(round(getattr(e, attr) * activated_scale(e.experts, activated)))
     return b // e.shard[1] if e.shard is not None else b
 
@@ -305,7 +299,7 @@ def _local_dims(k: int, n: int, shard: Optional[Tuple[str, int]]
 
 
 def _pack_leaf(path: str, comp: str, name: str, w, cap: int,
-               cache_dense: bool, shards: int = 1, chip: bool = False
+               shards: int = 1, chip: bool = False
                ) -> Tuple[PackEntry, Optional[BitmapWeight]]:
     arr = np.asarray(w)
     dense_bytes = arr.size * arr.dtype.itemsize
@@ -332,7 +326,7 @@ def _pack_leaf(path: str, comp: str, name: str, w, cap: int,
         reason = block_reason(*_local_dims(k, n, shard), block, chip)
         if reason:
             return fallback(reason)
-        bw = pack_bitmap_experts(arr, block=block, cache_dense=cache_dense)
+        bw = pack_bitmap_experts(arr, block=block)
         if shard is not None:
             bw = shard_bitmap(bw, shard[1], shard[0])
         entry = PackEntry(path=path, shape=arr.shape, packed=True, reason="",
@@ -352,7 +346,7 @@ def _pack_leaf(path: str, comp: str, name: str, w, cap: int,
     reason = block_reason(*_local_dims(k, n, shard), block, chip)
     if reason:
         return fallback(reason)
-    bw = pack_bitmap_stacked(arr, block=block, cache_dense=cache_dense)
+    bw = pack_bitmap_stacked(arr, block=block)
     if shard is not None:
         bw = shard_bitmap(bw, shard[1], shard[0])
     entry = PackEntry(path=path, shape=arr.shape, packed=True, reason="",
@@ -363,8 +357,7 @@ def _pack_leaf(path: str, comp: str, name: str, w, cap: int,
     return entry, bw
 
 
-def pack_model(params: Dict, cap: int = 128,
-               cache_dense: bool = False, shards: int = 1,
+def pack_model(params: Dict, cap: int = 128, shards: int = 1,
                chip: bool = False) -> PackedModel:
     """Pack every dispatchable serve-time GEMM operand of ``params``.
 
@@ -372,11 +365,6 @@ def pack_model(params: Dict, cap: int = 128,
     the packed stream is numerically identical to dense dispatch — the
     compression comes from whatever pruning already happened upstream
     (``global_l1_prune`` in the engine).
-
-    ``cache_dense`` attaches a pack-time dense rendering per tensor for
-    the xla oracle dispatch (decompression is a pack-time cost off-TPU;
-    it never counts toward the modeled HBM bytes) — the engine enables
-    it when the resolved kernel impl is "xla".
 
     ``chip`` (the engine sets it when the compiled TPU kernel serves)
     sends every tensor whose tile the chip's tiling rules refuse
@@ -396,8 +384,8 @@ def pack_model(params: Dict, cap: int = 128,
             packed_c: Dict = {}
             for name, w in tensors.items():
                 path = f"blocks/{bname}/{comp}/{name}"
-                entry, bw = _pack_leaf(path, comp, name, w, cap, cache_dense,
-                                       shards, chip)
+                entry, bw = _pack_leaf(path, comp, name, w, cap, shards,
+                                       chip)
                 manifest.append(entry)
                 packed_c[name] = bw
             packed_b[comp] = packed_c

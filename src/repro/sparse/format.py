@@ -39,21 +39,15 @@ WORD_ROWS = 32
 class BitmapWeight:
     """Bitmap-compressed (K, N) weight, tiled (BK, BN).
 
-    ``dense_cache`` is an optional pack-time dense rendering consumed
-    only by the *xla reference dispatch* (``ref.bitmap_spmm_ref``): on
-    backends without the Pallas kernel the EIM decompression is a
-    pack-time cost, not a per-step software re-sort — the hardware
-    analogue decompresses in the accelerator datapath, so re-running it
-    per decode step on CPU would model nothing and cost real wall time.
-    It is deliberately **excluded from ``hbm_bytes``**: the traffic model
-    describes the compressed stream the Pallas kernel actually fetches.
+    ``packed_bits`` and ``values`` are the weight's only form: the Pallas
+    kernel streams them, and the xla dispatch (``ref.bitmap_spmm_ref``)
+    decodes them through ``unpack_bitmap`` inside the step.
     """
 
     packed_bits: jax.Array   # (KT, NT, ceil(BK/32), BN) int32 bit words
     values: jax.Array        # (KT, NT, Q, BN) dtype, row-major packed
     shape: Tuple[int, int] = dataclasses.field(metadata=dict(static=True))
     block: Tuple[int, int] = dataclasses.field(metadata=dict(static=True))
-    dense_cache: jax.Array | None = None    # (K, N) oracle-path rendering
     #: sharded layout marker: ``("col"|"row", S)`` when every array leaf
     #: carries an explicit shard axis (extent S) immediately before its
     #: tile dims — ``shard_bitmap`` below.  ``shape``/``block`` stay the
@@ -106,8 +100,7 @@ def _pack_words(bits: np.ndarray) -> np.ndarray:
 
 def pack_bitmap(w, block: Tuple[int, int] = (128, 128),
                 density_budget: float | None = None,
-                budget: int | None = None,
-                cache_dense: bool = False) -> BitmapWeight:
+                budget: int | None = None) -> BitmapWeight:
     """Pack a dense (K, N) array (zeros = pruned) into BitmapWeight.
 
     If a tile holds more non-zeros than ``budget = ceil(BK·BN·density_budget)``
@@ -158,12 +151,10 @@ def pack_bitmap(w, block: Tuple[int, int] = (128, 128),
     values = np.zeros((kt * nt, q * bn), w.dtype)
     values[tile_of, slot] = nz
 
-    dense = (jnp.asarray(tiles.transpose(0, 2, 1, 3).reshape(k, n))
-             if cache_dense else None)
     return BitmapWeight(
         packed_bits=jnp.asarray(_pack_words(bits)),
         values=jnp.asarray(values.reshape(kt, nt, q, bn)),
-        shape=(k, n), block=(bk, bn), dense_cache=dense)
+        shape=(k, n), block=(bk, bn))
 
 
 def unpack_bitmap(bw: BitmapWeight) -> jax.Array:
@@ -181,8 +172,7 @@ def unpack_bitmap(bw: BitmapWeight) -> jax.Array:
     return dense_tiles.transpose(0, 2, 1, 3).reshape(bw.shape)
 
 
-def pack_bitmap_stacked(w, block: Tuple[int, int],
-                        cache_dense: bool = False) -> BitmapWeight:
+def pack_bitmap_stacked(w, block: Tuple[int, int]) -> BitmapWeight:
     """Pack a period-stacked (P, K, N) tensor into one ``BitmapWeight``
     whose array leaves carry a leading P axis.
 
@@ -201,14 +191,11 @@ def pack_bitmap_stacked(w, block: Tuple[int, int],
     tile_nnz = (w.reshape(p, kt, bk, nt, bn) != 0).transpose(
         0, 1, 3, 2, 4).reshape(p, kt, nt, -1).sum(-1)
     budget = max(1, int(tile_nnz.max()))
-    per = [pack_bitmap(w[i], block=block, budget=budget,
-                       cache_dense=cache_dense) for i in range(p)]
+    per = [pack_bitmap(w[i], block=block, budget=budget) for i in range(p)]
     return BitmapWeight(
         packed_bits=jnp.stack([q.packed_bits for q in per]),
         values=jnp.stack([q.values for q in per]),
-        shape=(k, n), block=block,
-        dense_cache=(jnp.stack([q.dense_cache for q in per])
-                     if cache_dense else None))
+        shape=(k, n), block=block)
 
 
 def unpack_bitmap_stacked(bw: BitmapWeight) -> jax.Array:
@@ -224,8 +211,7 @@ def unpack_bitmap_stacked(bw: BitmapWeight) -> jax.Array:
         for i in range(bw.packed_bits.shape[0])])
 
 
-def pack_bitmap_experts(w, block: Tuple[int, int],
-                        cache_dense: bool = False) -> BitmapWeight:
+def pack_bitmap_experts(w, block: Tuple[int, int]) -> BitmapWeight:
     """Pack a period-stacked expert stack (P, E, K, N) into one
     ``BitmapWeight`` whose array leaves carry leading (P, E) axes.
 
@@ -244,15 +230,12 @@ def pack_bitmap_experts(w, block: Tuple[int, int],
     w = np.asarray(w)
     assert w.ndim == 4, w.shape
     p, e, k, n = w.shape
-    flat = pack_bitmap_stacked(w.reshape(p * e, k, n), block=block,
-                               cache_dense=cache_dense)
+    flat = pack_bitmap_stacked(w.reshape(p * e, k, n), block=block)
     return BitmapWeight(
         packed_bits=flat.packed_bits.reshape(
             (p, e) + flat.packed_bits.shape[1:]),
         values=flat.values.reshape((p, e) + flat.values.shape[1:]),
-        shape=(k, n), block=block,
-        dense_cache=(flat.dense_cache.reshape(p, e, k, n)
-                     if cache_dense else None))
+        shape=(k, n), block=block)
 
 
 def unpack_bitmap_experts(bw: BitmapWeight) -> jax.Array:
@@ -268,38 +251,34 @@ def unpack_bitmap_experts(bw: BitmapWeight) -> jax.Array:
 # ('model' at that position) makes every shard's bitmap+values
 # device-local.  ``shape``/``block`` keep the full logical geometry.
 
-#: trailing per-tile dims of each array leaf (the shard axis sits just
-#: before these; leading stack axes — period P, expert E — come first)
-_TILE_ND = {"packed_bits": 4, "values": 4, "dense_cache": 2}
+#: trailing per-tile dims of each array leaf, (KT, NT, ·, BN): the shard
+#: axis sits just before these; leading stack axes (period P, expert E)
+#: come first
+TILE_ND = 4
 
-#: offset from ndim of the tile axis being split: col splits the NT axis
-#: (second tile dim — or N itself for dense_cache), row splits KT/K
-_SHARD_OFF = {"col": lambda tile_nd: tile_nd - 1, "row": lambda tile_nd: tile_nd}
+#: offset from ndim of the tile axis being split: col splits NT, row KT
+_SHARD_OFF = {"col": TILE_ND - 1, "row": TILE_ND}
 
 
-def _split_leaf(leaf, tile_nd: int, mode: str, shards: int):
+def _split_leaf(leaf, mode: str, shards: int):
     """Split the sharded tile axis into ``shards`` contiguous ranges and
     move the new shard axis to just before the tile dims."""
-    if leaf is None:
-        return None
     nd = leaf.ndim
-    ax = nd - _SHARD_OFF[mode](tile_nd)
+    ax = nd - _SHARD_OFF[mode]
     size = leaf.shape[ax]
     assert size % shards == 0, (leaf.shape, ax, shards)
     r = leaf.reshape(leaf.shape[:ax] + (shards, size // shards)
                      + leaf.shape[ax + 1:])
-    return jnp.moveaxis(r, ax, nd - tile_nd)
+    return jnp.moveaxis(r, ax, nd - TILE_ND)
 
 
-def _merge_leaf(leaf, tile_nd: int, mode: str):
+def _merge_leaf(leaf, mode: str):
     """Inverse of ``_split_leaf``: fold the shard axis back into the tile
     axis it was split from (shard ranges are contiguous, so this is a
     pure reshape after the moveaxis)."""
-    if leaf is None:
-        return None
     n = leaf.ndim
-    j = n - _SHARD_OFF[mode](tile_nd) - 1
-    m = jnp.moveaxis(leaf, n - tile_nd - 1, j)
+    j = n - _SHARD_OFF[mode] - 1
+    m = jnp.moveaxis(leaf, n - TILE_ND - 1, j)
     return m.reshape(m.shape[:j] + (m.shape[j] * m.shape[j + 1],)
                      + m.shape[j + 2:])
 
@@ -319,9 +298,8 @@ def shard_bitmap(bw: BitmapWeight, shards: int, mode: str) -> BitmapWeight:
         return bw
     return dataclasses.replace(
         bw,
-        packed_bits=_split_leaf(bw.packed_bits, 4, mode, shards),
-        values=_split_leaf(bw.values, 4, mode, shards),
-        dense_cache=_split_leaf(bw.dense_cache, 2, mode, shards),
+        packed_bits=_split_leaf(bw.packed_bits, mode, shards),
+        values=_split_leaf(bw.values, mode, shards),
         shard=(mode, shards))
 
 
@@ -332,9 +310,8 @@ def unshard_bitmap(bw: BitmapWeight) -> BitmapWeight:
     mode, _ = bw.shard
     return dataclasses.replace(
         bw,
-        packed_bits=_merge_leaf(bw.packed_bits, 4, mode),
-        values=_merge_leaf(bw.values, 4, mode),
-        dense_cache=_merge_leaf(bw.dense_cache, 2, mode),
+        packed_bits=_merge_leaf(bw.packed_bits, mode),
+        values=_merge_leaf(bw.values, mode),
         shard=None)
 
 
@@ -347,19 +324,15 @@ def gather_bitmap(bw: BitmapWeight, axis_name: str) -> BitmapWeight:
         return bw
     mode, _ = bw.shard
 
-    def g(leaf, tile_nd):
-        if leaf is None:
-            return None
-        ax = leaf.ndim - tile_nd - 1
+    def g(leaf):
+        ax = leaf.ndim - TILE_ND - 1
         return _merge_leaf(
-            jax.lax.all_gather(leaf, axis_name, axis=ax, tiled=True),
-            tile_nd, mode)
+            jax.lax.all_gather(leaf, axis_name, axis=ax, tiled=True), mode)
 
     return dataclasses.replace(
         bw,
-        packed_bits=g(bw.packed_bits, 4),
-        values=g(bw.values, 4),
-        dense_cache=g(bw.dense_cache, 2),
+        packed_bits=g(bw.packed_bits),
+        values=g(bw.values),
         shard=None)
 
 

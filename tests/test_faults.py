@@ -129,6 +129,35 @@ def test_bitflip_quarantine_lands_in_manifest():
         assert eng.packed.blocks[parts[1]][parts[2]][parts[3]] is None
 
 
+def test_bitflip_in_served_values_is_caught_on_xla():
+    """On the xla dispatch, which decodes the packed arrays inside every
+    served step, a bit flipped in a served tensor's values changes what
+    the step computes.  Under ``audit=True`` the auditor attributes it to
+    that tensor, quarantines it, and the replay serves the clean run's
+    tokens.  (Seed 2 flips an exponent bit; a flip in the low half of an
+    f32 mantissa is rounded away by the bf16 matmul.)"""
+    kw = dict(sparsity=0.0, impl="xla")
+    path = "blocks/b0/mlp/w_down"
+
+    def plan():
+        return FaultPlan(seed=2).bitflip(step=4, tensor=path,
+                                         field="values")
+
+    probe = ServeEngine(get_smoke_config("olmo-1b"), seed=0, num_slots=2,
+                        max_len=64, **kw)
+    clean = probe.probe_logits([3, 4])
+    plan().fire(probe, 4)
+    assert not np.array_equal(probe.probe_logits([3, 4]), clean)
+
+    _, _, base = _run(**kw)
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")
+        eng, rep, toks = _run(audit=True, faults=plan(), **kw)
+    assert toks == base
+    assert list(rep["lifecycle"]["quarantined"]) == [path]
+    assert rep["lifecycle"]["faults"]["log"][0]["tensor"] == path
+
+
 def test_combined_chaos_gemma_moe():
     """The whole seeded chaos schedule on a second arch (MoE): typed
     terminal states, clean-run tokens, zero violations, zero leaks."""

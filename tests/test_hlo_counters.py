@@ -69,21 +69,23 @@ def test_dus_cache_update_charged_at_update_size():
 def test_real_jitted_serve_steps_analyzable(arch):
     """The analyzer must handle the *real* serving programs — jitted
     decode and prefill steps with their while-loops, DUS cache updates,
-    and donated buffers — not just the synthetic shapes above.  The
-    full {packed, dense} × {contig, paged} band matrix lives in
-    test_traffic.py; this pins the analyzer side: real HLO parses, and
-    the counted bytes sit at or above the dispatch's fetch floor."""
+    and donated buffers — not just the synthetic shapes above: real HLO
+    parses, and the counted bytes sit at or above the packed weights
+    each step reads (the whole weight stream for decode; the stack
+    without the LM head for prefill)."""
     from repro.configs import get_smoke_config
     from repro.serve import ServeEngine
 
-    eng = ServeEngine(get_smoke_config(arch), seed=0, num_slots=2,
-                      max_len=32, sparsity=0.5, paged=True, page_len=8,
-                      prefill_chunk=8)
-    for phase in ("decode", "prefill"):
-        compiled = eng.traffic._lowered(phase).compile()
-        r = analyze(compiled.as_text())
+    cfg = get_smoke_config(arch)
+    eng = ServeEngine(cfg, seed=0, num_slots=2, max_len=32, sparsity=0.5,
+                      paged=True, page_len=8, prefill_chunk=8)
+    stream = eng.weight_stream_report()["sparse_bytes_per_step"]
+    head = (eng.lm_weight.hbm_bytes if eng.lm_weight is not None
+            else cfg.d_model * cfg.vocab_size * 4)
+    floors = {"decode": stream, "prefill": stream - head}
+    for phase, floor in floors.items():
+        r = analyze(eng.lower(phase).compile().as_text())
         assert r["flops"] > 0 and r["bytes"] > 0
-        floor = eng.traffic.modeled_executed(phase)["total_bytes"]
         assert r["bytes"] >= floor, (phase, r["bytes"], floor)
 
 
@@ -99,3 +101,17 @@ def test_parse_handles_tuple_shapes_with_index_comments():
     assert "main" in comps
     ops = [i.op for i in comps["main"]]
     assert "tuple" in ops and "get-tuple-element" in ops
+
+
+@pytest.mark.parametrize("kind,known", [("TPU v5 lite", True),
+                                        ("TPU v4", False), ("cpu", False)])
+def test_peaks_table_refuses_unknown_chips(kind, known):
+    """Peaks come from the table keyed by ``device_kind``; a chip it does
+    not hold is an error, never a silent v5e default."""
+    from repro.launch.hlo_analysis import peaks, roofline
+    if known:
+        assert peaks(kind)["hbm_bw"] == 819e9
+        assert roofline(1.0, 819e9, 0.0, kind)["memory_s"] == 1.0
+    else:
+        with pytest.raises(ValueError, match="no published peaks"):
+            roofline(1.0, 1.0, 0.0, kind)

@@ -1,13 +1,14 @@
 """Whole-stack bitmap weight streaming: packed-vs-dense equivalence,
-manifest/fallback surfacing, traffic aggregation, sampling."""
+manifest/fallback surfacing, the per-step weight stream, sampling."""
 import warnings
 
 import numpy as np
 import pytest
 
-from repro.configs import get_smoke_config
+from repro.configs import ARCHS, get_smoke_config
 from repro.models.config import BlockCfg, ModelConfig
 from repro.serve import ServeEngine, pack_model, poisson_trace
+from repro.serve.packed import ROUTED_EXPERT, activated_scale
 
 
 def _run_tokens(cfg, *, stream, sparsity=0.0, seed=0, n_requests=4,
@@ -26,16 +27,18 @@ def _run_tokens(cfg, *, stream, sparsity=0.0, seed=0, n_requests=4,
 # ------------------------------------------------------- equivalence -------
 
 
-@pytest.mark.parametrize("arch", ["olmo-1b", "gemma3-4b",
-                                  "granite-moe-3b-a800m"])
+@pytest.mark.parametrize("arch", ARCHS)
 def test_packed_streaming_matches_dense_tokens(arch):
     """sparsity=0: the fully-packed engine reproduces the dense engine's
-    tokens exactly, across attn/mlp, sliding-window and MoE archs —
-    packing is lossless and the bitmap dispatch is numerically identical
-    to dense ``@``."""
+    tokens exactly on every registered arch (sliding window, GQA, MoE,
+    hybrid mamba/MoE, rwkv, the frames and patch frontends) — packing is
+    lossless, and the bitmap dispatch, which decodes the packed arrays
+    inside every served step, is numerically identical to dense ``@``."""
     cfg = get_smoke_config(arch)
-    packed_toks, eng = _run_tokens(cfg, stream=True)
-    dense_toks, _ = _run_tokens(cfg, stream=False)
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")     # e.g. granite's dense head
+        packed_toks, eng = _run_tokens(cfg, stream=True)
+        dense_toks, _ = _run_tokens(cfg, stream=False)
     assert packed_toks == dense_toks
     assert all(toks for toks in packed_toks)
     assert eng.packed is not None and eng.packed.packed_entries
@@ -57,7 +60,7 @@ def test_packed_streaming_lossless_under_pruning():
     assert eng.report()["weight_stream"]["reduction"] > 2.0
 
 
-# ------------------------------------------------- manifest / traffic ------
+# ------------------------------------------- manifest / weight stream ------
 
 
 def test_pack_model_manifest_records_fallbacks():
@@ -74,20 +77,6 @@ def test_pack_model_manifest_records_fallbacks():
     assert ws["sparse_bytes_per_step"] < ws["dense_bytes_per_step"]
     assert ws["packed_tensors"] == len(pm.packed_entries)
     assert ws["fallbacks"] == {e.path: e.reason for e in pm.fallback_entries}
-
-
-def test_dense_cache_not_counted_in_hbm_bytes():
-    """The xla-oracle dense rendering must not change the modeled
-    compressed-stream bytes."""
-    from repro.sparse.format import pack_bitmap
-    r = np.random.default_rng(0)
-    w = r.standard_normal((64, 128)).astype(np.float32)
-    w *= r.random((64, 128)) >= 0.75
-    a = pack_bitmap(w, block=(64, 64))
-    b = pack_bitmap(w, block=(64, 64), cache_dense=True)
-    assert b.dense_cache is not None
-    assert a.hbm_bytes == b.hbm_bytes
-    np.testing.assert_array_equal(np.asarray(b.dense_cache), w)
 
 
 def test_stacked_pack_roundtrip():
@@ -332,6 +321,76 @@ def test_expert_stream_accounting():
     stored = head + sum(e.sparse_bytes for e in eng.packed.manifest)
     assert want_sparse < stored
     assert activated == 4
+
+
+def _engine(arch="olmo-1b", **kw):
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")     # e.g. granite's dense head
+        return ServeEngine(get_smoke_config(arch), num_slots=2, max_len=32,
+                           sparsity=0.5, seed=0, **kw)
+
+
+@pytest.mark.parametrize("stream", [True, False], ids=["packed", "dense"])
+@pytest.mark.parametrize("arch", ARCHS)
+def test_weight_stream_sums_the_packed_leaves(arch, stream):
+    """``weight_stream_report()`` (the benchmark's ``weight_mb_per_step``)
+    equals a count taken from the engine's own leaves: each packed
+    leaf's ``hbm_bytes``, each dense tensor's array bytes, router-gated
+    expert stacks scaled to the experts a step activates, plus the LM
+    head — packed or dense."""
+    eng = _engine(arch, stream_weights=stream, bitmap_head=stream)
+    cfg = eng.cfg
+    activated = 2 * cfg.top_k if cfg.num_experts else None
+    packed = eng.packed.blocks if eng.packed is not None else None
+    sparse = dense = 0
+    for bname, bdict in eng.params["blocks"].items():
+        for comp, tensors in bdict.items():
+            for name, w in tensors.items():
+                routed = (w.shape[1] if (comp, name) in ROUTED_EXPERT
+                          and w.ndim == 4 else 0)
+                scale = activated_scale(routed, activated)
+                d = int(round(w.size * w.dtype.itemsize * scale))
+                bw = packed[bname][comp][name] if packed else None
+                sparse += d if bw is None else int(round(bw.hbm_bytes
+                                                         * scale))
+                dense += d
+    head_dense = cfg.d_model * cfg.vocab_size * 4
+    sparse += (eng.lm_weight.hbm_bytes if eng.lm_weight is not None
+               else head_dense)
+    dense += head_dense
+    ws = eng.weight_stream_report()
+    assert ws["sparse_bytes_per_step"] == sparse
+    assert ws["dense_bytes_per_step"] == dense
+    assert sparse < dense if stream else sparse == dense
+
+
+def test_weight_stream_tracks_quarantine():
+    """Quarantining a packed tensor raises the per-step weight stream by
+    exactly that tensor's dense minus packed bytes."""
+    eng = _engine()
+    before = eng.weight_stream_report()["sparse_bytes_per_step"]
+    e = next(e for e in eng.packed.manifest if e.packed)
+    rise = e.dense_bytes - e.sparse_bytes
+    assert rise > 0
+    eng.packed.quarantine(e.path, "test")
+    after = eng.weight_stream_report()["sparse_bytes_per_step"]
+    assert after == before + rise
+
+
+@pytest.mark.parametrize("arch,knobs,steps", [
+    # 2 periods x 7 matrices, each one (K, N) tile, and the head's two
+    # 128-wide column blocks
+    ("olmo-1b", {}, 2 * 7 + 2),
+    # 2 periods x (4 attention matrices + 3 stacks of 5 experts), each
+    # one tile at 2 slots x 1 capacity row; the head serves dense
+    ("granite-moe-3b-a800m", {}, 2 * (4 + 3 * 5)),
+    ("olmo-1b", {"stream_weights": False, "bitmap_head": False}, 0),
+], ids=["olmo-1b", "granite-moe-3b-a800m", "dense"])
+def test_weight_stream_counts_spmm_grid_steps(arch, knobs, steps):
+    """``spmm_grid_steps_per_step`` equals the hand count of the
+    ``bitmap_spmm`` grid steps one decode step runs."""
+    ws = _engine(arch, **knobs).weight_stream_report()
+    assert ws["spmm_grid_steps_per_step"] == steps
 
 
 # ---------------------------------------------------------- sampling -------

@@ -209,24 +209,6 @@ _SCHEMA = {
                       "sparse_bytes_per_step": (int, float),
                       "dense_bytes_per_step": (int, float),
                       "reduction": float},
-    "traffic": {"per_role": dict,
-                "weight": {"sparse_bytes_per_step": int,
-                           "dense_bytes_per_step": int,
-                           "reduction": float},
-                "kv": {"line_bytes_per_token": int, "read_bytes": int,
-                       "write_bytes": int, "prefix_saved_bytes": int},
-                "phases": {"decode": {"steps": int, "weight_bytes": int,
-                                      "kv_read_bytes": int,
-                                      "kv_write_bytes": int},
-                           "prefill": {"calls": int, "weight_bytes": int,
-                                       "kv_read_bytes": int,
-                                       "kv_write_bytes": int}},
-                "energy": {"macs_per_token": int, "pj_per_token": float,
-                           "pj_per_token_dense": float,
-                           "tops_per_watt": float,
-                           "tops_per_watt_dense": float},
-                "roofline": dict,
-                "crosscheck": None},
     "paging": {"paged": bool, "fallback": None,
                "reserved_kv_bytes": int, "contiguous_kv_bytes": int,
                "reserved_reduction": float},
@@ -392,7 +374,7 @@ def test_programs_carry_named_scopes(program, scopes):
     ``op_name`` metadata the profiler attaches to each device op."""
     eng = _engine(paged=True, page_len=8, prefill_chunk=8)
     with jax.set_mesh(eng.mesh):
-        hlo = eng.traffic._lowered(program).compile().as_text()
+        hlo = eng.lower(program).compile().as_text()
     names = re.findall(r'op_name="([^"]*)"', hlo)
     for scope in scopes:
         assert any(f"/{scope}/" in n for n in names), scope
