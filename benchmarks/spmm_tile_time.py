@@ -1,5 +1,6 @@
 """Device time of ``bitmap_spmm`` alone, per 128x128 tile, at olmo-1b's
-projection and LM-head widths, read from a profiler trace.
+projection and LM-head widths, read from a profiler trace.  Rows: the
+benchmark cells' 16 decode slots, 128, and a prefill call's 16 x 32.
 
 Each matrix is a 75 %-sparse (the head: dense) bf16 ``BitmapWeight`` at a
 fixed value-row count Q; one packed 1024x1024 block is repeated over the
@@ -114,7 +115,7 @@ def main() -> None:
     p.add_argument("--other", default=None,
                    help="path of another bitmap_spmm.py to time beside")
     p.add_argument("--unroll", default=str(this_kernel.STRIP_UNROLL))
-    p.add_argument("--rows", default="16,128")
+    p.add_argument("--rows", default="16,128,512")
     p.add_argument("--reps", type=int, default=20)
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--out", default=None)

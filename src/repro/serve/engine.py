@@ -1538,6 +1538,8 @@ class ServeEngine:
             shard_fb["lm_head"] = self.head_shard_fallback
         out = {**rep,
                "spmm_grid_steps_per_step": self._spmm_grid_steps(),
+               "spmm_prefill_expansions_per_tile":
+                   self._spmm_prefill_expansions_per_tile(),
                "sparse_bytes_per_step": sparse,
                "dense_bytes_per_step": dense,
                "reduction": dense / sparse if sparse else 1.0,
@@ -1562,24 +1564,49 @@ class ServeEngine:
         routed, computed = moe_rows(1, cfg)
         return 100.0 * routed / computed
 
-    def _spmm_grid_steps(self) -> int:
-        """``bitmap_spmm`` grid steps of one decode step on the Pallas
-        path: each packed matrix once, at the decode rows (``num_slots``;
-        a routed expert stack at each expert's capacity over them), and
-        the packed head."""
-        from repro.kernels.bitmap_spmm import grid_steps
+    def _spmm_calls(self, rows: int, head: bool) -> list:
+        """(packed weight, rows per matrix) of each ``bitmap_spmm`` a
+        step over ``rows`` tokens streams on the Pallas path: each packed
+        matrix at ``rows``, a routed expert stack at each expert's buffer
+        (``expert_capacity`` of one token per batch row, as ``moe_rows``
+        sizes it for the decode and the folded prefill dispatch), and the
+        packed head where ``head``."""
         from repro.models.layers import expert_capacity
-        dt = jnp.dtype(self.cfg.compute_dtype)
-        rows = self.num_slots
         leaves = self.packed.leaves() if self.packed is not None else []
-        steps = 0
+        calls = []
         for path, bw in leaves:
             routed = tuple(path.split("/")[-2:]) in ROUTED_EXPERT
-            m = rows * expert_capacity(1, self.cfg) if routed else rows
-            steps += grid_steps(bw, m, dt)
-        if self.lm_weight is not None:
-            steps += grid_steps(self.lm_weight, rows, dt)
-        return steps
+            calls.append((bw, rows * expert_capacity(1, self.cfg)
+                          if routed else rows))
+        if head and self.lm_weight is not None:
+            calls.append((self.lm_weight, rows))
+        return calls
+
+    def _spmm_grid_steps(self) -> int:
+        """``bitmap_spmm`` grid steps of one decode step on the Pallas
+        path: each packed matrix once, at the decode rows (``num_slots``),
+        and the packed head."""
+        from repro.kernels.bitmap_spmm import grid_steps
+        dt = jnp.dtype(self.cfg.compute_dtype)
+        return sum(grid_steps(bw, m, dt)
+                   for bw, m in self._spmm_calls(self.num_slots, head=True))
+
+    def _spmm_prefill_expansions_per_tile(self) -> Optional[float]:
+        """Tile expansions of one chunked-prefill call on the Pallas path
+        ÷ the packed tiles it streams (each packed matrix at ``num_slots
+        × prefill_chunk`` rows; no head): the kernel expands every tile
+        once per row block (``bitmap_spmm.row_blocks``).  None without
+        packed weights or chunked prefill."""
+        from repro.kernels.bitmap_spmm import row_blocks
+        calls = self._spmm_calls(self.num_slots * self.prefill_chunk,
+                                 head=False)
+        if not (calls and self.prefill_chunk):
+            return None
+        dt = jnp.dtype(self.cfg.compute_dtype)
+        tiles = [math.prod(bw.values.shape[:-2]) for bw, _ in calls]
+        expanded = sum(t * row_blocks(bw, m, dt)[1]
+                       for t, (bw, m) in zip(tiles, calls))
+        return expanded / sum(tiles)
 
     def prefill_report(self) -> dict:
         """The prefill section: chunk-call accounting + the step split."""

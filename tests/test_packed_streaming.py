@@ -1,5 +1,6 @@
 """Whole-stack bitmap weight streaming: packed-vs-dense equivalence,
 manifest/fallback surfacing, the per-step weight stream, sampling."""
+import math
 import warnings
 
 import numpy as np
@@ -323,11 +324,11 @@ def test_expert_stream_accounting():
     assert activated == 4
 
 
-def _engine(arch="olmo-1b", **kw):
+def _engine(arch="olmo-1b", num_slots=2, **kw):
     with warnings.catch_warnings():
         warnings.simplefilter("ignore")     # e.g. granite's dense head
-        return ServeEngine(get_smoke_config(arch), num_slots=2, max_len=32,
-                           sparsity=0.5, seed=0, **kw)
+        return ServeEngine(get_smoke_config(arch), num_slots=num_slots,
+                           max_len=32, sparsity=0.5, seed=0, **kw)
 
 
 @pytest.mark.parametrize("stream", [True, False], ids=["packed", "dense"])
@@ -391,6 +392,28 @@ def test_weight_stream_counts_spmm_grid_steps(arch, knobs, steps):
     ``bitmap_spmm`` grid steps one decode step runs."""
     ws = _engine(arch, **knobs).weight_stream_report()
     assert ws["spmm_grid_steps_per_step"] == steps
+
+
+@pytest.mark.parametrize("arch,tiles", [
+    # 2 periods x 7 matrices of one tile each; the head is not in prefill
+    ("olmo-1b", 2 * 7),
+    # 2 periods x (4 attention matrices + 3 stacks of 5 one-tile experts)
+    ("granite-moe-3b-a800m", 2 * (4 + 3 * 5)),
+])
+def test_weight_stream_counts_prefill_expansions_per_tile(arch, tiles):
+    """``spmm_prefill_expansions_per_tile`` equals the hand count of one
+    prefill call at 16 slots x a 32-token chunk: 512 rows per matrix
+    (and per expert's buffer, one capacity row per token), one row block
+    each, so each of the call's packed tiles is expanded once."""
+    eng = _engine(arch, num_slots=16, prefill_chunk=32)
+    assert sum(math.prod(bw.values.shape[:-2])
+               for _, bw in eng.packed.leaves()) == tiles
+    # one row block: each tile expanded once (4.0 when a row block held
+    # 128 rows)
+    ws = eng.weight_stream_report()
+    assert ws["spmm_prefill_expansions_per_tile"] == 1.0
+    assert _engine(arch).weight_stream_report()[
+        "spmm_prefill_expansions_per_tile"] is None   # no chunked prefill
 
 
 # ---------------------------------------------------------- sampling -------

@@ -85,6 +85,19 @@ def test_bitmap_spmm_compiles_at_cell_decode_rows(one_chip, name):
         x, w).compile())
 
 
+@pytest.mark.parametrize("name", ["wq", "w_up", "w_down"])
+def test_bitmap_spmm_compiles_at_cell_prefill_rows(one_chip, name):
+    """A prefill call's 16 x 32 rows in one row block, each expanded tile
+    carried through four 128-row sub-blocks; K in one strip, or for
+    ``w_down`` (K = 8192) in four strips of 16 tiles."""
+    k, n = OLMO[name]
+    x = jax.ShapeDtypeStruct((CELL_SLOTS * CHUNK, k), jnp.bfloat16,
+                             sharding=one_chip)
+    w = _weight(k, n, one_chip, dtype=jnp.bfloat16)
+    _assert_kernel(jax.jit(lambda x, w: bitmap_spmm(x, w)).lower(
+        x, w).compile())
+
+
 @pytest.mark.parametrize("values_dtype", [jnp.float32, jnp.bfloat16])
 def test_bitmap_spmm_compiles_for_each_value_dtype(one_chip, values_dtype):
     x = jax.ShapeDtypeStruct((SLOTS, 2048), jnp.bfloat16, sharding=one_chip)
@@ -94,8 +107,10 @@ def test_bitmap_spmm_compiles_for_each_value_dtype(one_chip, values_dtype):
 
 
 # capacity rows per expert at top-8 of 40 (capacity factor 1.25): decode
-# over 4 slots, and a 4 x 32-token prefill chunk
-@pytest.mark.parametrize("m", [2, 33], ids=["decode", "prefill"])
+# over 4 slots, and a 4 x 32-token prefill chunk; the benchmark cell's
+# prefill call, its chunk folded into the batch: 16 x 32 rows per expert
+@pytest.mark.parametrize("m", [2, 33, CELL_SLOTS * CHUNK],
+                         ids=["decode", "prefill", "cell-prefill"])
 @pytest.mark.parametrize("k,n", [(GRANITE_D, GRANITE_FF),
                                  (GRANITE_FF, GRANITE_D)],
                          ids=["w_up", "w_down"])
